@@ -115,11 +115,12 @@ def bench_backend_series(name: str, n: int, iters: int = 3) -> dict:
     hand = {
         "GEMVER": lambda i: ops.gemver(
             i["A"], i["u1"], i["v1"], i["u2"], i["v2"], i["y"], i["z"],
-            i["alpha"], i["beta"], use_pallas=True),
+            i["alpha"], i["beta"], use_pallas=True, interpret=True),
         "BiCGK": lambda i: ops.bicgk(i["A"], i["p"], i["r"],
-                                     use_pallas=True),
+                                     use_pallas=True, interpret=True),
         "LM_RMSNORM": lambda i: ops.rmsnorm(i["x"][None], i["gamma"],
-                                            use_pallas=True)[0],
+                                            use_pallas=True,
+                                            interpret=True)[0],
     }[name]
 
     series = {}
@@ -170,5 +171,7 @@ def run_all(quick: bool = False) -> list[str]:
 
 
 if __name__ == "__main__":
+    from repro.launch import enable_compile_cache
+    enable_compile_cache()
     for r in run_all():
         print(r)

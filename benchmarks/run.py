@@ -131,4 +131,6 @@ def main() -> None:
 
 
 if __name__ == '__main__':
+    from repro.launch import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -80,4 +80,6 @@ def main(limit: int = 32):
 
 
 if __name__ == "__main__":
+    from repro.launch import enable_compile_cache
+    enable_compile_cache()
     main()

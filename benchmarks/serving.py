@@ -313,4 +313,6 @@ def main():
 
 
 if __name__ == "__main__":
+    from repro.launch import enable_compile_cache
+    enable_compile_cache()
     main()

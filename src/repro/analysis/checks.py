@@ -43,7 +43,8 @@ from ..core.graph import Graph
 from ..core.masking import MASK_INPUT
 from ..core.plan import (PLAN_VERSION, ExecutionPlan, PackedPlan,
                          graph_signature, plan_fingerprint)
-from ..core.predictor import V5E, HardwareModel, accumulable, cost_impl
+from ..core.predictor import (V5E, HardwareModel, accumulable, block_granules,
+                              block_is_legal, cost_impl)
 
 #: env var overriding the VMEM budget the RPL215 check enforces (bytes)
 VMEM_BUDGET_ENV = "REPRO_VMEM_BUDGET"
@@ -355,7 +356,9 @@ def verify_plan(plan: ExecutionPlan, g: Graph, hw: HardwareModel = V5E,
     Group binding re-runs ``analyse_group`` (RPL211 covers fusion
     legality including the phase-chain-under-inclusion condition, rule
     2), validates the grid order and block sizes against the bound
-    fusion (RPL212/RPL213), enforces the pallas phase contract — every
+    fusion (RPL212/RPL213; blocks must also follow the predictor's
+    per-operand tiling rule, ``block_granules``, on either backend),
+    enforces the pallas phase contract — every
     consumed reduction accumulable under the plan's order (RPL214) —
     and re-costs the implementation to check the VMEM footprint,
     including consumed-reduction scratch, against the budget (RPL215;
@@ -399,12 +402,20 @@ def verify_plan(plan: ExecutionPlan, g: Graph, hw: HardwareModel = V5E,
             ok = False
         if ok:
             order = tuple(f.axis_roots[p] for p in gp.order_pos)
+            gran = block_granules(f, g, hw)
             for bi, (b, r) in enumerate(zip(gp.blocks, order)):
                 size = f.axis_sizes[f.axis_roots.index(r)]
                 if b > size:
                     out.append(diag(
                         "RPL213", f"{loc}.blocks[{bi}]",
                         f"block {b} exceeds axis size {size}"))
+                    ok = False
+                elif not block_is_legal(size, b, gran[r]):
+                    out.append(diag(
+                        "RPL213", f"{loc}.blocks[{bi}]",
+                        f"block {b} of a {size}-long axis is neither the "
+                        f"whole axis nor a divisor in multiples of "
+                        f"{gran[r]} (the operands' Mosaic tiling)"))
                     ok = False
         if ok:
             if plan.backend == "pallas":

@@ -12,7 +12,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-from repro.core.elementary import (Elementary, Monoid, make_map,
+from repro.core.elementary import (Elementary, Monoid, col, make_map,
                                    make_nested_map, make_nested_map_reduce,
                                    make_reduce)
 
@@ -65,8 +65,8 @@ gemtv_t = make_nested_map_reduce(
 # B_ij = A_ij + u1_i v1_j + u2_i v2_j   (GEMVER rank-2 update, nested map)
 rank2_update = make_nested_map(
     "rank2_update",
-    lambda A, u1, v1, u2, v2: A + u1[..., :, None] * v1[..., None, :]
-    + u2[..., :, None] * v2[..., None, :],
+    lambda A, u1, v1, u2, v2: A + col(u1) * v1[..., None, :]
+    + col(u2) * v2[..., None, :],
     in_axes=[(0, 1), (0,), (1,), (0,), (1,)], flops_per_point=4)
 
 # C_ij = A_ij + B_ij                    (MADD, nested map)
@@ -75,7 +75,7 @@ madd = make_nested_map(
 
 # outer product u v^T                   (GER building block)
 outer = make_nested_map(
-    "outer", lambda u, v: u[..., :, None] * v[..., None, :],
+    "outer", lambda u, v: col(u) * v[..., None, :],
     in_axes=[(0,), (1,)], flops_per_point=1)
 
 ALL = {e.name: e for e in [

@@ -143,7 +143,8 @@ def group_inputs(f, seed: int = 0) -> tuple:
 
 
 def measure_group(g: Graph, impl: Impl, *, backend: str = "jnp",
-                  interpret: bool = True, reps: int = MEAS_REPS,
+                  hw: HardwareModel = V5E,
+                  interpret: bool = False, reps: int = MEAS_REPS,
                   warmup: int = MEAS_WARMUP, inner: int = GROUP_INNER,
                   seed: int = 0) -> float:
     """Time ONE fused group in isolation: jit the group's kernel (the
@@ -152,7 +153,7 @@ def measure_group(g: Graph, impl: Impl, *, backend: str = "jnp",
     intercept every fresh measurement at one seam."""
     import jax
     if backend == "pallas":
-        fn = codegen._group_pallas_fn(g, impl, interpret=interpret)
+        fn = codegen._group_pallas_fn(g, impl, hw=hw, interpret=interpret)
     else:
         fn = codegen._group_dense_fn(impl.fusion)
     return measure_callable(jax.jit(fn), group_inputs(impl.fusion, seed),
@@ -173,7 +174,7 @@ def combination_key(plan: ExecutionPlan) -> str:
     return hashlib.sha256(payload.encode()).hexdigest()
 
 
-def hw_fingerprint(backend: str = "jnp", interpret: bool = True) -> str:
+def hw_fingerprint(backend: str = "jnp", interpret: bool = False) -> str:
     """Fingerprint of the measuring environment.  Two hosts with the
     same fingerprint are interchangeable for the measured-cost table
     (same compiler backend + jax platform/device kind/version), which is
@@ -294,7 +295,7 @@ def impl_group_key(g: Graph, im: Impl, fingerprint: str) -> str:
 
 
 def predict_combination(g: Graph, combo: Combination, hw: HardwareModel, *,
-                        backend: str = "jnp", interpret: bool = True,
+                        backend: str = "jnp", interpret: bool = False,
                         cache: PlanCache | None = None) -> float:
     """Predicted seconds for one combination under the **two-phase
     predictor** (DESIGN.md §8): a group present in ``cache``'s
@@ -323,7 +324,7 @@ def predict_combination(g: Graph, combo: Combination, hw: HardwareModel, *,
 
 def autotune_combination(space: OptimizationSpace, *,
                          hw: HardwareModel = V5E, backend: str = "jnp",
-                         interpret: bool = True,
+                         interpret: bool = False,
                          cache: PlanCache | None = None,
                          budget: int = 8, reps: int = MEAS_REPS,
                          warmup: int = MEAS_WARMUP,
@@ -415,7 +416,7 @@ def autotune_combination(space: OptimizationSpace, *,
                 missing = None                 # served; skip measuring
         if missing is not None:
             for k, im in missing:
-                t = measure_group(g, im, backend=backend,
+                t = measure_group(g, im, backend=backend, hw=hw,
                                   interpret=interpret, reps=reps,
                                   warmup=warmup, inner=inner, seed=seed)
                 rec = {"kind": "group", "t_meas": t,

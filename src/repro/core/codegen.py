@@ -52,8 +52,14 @@ from .elementary import Monoid
 from .fusion import Fusion, call_phases, consumed_reductions
 from .graph import Graph, Var
 from .plan import ExecutionPlan, PackedPlan, build_plan
-from .predictor import V5E, HardwareModel, Impl, accumulable, reduce_roots_of
+from .predictor import (V5E, HardwareModel, Impl, accumulable,
+                        operand_carrier, reduce_roots_of)
 from .scheduler import Combination
+
+#: scoped VMEM Mosaic may use per kernel beyond the predictor's budget
+#: (``hw.vmem_bytes``): the kernel body's temporaries, which the block
+#: count in ``cost_impl`` cannot see (64 + 36 = 100 of the v5e's 128 MiB)
+VMEM_HEADROOM_BYTES = 36 * 1024 * 1024
 
 
 # ---------------------------------------------------------------------------
@@ -93,7 +99,19 @@ def _monoid_sum(monoid: Monoid, x, axes):
     return jnp.min(x, axis=axes)
 
 
-def _group_pallas_fn(g: Graph, impl: Impl, interpret: bool = True) -> Callable:
+def _require_pallas_platform(interpret: bool) -> None:
+    """Compiled Pallas kernels need a TPU; elsewhere the caller must ask
+    for the interpreter explicitly — it is never a silent fallback."""
+    if not interpret and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            f"the pallas backend compiles Mosaic kernels, which need a "
+            f"TPU, but JAX's default backend is "
+            f"{jax.default_backend()!r}; pass interpret=True to run the "
+            f"kernels in the Pallas interpreter instead")
+
+
+def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
+                     interpret: bool = False) -> Callable:
     """Build the single pallas_call for one fused group.
 
     Groups whose reductions are only *produced* (never consumed inside)
@@ -110,7 +128,15 @@ def _group_pallas_fn(g: Graph, impl: Impl, interpret: bool = True) -> Callable:
     axes an innermost suffix); ``enumerate_impls`` emits only such
     orders, and a hand-built plan violating it raises
     ``NotImplementedError`` — the group-split contract (DESIGN.md §2).
+
+    Every value crosses the kernel boundary — BlockSpec, partials
+    array, scratch — in its ``predictor.operand_carrier`` form (rank
+    >= 2, lane-dense vectors, ``(1, 1)`` scalars), the layout the
+    predictor's block legality and VMEM count assume; the body reshapes
+    blocks back to the elementaries' natural ranks.  ``interpret=False``
+    compiles Mosaic kernels and needs a TPU.
     """
+    _require_pallas_platform(interpret)
     f = impl.fusion
     order, spatial_grid = impl.order, impl.grid
     pos = {r: i for i, r in enumerate(order)}
@@ -156,66 +182,86 @@ def _group_pallas_fn(g: Graph, impl: Impl, interpret: bool = True) -> Callable:
     def roots_of(v: Var) -> tuple[int, ...]:
         return tuple(g.axis_root(a) for a in v.axis_ids)
 
-    def make_index_map(vroots: tuple[int, ...], lead_zeros: int = 0,
-                       lead_roots: tuple[int, ...] = ()):
+    def natural_block(v: Var) -> tuple[int, ...]:
+        return tuple(blk[r] for r in roots_of(v))
+
+    def carrier(v: Var) -> tuple[tuple[int, ...], tuple[int, ...]]:
+        return operand_carrier(v.shape, natural_block(v), v.dtype, hw)
+
+    def is_row(v: Var) -> bool:
+        """A vector carried as one ``(1, n)`` row (blocks move along its
+        lanes) rather than a lane-dense view (blocks of whole rows)."""
+        return len(v.shape) == 1 and carrier(v)[1][0] == 1
+
+    def make_index_map(v: Var, lead_roots: tuple[int, ...] = ()):
+        vroots = roots_of(v)
+        row = is_row(v)
+
         def index_map(*gids):
             gids = gids[gofs:]               # the phase axis moves no blocks
             lead = tuple(gids[pos[r]] for r in lead_roots)
-            body = tuple(gids[pos[r]] for r in vroots)
-            return (0,) * lead_zeros + lead + body
+            if not vroots:                   # (1, 1) scalar carrier
+                body = (0, 0)
+            elif len(vroots) == 1:
+                gid = gids[pos[vroots[0]]]
+                body = (0, gid) if row else (gid, 0)
+            else:
+                body = tuple(gids[pos[r]] for r in vroots)
+            return lead + body
         return index_map
 
-    # ---- input specs ------------------------------------------------------
-    in_specs, in_is_scalar = [], []
-    for v in f.external_inputs:
+    def load(v: Var, ref, idx=Ellipsis):
+        """A carrier block read back at the elementaries' natural rank."""
         if v.shape == ():
-            in_specs.append(pl.BlockSpec((1, 1), lambda *g_: (0, 0)))
-            in_is_scalar.append(True)
-        else:
-            vr = roots_of(v)
-            in_specs.append(pl.BlockSpec(tuple(blk[r] for r in vr),
-                                         make_index_map(vr)))
-            in_is_scalar.append(False)
+            return ref[0, 0]
+        return jnp.reshape(ref[idx], natural_block(v))
+
+    # ---- input specs ------------------------------------------------------
+    in_specs = []
+    for v in f.external_inputs:
+        in_specs.append(pl.BlockSpec(carrier(v)[1], make_index_map(v)))
 
     # ---- output specs -----------------------------------------------------
     out_specs, out_shapes, out_mode = [], [], []
-    # out_mode: ('map',), ('acc', reduce_pos), ('partial', rr, lead_shape)
+    # out_mode: ('map',), ('acc', reduce_pos), ('partial', lead_axes)
     for v in f.outputs:
-        vr = roots_of(v)
+        shape, block = carrier(v)
         rr = reduce_roots_of(v, f, g)
-        if not rr:
-            out_specs.append(pl.BlockSpec(tuple(blk[r] for r in vr),
-                                          make_index_map(vr)))
-            out_shapes.append(jax.ShapeDtypeStruct(v.shape, v.dtype))
-            out_mode.append(("map", None))
-        elif accumulable(v, f, g, order):
-            if v.shape == ():  # full reduction to scalar: (1,1) carrier
-                out_specs.append(pl.BlockSpec((1, 1), lambda *g_: (0, 0)))
-                out_shapes.append(jax.ShapeDtypeStruct((1, 1), v.dtype))
-            else:
-                out_specs.append(pl.BlockSpec(tuple(blk[r] for r in vr),
-                                              make_index_map(vr)))
-                out_shapes.append(jax.ShapeDtypeStruct(v.shape, v.dtype))
-            out_mode.append(("acc", tuple(pos[r] for r in rr)))
+        if not rr or accumulable(v, f, g, order):
+            out_specs.append(pl.BlockSpec(block, make_index_map(v)))
+            out_shapes.append(jax.ShapeDtypeStruct(shape, v.dtype))
+            out_mode.append(("acc", tuple(pos[r] for r in rr)) if rr
+                            else ("map", None))
         else:
+            # one carrier block per grid cell along the reduce axes,
+            # combined after the kernel
             lead = tuple(spatial_grid[pos[r]] for r in rr)
-            block = (1,) * len(rr) + tuple(blk[r] for r in vr)
             out_specs.append(pl.BlockSpec(
-                block, make_index_map(vr, lead_roots=rr)))
-            out_shapes.append(jax.ShapeDtypeStruct(lead + v.shape, v.dtype))
+                (1,) * len(rr) + block, make_index_map(v, lead_roots=rr)))
+            out_shapes.append(jax.ShapeDtypeStruct(lead + shape, v.dtype))
             out_mode.append(("partial", tuple(range(len(rr)))))
 
     # ---- scratch accumulators for consumed reductions ---------------------
-    # full-size VMEM buffers (padded to rank >= 2): the finished value of
-    # phase p, read back via dynamic block slices from phase p+1 on
-    scratch_shapes, scratch_at, scratch_roots = [], {}, {}
+    # full-size carrier buffers: the finished value of phase p, read back
+    # via dynamic block slices from phase p+1 on
+    scratch_shapes, scratch_at = [], {}
     for c in consumed:
-        v = c.out
-        vr = roots_of(v)
-        shape = tuple(v.shape) + (1,) * max(0, 2 - len(v.shape))
         scratch_at[c.idx] = len(scratch_shapes)
-        scratch_roots[c.idx] = vr
-        scratch_shapes.append(pltpu.VMEM(shape, v.dtype))
+        scratch_shapes.append(pltpu.VMEM(carrier(c.out)[0], c.out.dtype))
+
+    def scratch_index(v: Var):
+        """The current grid cell's block of a scratch carrier."""
+        vroots = roots_of(v)
+        if not vroots:
+            return (slice(None), slice(None))
+        if len(vroots) == 1:
+            pid = pl.program_id(gofs + pos[vroots[0]])
+            rows, lanes = carrier(v)[1]
+            if is_row(v):
+                return (slice(None), pl.ds(pid * lanes, lanes))
+            return (pl.ds(pid * rows, rows), slice(None))
+        return tuple(pl.ds(pl.program_id(gofs + pos[r]) * blk[r], blk[r])
+                     for r in vroots)
 
     n_in = len(f.external_inputs)
     n_out = len(f.outputs)
@@ -227,8 +273,8 @@ def _group_pallas_fn(g: Graph, impl: Impl, interpret: bool = True) -> Callable:
         scratch_refs = refs[n_in + n_out:]
         phase = pl.program_id(0) if multi else None
         env: dict[Var, Any] = {}
-        for v, ref, is_scalar in zip(f.external_inputs, in_refs, in_is_scalar):
-            env[v] = ref[0, 0] if is_scalar else ref[...]
+        for v, ref in zip(f.external_inputs, in_refs):
+            env[v] = load(v, ref)
         for c in f.calls:
             val = c.elem.fn(*[env[a] for a in c.args])
             gate = (phase == phase_of[c.idx]) if multi else None
@@ -237,41 +283,38 @@ def _group_pallas_fn(g: Graph, impl: Impl, interpret: bool = True) -> Callable:
                 # (possibly partial) value is read back from scratch, so
                 # consumers at later phases see the finished reduction
                 sref = scratch_refs[scratch_at[c.idx]]
-                vr = scratch_roots[c.idx]
-                idx = tuple(pl.dslice(pl.program_id(gofs + pos[r]) * blk[r],
-                                      blk[r]) for r in vr)
-                idx += (0,) * max(0, 2 - len(vr))
+                idx = scratch_index(c.out)
+                cval = jnp.reshape(val, carrier(c.out)[1]).astype(sref.dtype)
                 rr = reduce_roots_of(c.out, f, g)
                 is_first = functools.reduce(
                     jnp.logical_and,
                     [pl.program_id(gofs + pos[r]) == 0 for r in rr])
 
                 @pl.when(gate & is_first)
-                def _init_scratch(sref=sref, idx=idx, val=val):
-                    sref[idx] = val.astype(sref.dtype)
+                def _init_scratch(sref=sref, idx=idx, cval=cval):
+                    sref[idx] = cval
 
                 @pl.when(gate & jnp.logical_not(is_first))
-                def _acc_scratch(sref=sref, idx=idx, val=val,
+                def _acc_scratch(sref=sref, idx=idx, cval=cval,
                                  m=c.elem.monoid):
-                    sref[idx] = m.combine(sref[idx], val.astype(sref.dtype))
+                    sref[idx] = m.combine(sref[idx], cval)
 
-                env[c.out] = sref[idx]
+                env[c.out] = load(c.out, sref, idx)
             elif not c.elem.is_reduction:
                 env[c.out] = val
             if c.out in out_index:
                 i = out_index[c.out]
                 mode, aux = out_mode[i]
                 ref = out_refs[i]
-                if mode == "map":
+                cval = jnp.reshape(val, ref.shape).astype(ref.dtype)
+                if mode == "map" or mode == "partial":
                     if multi:
                         @pl.when(gate)
-                        def _write(ref=ref, val=val):
-                            ref[...] = val.astype(ref.dtype)
+                        def _write(ref=ref, cval=cval):
+                            ref[...] = cval
                     else:
-                        ref[...] = val.astype(ref.dtype)
-                elif mode == "acc":
-                    if c.out.shape == ():
-                        val = jnp.reshape(val, (1, 1))
+                        ref[...] = cval
+                else:  # acc
                     is_first = functools.reduce(
                         jnp.logical_and,
                         [pl.program_id(p + gofs) == 0 for p in aux])
@@ -282,43 +325,30 @@ def _group_pallas_fn(g: Graph, impl: Impl, interpret: bool = True) -> Callable:
                         not_first = jnp.logical_not(is_first)
 
                     @pl.when(is_first)
-                    def _init(ref=ref, val=val):
-                        ref[...] = val.astype(ref.dtype)
+                    def _init(ref=ref, cval=cval):
+                        ref[...] = cval
 
                     @pl.when(not_first)
-                    def _accum(ref=ref, val=val, m=c.elem.monoid):
-                        ref[...] = m.combine(ref[...], val.astype(ref.dtype))
-                else:  # partial
-                    lead = len(aux)
-                    part = jnp.reshape(val, (1,) * lead + val.shape
-                                       ).astype(ref.dtype)
-                    if multi:
-                        @pl.when(gate)
-                        def _write_part(ref=ref, part=part):
-                            ref[...] = part
-                    else:
-                        ref[...] = part
+                    def _accum(ref=ref, cval=cval, m=c.elem.monoid):
+                        ref[...] = m.combine(ref[...], cval)
 
     call = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=tuple(out_shapes), interpret=interpret,
         scratch_shapes=tuple(scratch_shapes),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=hw.vmem_bytes + VMEM_HEADROOM_BYTES),
     )
 
     def run(*ext_vals):
-        vals = []
-        for v, x, is_scalar in zip(f.external_inputs, ext_vals, in_is_scalar):
-            x = jnp.asarray(x, v.dtype)
-            vals.append(jnp.reshape(x, (1, 1)) if is_scalar else x)
+        vals = [jnp.reshape(jnp.asarray(x, v.dtype), carrier(v)[0])
+                for v, x in zip(f.external_inputs, ext_vals)]
         raw = call(*vals)
         outs = []
         for v, r, (mode, aux) in zip(f.outputs, raw, out_mode):
-            c = v.producer
             if mode == "partial":
-                r = _monoid_sum(c.elem.monoid, r, tuple(aux))
-            if v.shape == ():
-                r = jnp.reshape(r, ())
-            outs.append(r)
+                r = _monoid_sum(v.producer.elem.monoid, r, aux)
+            outs.append(jnp.reshape(r, v.shape))
         return tuple(outs)
 
     run.__name__ = "pallas_" + "_".join(c.elem.name for c in f.calls)
@@ -408,9 +438,8 @@ def _program_fn(plan: ExecutionPlan, impls: list[Impl], fns: list[Callable],
     plan's index table (plan.GroupPlan.inputs / plan.outputs).
 
     ``barrier=False`` drops the inter-group ``optimization_barrier`` —
-    required under ``vmap`` (the primitive has no batching rule in older
-    jax) and desirable for serving, where XLA fusing across the chosen
-    kernel boundaries is pure upside."""
+    desirable for serving, where XLA fusing across the chosen kernel
+    boundaries is pure upside."""
 
     def read(ref, inputs, group_outs):
         if ref[0] == "input":
@@ -433,13 +462,13 @@ def _program_fn(plan: ExecutionPlan, impls: list[Impl], fns: list[Callable],
 
 
 def _group_fns(g: Graph, plan: ExecutionPlan, impls: list[Impl],
-               interpret: bool) -> list[Callable]:
+               hw: HardwareModel, interpret: bool) -> list[Callable]:
     fns = []
     for im in impls:
         if plan.backend == "jnp":
             fns.append(_group_dense_fn(im.fusion))
         elif plan.backend == "pallas":
-            fns.append(_group_pallas_fn(g, im, interpret=interpret))
+            fns.append(_group_pallas_fn(g, im, hw=hw, interpret=interpret))
         else:
             raise VerificationError.single(
                 "RPL401", "plan.backend",
@@ -448,17 +477,17 @@ def _group_fns(g: Graph, plan: ExecutionPlan, impls: list[Impl],
 
 
 def compile_plan(g: Graph, plan: ExecutionPlan, hw: HardwareModel = V5E,
-                 interpret: bool = True, jit: bool = True) -> CompiledProgram:
+                 interpret: bool = False, jit: bool = True) -> CompiledProgram:
     """ExecutionPlan -> executable (one jitted whole-program function)."""
     impls = plan.bind(g, hw)
-    fns = _group_fns(g, plan, impls, interpret)
+    fns = _group_fns(g, plan, impls, hw, interpret)
     program = _program_fn(plan, impls, fns, plan.backend)
     return CompiledProgram(graph=g, plan=plan, group_impls=impls,
                            fn=jax.jit(program) if jit else program)
 
 
 def compile_plan_batched(g: Graph, plan: ExecutionPlan, max_batch: int = 8,
-                         hw: HardwareModel = V5E, interpret: bool = True,
+                         hw: HardwareModel = V5E, interpret: bool = False,
                          jit: bool = True) -> BatchedProgram:
     """ExecutionPlan -> vmap-batched executable (one dispatch per batch).
 
@@ -467,7 +496,7 @@ def compile_plan_batched(g: Graph, plan: ExecutionPlan, max_batch: int = 8,
     horizontal fusion.  Inter-group barriers are dropped (see
     ``_program_fn``)."""
     impls = plan.bind(g, hw)
-    fns = _group_fns(g, plan, impls, interpret)
+    fns = _group_fns(g, plan, impls, hw, interpret)
     program = _program_fn(plan, impls, fns, plan.backend, barrier=False)
     batched = jax.vmap(program)
     batched.__name__ = "batched_" + plan.signature[:8]
@@ -612,7 +641,7 @@ def _packed_program_fn(packed: PackedPlan, fns: list[Callable],
 
 def compile_plan_packed(graphs: Sequence[Graph], packed: PackedPlan,
                         max_batch: int = 8, hw: HardwareModel = V5E,
-                        interpret: bool = True, jit: bool = True
+                        interpret: bool = False, jit: bool = True
                         ) -> PackedProgram:
     """PackedPlan -> executable: ONE jitted whole-program function over
     N member graphs (DESIGN.md §9).
@@ -628,7 +657,7 @@ def compile_plan_packed(graphs: Sequence[Graph], packed: PackedPlan,
     for g, plan in zip(graphs, packed.members):
         impls = plan.bind(g, hw)
         member_impls.append(tuple(impls))
-        fns.extend(_group_fns(g, plan, impls, interpret))
+        fns.extend(_group_fns(g, plan, impls, hw, interpret))
     program = _packed_program_fn(packed, fns, packed.members[0].backend
                                  if packed.members else "jnp")
     return PackedProgram(graphs=tuple(graphs), packed=packed,
@@ -638,7 +667,7 @@ def compile_plan_packed(graphs: Sequence[Graph], packed: PackedPlan,
 
 
 def compile_combination(g: Graph, combo: Combination, backend: str = "jnp",
-                        interpret: bool = True, jit: bool = True,
+                        interpret: bool = False, jit: bool = True,
                         hw: HardwareModel = V5E) -> CompiledProgram:
     plan = build_plan(g, combo, backend=backend)
     return compile_plan(g, plan, hw=hw, interpret=interpret, jit=jit)

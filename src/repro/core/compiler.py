@@ -70,7 +70,7 @@ class CompileReport:
 
 class FusionCompiler:
     def __init__(self, hw: HardwareModel | str = V5E, backend: str = "jnp",
-                 interpret: bool = True, max_impls_per_fusion: int = 64,
+                 interpret: bool = False, max_impls_per_fusion: int = 64,
                  dtype=np.float32,
                  cache: PlanCache | bool | None = True,
                  autotune_budget: int = 8,
@@ -83,6 +83,11 @@ class FusionCompiler:
         ``mode="autotune"`` measures; it is part of the autotune cache
         keys (a bigger budget is a different — more thorough — search),
         while reps/warmup are measurement discipline only.
+
+        ``interpret=True`` runs ``backend="pallas"`` kernels in the
+        Pallas interpreter (any platform); the default compiles them
+        with Mosaic, which needs a TPU — a Pallas compile elsewhere
+        raises instead of falling back.
 
         ``verify`` selects the static-verification depth (DESIGN.md
         §11).  ``False``/default: the cheap always-on subset still runs

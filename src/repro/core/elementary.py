@@ -159,6 +159,14 @@ def _as_f32(x):
     return jnp.asarray(x, jnp.float32)
 
 
+def col(v):
+    """``v[..., :, None]`` — a vector as a column, for broadcasting down
+    the rows of a matrix block.  Spelled as the transpose of the row
+    view: Mosaic lowers that inside a Pallas kernel, while it refuses
+    the direct reshape of a lane-major vector into a column."""
+    return jnp.swapaxes(v[..., None, :], -1, -2)
+
+
 # ---------------------------------------------------------------------------
 # Constructors for the common kinds (convenience API used by libraries).
 # ---------------------------------------------------------------------------

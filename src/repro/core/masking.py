@@ -44,7 +44,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from .diagnostics import VerificationError
-from .elementary import Elementary, Monoid, make_map, make_nested_map
+from .elementary import (Elementary, Monoid, col, make_map,
+                         make_nested_map)
 from .graph import Graph, Var
 
 #: Reserved input name carrying per-lane validity (1.0 valid, 0.0 pad).
@@ -78,7 +79,7 @@ def mask_elementary(monoid: Monoid, rank: int, dim: int) -> Elementary:
     if rank == 2 and dim == 0:
         return make_nested_map(
             f"mask_{monoid.value}_r2d0",
-            lambda x, m: jnp.where(m[..., :, None] != 0, x, ident(x)),
+            lambda x, m: jnp.where(col(m) != 0, x, ident(x)),
             in_axes=[(0, 1), (0,)], flops_per_point=1, pad_safe=pad_safe)
     if rank == 2 and dim == 1:
         return make_nested_map(

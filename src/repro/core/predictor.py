@@ -10,8 +10,9 @@ paper's (calling order, routine variant, block size, serial iterations):
   per-grid-cell partials combined by a follow-up step (the paper's
   "extra kernel" reduction finalization, §3.2.2(i)).
 * **block sizes** per axis (must divide the axis size and respect the
-  128-lane / 8-sublane TPU tiling, the analogue of the paper's
-  32-element granularity).
+  128-lane / 8-sublane TPU tiling of every operand that axis indexes,
+  the analogue of the paper's 32-element granularity — see
+  ``block_granules`` and ``operand_carrier``).
 
 The predicted runtime is the paper's model:  ``t = max(t_transfer,
 t_compute) + t_launch`` assuming full overlap of DMA and compute
@@ -241,6 +242,81 @@ def _divisor_blocks(size: int, minimum: int, maximum: int | None = None) -> list
     return out or [size]
 
 
+# ---------------------------------------------------------------------------
+# operand layout at the Pallas kernel boundary (shared with codegen)
+# ---------------------------------------------------------------------------
+
+def _round_up(x: int, m: int) -> int:
+    return -(-x // m) * m
+
+
+def operand_carrier(shape: tuple[int, ...], block: tuple[int, ...], dtype,
+                    hw: HardwareModel) -> tuple[tuple[int, ...], tuple[int, ...]]:
+    """``(array shape, block shape)`` a value takes at the kernel boundary.
+
+    Mosaic tiles the last two dims of every operand (sublane, lane) and
+    refuses rank-1 blocks under a leading batch axis, so values are
+    carried as arrays of rank >= 2:
+
+    * a scalar is a ``(1, 1)`` array;
+    * a vector blocked in whole tiles (``b`` a multiple of ``sublane *
+      lane`` elements) is a lane-dense ``(n / lane, lane)`` view, blocked
+      ``(b / lane, lane)``;
+    * any other vector is one ``(1, n)`` row, blocked ``(1, b)`` (``b``
+      a multiple of the lane count, or the whole row);
+    * rank >= 2 values keep their shape and block.
+
+    The kernel reshapes rank-1 blocks back to ``(b,)`` for the
+    elementary bodies, so they stay block-polymorphic."""
+    if not shape:
+        return (1, 1), (1, 1)
+    if len(shape) == 1:
+        sub, lane = hw.min_tile_for(dtype)
+        (n,), (b,) = shape, block
+        if b % (sub * lane) == 0:
+            return (n // lane, lane), (b // lane, lane)
+        return (1, n), (1, b)
+    return tuple(shape), tuple(block)
+
+
+def padded_bytes(shape: tuple[int, ...], dtype, hw: HardwareModel) -> int:
+    """VMEM bytes of a carrier-shaped (rank >= 2) buffer: the last two
+    dims round up to the (sublane, lane) tile, as Mosaic lays them out."""
+    sub, lane = hw.min_tile_for(dtype)
+    *lead, s, l = shape
+    return (np.dtype(dtype).itemsize * math.prod(lead)
+            * _round_up(s, sub) * _round_up(l, lane))
+
+
+def block_granules(f: Fusion, g: Graph, hw: HardwareModel) -> dict[int, int]:
+    """Per axis root, the multiple every block of that axis must be
+    (the full axis is always legal): the strictest tiling rule of any
+    value the fusion touches, in each value's own memory order.
+
+    The last dim of a value takes the lane count (a vector's only dim is
+    the lane dim of its carrier), the second-to-last dim of a rank >= 2
+    value the sublane count.  All are powers of two, so the max is the
+    lcm."""
+    gran = {r: 1 for r in f.axis_roots}
+    vs = f.external_inputs + f.outputs + f.internal_vars
+    for v in vs:
+        roots = [g.axis_root(a) for a in v.axis_ids]
+        if not roots:
+            continue
+        sub, lane = hw.min_tile_for(v.dtype)
+        rules = [1] * (len(roots) - 2) + [sub, lane][-len(roots):]
+        for r, m in zip(roots, rules):
+            gran[r] = max(gran[r], m)
+    return gran
+
+
+def block_is_legal(size: int, block: int, granule: int) -> bool:
+    """The block rule ``enumerate_impls`` applies (and the verifier
+    re-checks): the whole axis, or a divisor that is a granule multiple."""
+    return block == size or (0 < block < size and size % block == 0
+                             and block % granule == 0)
+
+
 def var_streams(v: Var, g: Graph, order: tuple[int, ...], grid: tuple[int, ...]) -> int:
     """How many times ``v`` is streamed from HBM for a given grid order.
 
@@ -306,13 +382,15 @@ def cost_impl(f: Fusion, g: Graph, order: tuple[int, ...],
     flops = n_phases * sum(c.elem.flops(c.axis_sizes) for c in f.calls)
 
     # ---- VMEM footprint (double-buffered blocks) ---------------------------
+    # every buffer counts at its padded carrier size — what codegen's
+    # BlockSpecs and scratch shapes actually occupy (a partials block
+    # only adds leading unit dims, which pad nothing)
+    def carrier(v: Var):
+        block = tuple(blk[g.axis_root(a)] for a in v.axis_ids)
+        return operand_carrier(v.shape, block, v.dtype, hw)
+
     def block_bytes(v: Var) -> float:
-        n = v.dtype.itemsize
-        for a in v.axis_ids:
-            r = g.axis_root(a)
-            n *= blk.get(r, 1)
-        sub, lane = hw.min_tile_for(v.dtype)
-        return max(n, v.dtype.itemsize * sub * lane)
+        return padded_bytes(carrier(v)[1], v.dtype, hw)
 
     vmem = 0.0
     for v in f.external_inputs:
@@ -323,9 +401,7 @@ def cost_impl(f: Fusion, g: Graph, order: tuple[int, ...],
         vmem += block_bytes(v)
     for c in consumed:
         # full-size scratch accumulator carrying the finished reduction
-        v = c.out
-        sub, lane = hw.min_tile_for(v.dtype)
-        vmem += max(v.nbytes, v.dtype.itemsize * sub * lane)
+        vmem += padded_bytes(carrier(c.out)[0], c.out.dtype, hw)
 
     dt = fusion_dtype(f)
     t_t = traffic / hw.hbm_bw
@@ -340,6 +416,8 @@ def enumerate_impls(f: Fusion, g: Graph, hw: HardwareModel = V5E,
                     max_impls: int = 64) -> list[Impl]:
     """All (order × block) implementations of a fusion, pruned.
 
+    Block sizes per axis come from ``block_granules``, so every emitted
+    block is one Mosaic accepts for every operand the axis indexes.
     Pruning (paper §4.2): drop implementations that exceed the VMEM
     budget (the occupancy analogue) and Pareto-dominated ones.
 
@@ -354,21 +432,15 @@ def enumerate_impls(f: Fusion, g: Graph, hw: HardwareModel = V5E,
     """
     roots, sizes = f.axis_roots, f.axis_sizes
     depth = len(roots)
-    dt = fusion_dtype(f)
-    min_tile = hw.min_tile_for(dt)
+    gran = block_granules(f, g, hw)
     consumed = consumed_reductions(f, g)
     cands: list[Impl] = []
     if depth == 1:
-        min_b = min_tile[1]
-        for b in _divisor_blocks(sizes[0], min_b, maximum=1 << 22):
+        for b in _divisor_blocks(sizes[0], gran[roots[0]], maximum=1 << 22):
             cands.append(cost_impl(f, g, roots, (b,), hw))
     else:
-        # the last two canonical axes are the in-memory (sublane, lane)
-        # pair and carry the tiling minima; axes above them (depth >= 3:
-        # batch-like dims) may block at any divisor
-        mins = [1] * (depth - 2) + [min_tile[0], min_tile[1]]
         blocks_per_axis = [
-            _divisor_blocks(sizes[k], mins[k], maximum=1 << 16)
+            _divisor_blocks(sizes[k], gran[roots[k]], maximum=1 << 16)
             for k in range(depth)
         ]
         for order in itertools.permutations(range(depth)):

@@ -12,10 +12,9 @@ Two submodules:
   there are more devices than experts), numerically equivalent to the
   GSPMD ``models.common.moe_layer`` and differentiable end to end.
 
-Version notes: the package imports (and its pspec builders work) on any
-jax with ``NamedSharding``; the ambient-mesh convenience paths
-(``jax.sharding.set_mesh``) need jax >= 0.6.  Everything also accepts an
-explicit ``mesh=`` argument, which is what the tier-1 tests use.
+Everything accepts an explicit ``mesh=`` argument, which is what the
+tier-1 tests use; the ambient-mesh paths read ``jax.sharding.set_mesh``
+or a ``with mesh:`` context.
 """
 from . import moe_ep, sharding
 

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from .sharding import (axis_product, current_mesh, dp_axes, mesh_axis_sizes,
-                       shard_map_compat)
+                       shard_map_unchecked)
 
 
 def supported(cfg, mesh=None) -> bool:
@@ -64,8 +64,7 @@ def moe_layer_ep(cfg, x, p, mesh=None):
       p: param dict — ``router (D, E)``, ``wg``/``wu`` ``(E, D, F)``,
         ``wd (E, F, D)``, optional ``wg_s``/``wu_s``/``wd_s``.
       mesh: mesh to partition over; defaults to the ambient mesh
-        (``jax.sharding.set_mesh`` on jax >= 0.6, ``with mesh:`` on
-        older jax).
+        (``jax.sharding.set_mesh`` or ``with mesh:``).
 
     Returns:
       ``(y, aux)``: ``(G, Tg, D)`` outputs and the scalar Switch-style
@@ -129,7 +128,7 @@ def moe_layer_ep(cfg, x, p, mesh=None):
             h = jax.nn.gelu(h)
         return jnp.einsum("gecf,efd->gecd", h, wd_l)
 
-    run = shard_map_compat(
+    run = shard_map_unchecked(
         ffn, mesh,
         in_specs=(P(gax, "model", None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),

@@ -23,10 +23,9 @@ the builders never emit an uneven sharding, so any mesh from
 so one global request batch executes as per-replica row blocks on the
 ``data`` axis — the sharded serving engine's dispatch path.
 
-The module works with an explicit ``mesh`` argument on any supported
-jax; ``current_mesh()`` additionally picks up the ambient mesh set by
-``jax.sharding.set_mesh`` (jax >= 0.6) or a ``with mesh:`` context
-(older jax).
+Every helper takes an explicit ``mesh``; ``current_mesh()`` additionally
+picks up the ambient mesh set by ``jax.sharding.set_mesh`` or a
+``with mesh:`` context.
 """
 from __future__ import annotations
 
@@ -37,37 +36,25 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 
 # ---------------------------------------------------------------------------
-# mesh helpers (version compatible)
+# mesh helpers
 # ---------------------------------------------------------------------------
 
 def current_mesh(mesh=None):
     """The mesh to shard over: ``mesh`` if given, else the ambient one.
 
-    Checks, in order: the explicit argument, the concrete/abstract mesh
-    installed by ``jax.sharding.set_mesh`` (jax >= 0.6), and the
-    ``with mesh:`` context mesh of older jax.  Returns ``None`` when no
-    mesh is active.
+    Checks, in order: the explicit argument, the (abstract) mesh
+    installed by ``jax.sharding.set_mesh``, and the mesh of a
+    ``with mesh:`` context (which jax records only as the
+    thread-resource mesh).  Returns ``None`` when no mesh is active.
     """
     if mesh is not None:
         return mesh
-    for getter in ("get_concrete_mesh", "get_abstract_mesh"):
-        fn = getattr(jax.sharding, getter, None)
-        if fn is None:
-            continue
-        try:
-            m = fn()
-        except Exception:
-            continue
-        if m is not None and getattr(m, "axis_names", ()):
-            return m
-    try:  # jax < 0.6: `with mesh:` sets the thread-resource mesh
-        from jax.interpreters import pxla
-        m = pxla.thread_resources.env.physical_mesh
-        if m is not None and m.axis_names:
-            return m
-    except Exception:
-        pass
-    return None
+    m = jax.sharding.get_abstract_mesh()
+    if m.axis_names:
+        return m
+    from jax.interpreters import pxla
+    m = pxla.thread_resources.env.physical_mesh
+    return m if m.axis_names else None
 
 
 def mesh_axis_sizes(mesh) -> dict[str, int]:
@@ -105,29 +92,12 @@ def mesh_fingerprint(mesh) -> str:
     return repr((tuple(mesh_axis_sizes(mesh).items()), ids))
 
 
-def shard_map_compat(f: Callable, mesh, in_specs, out_specs) -> Callable:
-    """``shard_map`` across jax versions.
-
-    Prefers ``jax.shard_map`` (jax >= 0.6, ``check_vma``) and falls back
-    to ``jax.experimental.shard_map.shard_map`` (``check_rep``).
-    Replication checking is disabled: bodies here are collective-free
+def shard_map_unchecked(f: Callable, mesh, in_specs, out_specs) -> Callable:
+    """``jax.shard_map`` with replication checking off: bodies here are
     per-shard programs whose unmentioned-axis replication is true by
-    construction.
-    """
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        for kw in ({"check_vma": False}, {}):
-            try:
-                return sm(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kw)
-            except TypeError:
-                continue
-    from jax.experimental.shard_map import shard_map as sm
-    try:
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
-    except TypeError:  # pragma: no cover - future jax without check_rep
-        return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    construction."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 # ---------------------------------------------------------------------------
@@ -311,7 +281,7 @@ def shard_program(prog, mesh, axis: str = "data"):
         raise ValueError("program carries no raw_fn; compile it with "
                          "FusionCompiler.compile_batched")
     spec = P(axis)
-    fn = shard_map_compat(
+    fn = shard_map_unchecked(
         prog.raw_fn, mesh,
         in_specs=(spec,) * len(prog.plan.input_names),
         out_specs=(spec,) * len(prog.plan.outputs))

@@ -39,7 +39,7 @@ def _adamw_kernel(h_ref, p_ref, g_ref, m_ref, v_ref,
                    static_argnames=("block_rows", "interpret"))
 def adamw_update(p, g, m, v, *, lr, beta1=0.9, beta2=0.95, eps=1e-8,
                  weight_decay=0.0, step=1, block_rows: int = 512,
-                 interpret: bool = True):
+                 interpret: bool = False):
     """Flat 1-D p/g/m/v of equal length N (N % 128 == 0 after caller pads).
 
     Returns (p', m', v').  m, v are f32; p may be bf16/f32.

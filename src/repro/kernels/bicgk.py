@@ -27,7 +27,7 @@ def _bicgk_kernel(A_ref, p_ref, r_ref, qp_ref, s_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_cols", "interpret"))
 def bicgk(A: jax.Array, p: jax.Array, r: jax.Array, *,
-          block_cols: int = 512, interpret: bool = True):
+          block_cols: int = 512, interpret: bool = False):
     """A: (m, n); p: (n,); r: (m,).  Returns (q, s)."""
     m, n = A.shape
     bj = min(block_cols, n)

@@ -47,7 +47,8 @@ def _decode_attn_kernel(q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("block_kv", "interpret"))
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                     block_kv: int = 512, interpret: bool = True) -> jax.Array:
+                     block_kv: int = 512,
+                     interpret: bool = False) -> jax.Array:
     """q: (B, Hq, d); k, v: (B, S, Hkv, d) -> (B, Hq, d)."""
     B, Hq, d = q.shape
     _, S, Hkv, _ = k.shape

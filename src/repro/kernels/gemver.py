@@ -43,7 +43,7 @@ def _k2(B_ref, x_ref, a_ref, w_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def gemver(A, u1, v1, u2, v2, y, z, alpha, beta, *,
-           block_rows: int = 256, interpret: bool = True):
+           block_rows: int = 256, interpret: bool = False):
     m, n = A.shape
     bi = min(block_rows, m)
     while m % bi:

@@ -24,7 +24,7 @@ def _rmsnorm_kernel(x_ref, g_ref, o_ref, *, eps: float):
 
 @functools.partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm(x: jax.Array, gamma: jax.Array, *, eps: float = 1e-6,
-            block_rows: int = 256, interpret: bool = True) -> jax.Array:
+            block_rows: int = 256, interpret: bool = False) -> jax.Array:
     """x: (T, D), gamma: (D,) -> (T, D).  T must divide by block_rows."""
     T, D = x.shape
     br = min(block_rows, T)

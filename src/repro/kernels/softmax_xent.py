@@ -26,7 +26,7 @@ def _xent_kernel(logits_ref, labels_ref, loss_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def softmax_xent(logits: jax.Array, labels: jax.Array, *,
-                 block_rows: int = 8, interpret: bool = True) -> jax.Array:
+                 block_rows: int = 8, interpret: bool = False) -> jax.Array:
     """logits (T, V), labels (T,) int32 -> mean cross-entropy (scalar)."""
     T, V = logits.shape
     br = min(block_rows, T)

@@ -191,8 +191,6 @@ def cost_summary(compiled) -> dict[str, Any]:
         ca = compiled.cost_analysis()
     except Exception as e:  # pragma: no cover
         return {"error": repr(e)}
-    if isinstance(ca, (list, tuple)):       # jax < 0.5 returns [dict]
-        ca = ca[0] if ca else None
     if not ca:
         return {"error": "cost_analysis unavailable"}
     return {"hlo_flops": float(ca.get("flops", 0.0)),

@@ -3,16 +3,10 @@ this module never touches jax device state."""
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 names mesh axis types; older jax has Auto-only meshes
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - exercised on jax 0.4.x
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _mk(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

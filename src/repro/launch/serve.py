@@ -13,12 +13,15 @@ function.
         --requests 200 --n 1024
 
 ``--backend pallas`` serves the same program through the pallas backend
-instead — every fused group one ``pl.pallas_call`` (interpret mode
-off-TPU), including multi-phase kernels that consume finished
-reductions in-kernel (DESIGN.md §2):
+instead — every fused group one ``pl.pallas_call``, including
+multi-phase kernels that consume finished reductions in-kernel
+(DESIGN.md §2).  The kernels compile with Mosaic, which needs a TPU;
+``--interpret`` runs them in the Pallas interpreter on any platform:
 
+    PYTHONPATH=src python -m repro.launch.serve --blas GEMVER \
+        --backend pallas --requests 8 --n 16384          # on a TPU
     PYTHONPATH=src python -m repro.launch.serve --blas ATAX \
-        --backend pallas --requests 4 --n 256
+        --backend pallas --interpret --requests 4 --n 256
 
 Empirical autotuning (DESIGN.md §8): ``--autotune`` compiles with
 ``mode="autotune"`` — the top ``--budget`` predicted combinations are
@@ -76,7 +79,7 @@ def serve_blas(args) -> dict:
     # the autotune budget is spent on) meaningful off-TPU
     hw = "calibrate" if args.autotune else V5E
     cc = FusionCompiler(cache=cache, hw=hw, autotune_budget=args.budget,
-                        backend=args.backend)
+                        backend=args.backend, interpret=args.interpret)
 
     t0 = time.perf_counter()
     prog = cc.compile(seq.script, seq.shapes(args.n), mode=mode)
@@ -141,8 +144,9 @@ def serve_engine(args) -> dict:
         sizes = [64, 100, 128] if args.quick else [256, 1000, 1024, 2048]
 
     mode = "autotune" if args.autotune else "best"
-    cc = (FusionCompiler(hw="calibrate", autotune_budget=args.budget)
-          if args.autotune else None)
+    cc = (FusionCompiler(hw="calibrate", autotune_budget=args.budget,
+                         interpret=args.interpret)
+          if args.autotune else FusionCompiler(interpret=args.interpret))
     if args.sharded:
         # sharded engine pins max_pack=1 (DESIGN.md §9 open edge)
         engine = ShardedServingEngine(compiler=cc, max_batch=args.max_batch,
@@ -211,7 +215,11 @@ def main(argv=None):
     ap.add_argument("--backend", default="jnp",
                     help="codegen backend for --blas serving: 'jnp' "
                     "(XLA sub-functions) or 'pallas' (one pallas_call "
-                    "per fused group; interpret mode off-TPU)")
+                    "per fused group, compiled for a TPU)")
+    ap.add_argument("--interpret", action="store_true",
+                    help="with --backend pallas: run the kernels in the "
+                    "Pallas interpreter (any platform) instead of "
+                    "compiling them for a TPU")
     ap.add_argument("--sharded", action="store_true",
                     help="with --engine: shard dispatches over the "
                     "'data' axis of a replica mesh (DESIGN.md §7)")
@@ -264,8 +272,9 @@ def main(argv=None):
             f"unknown backend {args.backend!r}",
             f"valid backends: {', '.join(KNOWN_BACKENDS)}")
 
-    from repro.launch import force_host_devices
+    from repro.launch import enable_compile_cache, force_host_devices
     force_host_devices(args.devices)
+    enable_compile_cache()
 
     if args.blas:
         return serve_engine(args) if args.engine else serve_blas(args)

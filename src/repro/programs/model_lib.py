@@ -9,10 +9,10 @@ fused whole-program XLA computation reproduces the corresponding
 ``repro.kernels.ref`` / ``repro.models`` oracle *bit for bit* on CPU
 XLA.  Two non-obvious consequences:
 
-* the attention contractions are phrased as the reference's 4-D einsums
-  with unit head/group dims — ``jnp.dot(K, q)`` contracts the same
-  numbers but XLA lowers it to a differently-associated loop and the
-  low bits diverge;
+* the value contraction is phrased as the reference's 4-D einsum with
+  unit head/group dims — a 2-D contraction of the same numbers can
+  lower to a differently-associated loop whose low bits diverge (the
+  score contraction is 2-D: see ``attn_score``);
 * AdamW takes ``1 - beta`` and the bias corrections as *inputs*
   (``omb*``, ``c*``) rather than computing them from ``beta`` in f32:
   ``f32(0.9)``-derived ``1 - b`` is 0.100000024 while the reference's
@@ -51,21 +51,22 @@ div_by = make_map(
 
 # --- attention contractions --------------------------------------------------
 
-# s_s = sum_d K_sd q_d — the decode score row.  Phrased as the
-# reference's GQA einsum with unit h/g dims (see module docstring).
+# s_s = sum_d K_sd q_d — the decode score row.  A plain 2-D contraction:
+# the reference's 4-D GQA phrasing (below) pads every K row of a Pallas
+# block to its own (8, 128) tile, tens of KiB of VMEM per row.
 attn_score = make_nested_map_reduce(
     "attn_score",
-    lambda K, q: jnp.einsum(
-        "...hgd,...shd->...hgs",
-        q[..., None, None, :], K[..., :, None, :])[..., 0, 0, :],
+    lambda K, q: jnp.einsum("...sd,...d->...s", K, q, precision="highest"),
     in_axes=[(0, 1), (1,)], out_axis=0, flops_per_point=2)
 
-# o_d = sum_s w_s V_sd — the weighted value sum, same einsum phrasing.
+# o_d = sum_s w_s V_sd — the weighted value sum, phrased as the
+# reference's GQA einsum with unit h/g dims (see module docstring).
 attn_out = make_nested_map_reduce(
     "attn_out",
     lambda V, w: jnp.einsum(
         "...hgs,...shd->...hgd",
-        w[..., None, None, :], V[..., :, None, :])[..., 0, 0, :],
+        w[..., None, None, :], V[..., :, None, :],
+        precision="highest")[..., 0, 0, :],
     in_axes=[(0, 1), (0,)], out_axis=1, flops_per_point=2)
 
 # --- AdamW (precision-matched variants of repro.optim.fused) -----------------

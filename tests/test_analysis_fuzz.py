@@ -150,6 +150,16 @@ def test_mutant_oversized_block():
     _reject(d, g, {"RPL213"})
 
 
+@pytest.mark.parametrize("backend", ["jnp", "pallas"])
+def test_mutant_block_off_the_mosaic_tiling(backend):
+    """A vector axis blocked at 8 (a divisor, but not a multiple of the
+    128-lane tile) is a kernel Mosaic refuses.  The block rule has one
+    owner, the predictor, so the verifier rejects it on either backend."""
+    d, g = _fixture("AXPYDOT", backend=backend, n=256)
+    d["groups"][0]["blocks"][0] = 8
+    _reject(d, g, {"RPL213"})
+
+
 def test_mutant_zero_n_outputs():
     d, g = _fixture("AXPYDOT")
     d["groups"][0]["n_outputs"] = 0

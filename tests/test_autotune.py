@@ -668,14 +668,14 @@ class TestCompileAll:
     def test_records_stats_and_reuses_cache(self):
         cache = PlanCache()
         cc = FusionCompiler(cache=cache)
-        seq = REGISTRY["GEMVER"]
-        res1 = cc.compile_all(seq.script, seq.shapes(128), limit=4)
+        seq = REGISTRY["GEMVER"]       # n=256: >= 4 legal combinations
+        res1 = cc.compile_all(seq.script, seq.shapes(256), limit=4)
         assert len(res1) == 4
         assert cache.stats.plan_misses == 4      # visible to telemetry
         ts = [c.t_pred for c, _ in res1]
         assert ts == sorted(ts)
 
-        res2 = cc.compile_all(seq.script, seq.shapes(128), limit=4)
+        res2 = cc.compile_all(seq.script, seq.shapes(256), limit=4)
         assert cache.stats.program_hits == 4     # fully served from cache
         assert [c.t_pred for c, _ in res2] == ts
         assert all(p2 is p1 for (_, p1), (_, p2) in zip(res1, res2))

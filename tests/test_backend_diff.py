@@ -76,7 +76,7 @@ def test_all_combinations_match_across_backends(name):
         jnp_out = _outputs(codegen.compile_combination(
             g, combo, backend="jnp"), env)
         pl_out = _outputs(codegen.compile_combination(
-            g, combo, backend="pallas"), env)
+            g, combo, backend="pallas", interpret=True), env)
         for o_p, o_j, r in zip(pl_out, jnp_out, ref):
             o_p, o_j = np.asarray(o_p), np.asarray(o_j)
             if bitwise:
@@ -112,7 +112,7 @@ def test_consuming_fusion_exists_and_validates(name):
     jnp_out = _outputs(codegen.compile_combination(
         g, consuming[0], backend="jnp"), env)
     pl_out = _outputs(codegen.compile_combination(
-        g, consuming[0], backend="pallas"), env)
+        g, consuming[0], backend="pallas", interpret=True), env)
     for o_p, o_j in zip(pl_out, jnp_out):
         np.testing.assert_allclose(np.asarray(o_p), np.asarray(o_j),
                                    rtol=1e-4, atol=1e-3)
@@ -137,7 +137,8 @@ def test_no_program_forced_to_singletons():
             assert any(len(im.fusion.calls) > 1 for im in best.impls), (
                 f"{name}: space has multi-call fusions but the best "
                 f"combination is all singletons")
-        codegen.compile_combination(g, best, backend="pallas", jit=False)
+        codegen.compile_combination(g, best, backend="pallas",
+                                    interpret=True, jit=False)
 
 
 def test_attn_softmax_is_three_phases():
@@ -166,7 +167,8 @@ def test_masked_engine_pallas_matches_jnp(name):
     engines = {}
     results = {}
     for backend in ("jnp", "pallas"):
-        eng = ServingEngine(compiler=FusionCompiler(cache=PlanCache()),
+        eng = ServingEngine(compiler=FusionCompiler(cache=PlanCache(),
+                                                    interpret=True),
                             max_batch=4, min_bucket=128,
                             registry=REGISTRY, backend=backend)
         reqs = [(name, n, make_inputs(REGISTRY[name], n, seed=i))
@@ -209,9 +211,22 @@ def _atax_bad_impl():
 def test_bad_order_raises_clear_error():
     g, f, im = _atax_bad_impl()
     with pytest.raises(NotImplementedError, match=r"gemv\+gemtv"):
-        codegen._group_pallas_fn(g, im)
+        codegen._group_pallas_fn(g, im, interpret=True)
     with pytest.raises(NotImplementedError, match="innermost suffix"):
-        codegen._group_pallas_fn(g, im)
+        codegen._group_pallas_fn(g, im, interpret=True)
+
+
+def test_pallas_without_interpret_raises_off_tpu():
+    """Compiled Mosaic kernels need a TPU: off-TPU, a Pallas compile
+    that does not ask for interpret mode raises — it never falls back
+    to the interpreter in silence."""
+    prog = REGISTRY["AXPYDOT"]
+    cc = FusionCompiler(backend="pallas", cache=None)
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        cc.compile(prog.script, prog.shapes(256))
+    # the interpreter, asked for, serves the same compile
+    FusionCompiler(backend="pallas", cache=None, interpret=True).compile(
+        prog.script, prog.shapes(256))
 
 
 def test_compile_surfaces_group_names():
@@ -221,7 +236,7 @@ def test_compile_surfaces_group_names():
     combo = Combination(impls=(im,), t_pred=im.t_pred)
     plan = build_plan(g, combo, backend="pallas")
     with pytest.raises(NotImplementedError, match=r"gemv\+gemtv"):
-        codegen.compile_plan(g, plan, jit=False)
+        codegen.compile_plan(g, plan, interpret=True, jit=False)
 
 
 def test_measure_group_times_multiphase_pallas_kernel():
@@ -250,4 +265,4 @@ def test_enumerated_impls_never_raise():
             if not consumed_reductions(f, g):
                 continue
             for im in space.impls_by_fusion[f.key]:
-                codegen._group_pallas_fn(g, im)  # must not raise
+                codegen._group_pallas_fn(g, im, interpret=True)  # no raise

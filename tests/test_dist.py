@@ -1,8 +1,8 @@
 """repro.dist + sharded serving (DESIGN.md §7), explicit-mesh path.
 
 Unlike tests/test_system.py and tests/test_moe_ep.py (which drive the
-``jax.sharding.set_mesh`` ambient-mesh API and need jax >= 0.6), these
-tests pass meshes explicitly, so they run on any supported jax.  The
+``jax.sharding.set_mesh`` ambient-mesh API), these tests pass meshes
+explicitly.  The
 multi-device cases run in subprocesses: the forced host device count
 must be set before jax initializes.
 """
@@ -142,7 +142,7 @@ for tag, (E, k) in {"ep": (4, 2), "replica": (2, 1)}.items():
     out[tag + "_gnorm"] = float(jnp.sqrt(sum(
         jnp.sum(v.astype(jnp.float32)**2)
         for v in jax.tree_util.tree_leaves(g))))
-    # `with mesh:` ambient resolution (the pre-0.6 context manager)
+    # `with mesh:` ambient resolution (the context-manager form)
     with mesh:
         assert moe_ep.supported(cfg)
         y_amb, _ = jax.jit(lambda x, p: moe_ep.moe_layer_ep(cfg, x, p))(x, p)

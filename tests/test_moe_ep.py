@@ -6,21 +6,9 @@ import os
 import subprocess
 import sys
 
-import jax
-import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
-
-def _unsupported() -> str | None:
-    """Explicit environment guard: skip (not error) when the
-    ambient-mesh API this test drives isn't available.  ``repro.dist``
-    itself runs on any supported jax — tests/test_dist.py covers the
-    explicit-mesh path — but this script uses
-    ``jax.sharding.set_mesh``."""
-    if not hasattr(jax.sharding, "set_mesh"):
-        return f"jax {jax.__version__} lacks jax.sharding.set_mesh (needs >= 0.6)"
-    return None
 
 SCRIPT = r"""
 import os
@@ -67,9 +55,6 @@ print(json.dumps(out))
 
 
 def test_moe_ep_matches_gspmd_and_has_grads():
-    reason = _unsupported()
-    if reason:
-        pytest.skip(reason)
     script = SCRIPT.format(repo=REPO)
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
                        text=True, timeout=600)

@@ -131,7 +131,8 @@ def test_synthetic_chain_backends_agree(n_calls, reduce_consume, gemv,
               for k, s in shapes.items()}
     want = reference(**inputs)
     jnp_prog = codegen.compile_combination(g, combo, backend="jnp")
-    pl_prog = codegen.compile_combination(g, combo, backend="pallas")
+    pl_prog = codegen.compile_combination(g, combo, backend="pallas",
+                                          interpret=True)
     jnp_out = jnp_prog(**inputs)
     pl_out = pl_prog(**inputs)
     if not isinstance(jnp_out, tuple):

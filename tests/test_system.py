@@ -17,15 +17,36 @@ from repro.core import FusionCompiler
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _dist_unsupported() -> str | None:
-    """Guard for the distributed subprocess tests: skip (not error) when
-    the ambient-mesh API they drive isn't available.  ``repro.dist``
-    itself runs on any supported jax — tests/test_dist.py exercises it
-    with explicit meshes — but these subprocess scripts use
-    ``jax.sharding.set_mesh``."""
-    if not hasattr(jax.sharding, "set_mesh"):
-        return f"jax {jax.__version__} lacks jax.sharding.set_mesh (needs >= 0.6)"
-    return None
+def test_compile_cache_dir(monkeypatch, tmp_path):
+    """Entry points keep JAX's persistent compilation cache where
+    JAX_COMPILATION_CACHE_DIR says (and then set nothing themselves),
+    else in the fixed <checkout>/.jax_cache."""
+    from repro.launch import CHECKOUT, enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = str(CHECKOUT / ".jax_cache")
+        assert enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert (CHECKOUT / "chip_smoke.py").is_file()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_compile_cache_needs_a_checkout(monkeypatch):
+    """An installed package has no checkout to keep .jax_cache in: the
+    helper then asks for JAX_COMPILATION_CACHE_DIR instead of writing
+    next to the installation."""
+    import repro.launch as launch
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.setattr(launch, "CHECKOUT", None)
+    with pytest.raises(RuntimeError, match="JAX_COMPILATION_CACHE_DIR"):
+        launch.enable_compile_cache()
+    assert jax.config.jax_compilation_cache_dir == before
 
 
 def test_end_to_end_bicg_solver_iteration():
@@ -117,9 +138,6 @@ print(json.dumps({{"ok": True,
 def test_multipod_lowering_smoke(arch, kind):
     """(2,2,2) pod/data/model mesh on 8 host devices: lower+compile the
     real step functions for reduced configs; collectives must appear."""
-    reason = _dist_unsupported()
-    if reason:
-        pytest.skip(reason)
     script = SUBPROC_SCRIPT.format(repo=REPO, arch=arch, kind=kind)
     out = subprocess.run([sys.executable, "-c", script],
                          capture_output=True, text=True, timeout=600)
