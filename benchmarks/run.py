@@ -111,24 +111,6 @@ def main() -> None:
                 + fk3_rows):
         print(row)
 
-    # --- roofline summary (reads cached dry-run artifacts if present) -------
-    try:
-        from benchmarks import roofline
-        from repro.configs import ARCHS
-        ok = 0
-        for arch in ARCHS:
-            r = roofline.cell_roofline(arch, "train_4k", "pod1")
-            if r and r.get("ok"):
-                ok += 1
-                print(f"ROOFLINE_{arch}_train4k,"
-                      f"{r['step_lower_bound_s']*1e6:.0f},"
-                      f"dominant={r['dominant']} "
-                      f"frac={r['roofline_fraction']:.2f}")
-        if not ok:
-            print("ROOFLINE,0,run repro.launch.dryrun first", file=sys.stderr)
-    except Exception as e:  # pragma: no cover
-        print(f"ROOFLINE,0,error:{e}", file=sys.stderr)
-
 
 if __name__ == '__main__':
     from repro.launch import enable_compile_cache

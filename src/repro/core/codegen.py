@@ -39,6 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import re
 from typing import Any, Callable, Sequence
 
 import jax
@@ -47,6 +48,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import tracing
 from .diagnostics import UnsupportedGroupError, VerificationError
 from .elementary import Monoid
 from .fusion import Fusion, call_phases, consumed_reductions
@@ -77,6 +79,15 @@ def execute_dense(g: Graph, env: dict[str, Any]):
 # ---------------------------------------------------------------------------
 # group executors
 # ---------------------------------------------------------------------------
+
+def group_label(i: int, f: Fusion) -> str:
+    """The name of group ``i`` of a plan: its position and its calls'
+    names, identifier-safe (``g0_rank2_update_gemtv``).  The group's
+    ``named_scope`` and its Pallas kernel carry it into the device trace,
+    where it stays the same from one compile of a plan to the next."""
+    names = "_".join(c.elem.name for c in f.calls)
+    return re.sub(r"\W", "_", f"g{i}_{names}")
+
 
 def _group_dense_fn(f: Fusion) -> Callable:
     """Pure function (ext_inputs...) -> (outputs...) for one fused group."""
@@ -111,7 +122,8 @@ def _require_pallas_platform(interpret: bool) -> None:
 
 
 def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
-                     interpret: bool = False) -> Callable:
+                     interpret: bool = False,
+                     name: str | None = None) -> Callable:
     """Build the single pallas_call for one fused group.
 
     Groups whose reductions are only *produced* (never consumed inside)
@@ -134,7 +146,8 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
     >= 2, lane-dense vectors, ``(1, 1)`` scalars), the layout the
     predictor's block legality and VMEM count assume; the body reshapes
     blocks back to the elementaries' natural ranks.  ``interpret=False``
-    compiles Mosaic kernels and needs a TPU.
+    compiles Mosaic kernels and needs a TPU.  ``name`` names the kernel
+    (``group_label``).
     """
     _require_pallas_platform(interpret)
     f = impl.fusion
@@ -335,7 +348,7 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
     call = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
         out_shape=tuple(out_shapes), interpret=interpret,
-        scratch_shapes=tuple(scratch_shapes),
+        scratch_shapes=tuple(scratch_shapes), name=name,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=hw.vmem_bytes + VMEM_HEADROOM_BYTES),
     )
@@ -376,8 +389,15 @@ class CompiledProgram:
     def n_groups(self) -> int:
         return len(self.plan.groups)
 
+    @property
+    def group_labels(self) -> list[str]:
+        """Each group's name in a device trace, in ``group_impls`` order."""
+        return [group_label(i, im.fusion)
+                for i, im in enumerate(self.group_impls)]
+
     def __call__(self, **inputs):
-        outs = self.fn(*_gather_args(self.plan, inputs))
+        with tracing.span(tracing.DISPATCH):
+            outs = self.fn(*_gather_args(self.plan, inputs))
         return outs[0] if len(outs) == 1 else outs
 
     def block_until_ready(self, result):
@@ -439,7 +459,9 @@ def _program_fn(plan: ExecutionPlan, impls: list[Impl], fns: list[Callable],
 
     ``barrier=False`` drops the inter-group ``optimization_barrier`` —
     desirable for serving, where XLA fusing across the chosen kernel
-    boundaries is pure upside."""
+    boundaries is pure upside.  Each group runs in a ``named_scope`` of
+    its ``group_label``."""
+    labels = [group_label(i, im.fusion) for i, im in enumerate(impls)]
 
     def read(ref, inputs, group_outs):
         if ref[0] == "input":
@@ -449,8 +471,9 @@ def _program_fn(plan: ExecutionPlan, impls: list[Impl], fns: list[Callable],
     def program(*input_vals):
         inputs = dict(zip(plan.input_names, input_vals))
         group_outs: list[tuple] = []
-        for gp, fn in zip(plan.groups, fns):
-            outs = fn(*[read(r, inputs, group_outs) for r in gp.inputs])
+        for gp, fn, label in zip(plan.groups, fns, labels):
+            with jax.named_scope(label):
+                outs = fn(*[read(r, inputs, group_outs) for r in gp.inputs])
             if barrier and backend == "jnp" and len(plan.groups) > 1:
                 # kernel boundary: stop XLA fusing across groups
                 outs = jax.lax.optimization_barrier(outs)
@@ -464,11 +487,12 @@ def _program_fn(plan: ExecutionPlan, impls: list[Impl], fns: list[Callable],
 def _group_fns(g: Graph, plan: ExecutionPlan, impls: list[Impl],
                hw: HardwareModel, interpret: bool) -> list[Callable]:
     fns = []
-    for im in impls:
+    for i, im in enumerate(impls):
         if plan.backend == "jnp":
             fns.append(_group_dense_fn(im.fusion))
         elif plan.backend == "pallas":
-            fns.append(_group_pallas_fn(g, im, hw=hw, interpret=interpret))
+            fns.append(_group_pallas_fn(g, im, hw=hw, interpret=interpret,
+                                        name=group_label(i, im.fusion)))
         else:
             raise VerificationError.single(
                 "RPL401", "plan.backend",

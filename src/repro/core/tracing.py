@@ -1,0 +1,20 @@
+"""Names the program gives its work in a profiler trace.
+
+* Host span ``DISPATCH`` (``repro.dispatch``): ``CompiledProgram.__call__``,
+  from gathering the arguments to the jitted call's return.
+* Group labels (``codegen.group_label``, e.g. ``g0_rank2_update_gemtv``):
+  each fused group's ``jax.named_scope`` and, on the Pallas backend, its
+  kernel's name, so the device trace names a group's kernel the same way
+  whatever the plan's signature.
+
+A span is a ``jax.profiler.TraceAnnotation``: with no profiler running it
+costs well under a microsecond, so it is always on.
+"""
+import jax
+
+DISPATCH = "repro.dispatch"
+
+
+def span(name: str):
+    """A host span named ``name`` on the profiler's clock."""
+    return jax.profiler.TraceAnnotation(name)

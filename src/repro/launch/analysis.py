@@ -1,6 +1,6 @@
 """Compiled-artifact analysis: memory, HLO cost, collective inventory.
 
-Used by the dry-run and the roofline harness.  No device-state side
+Used by the dry-run.  No device-state side
 effects — safe to import from tests.
 
 Scan caveat (measured, see EXPERIMENTS.md §Dry-run): XLA's
@@ -8,7 +8,7 @@ Scan caveat (measured, see EXPERIMENTS.md §Dry-run): XLA's
 scanned layer stacks are under-reported.  We therefore (a) parse
 collectives per HLO computation and multiply ops inside loop bodies by
 the known trip count, and (b) pair the HLO numbers with closed-form
-analytic terms (roofline.py) — the compiled artifact proves *what*
+analytic terms (``costmodel.py``) — the compiled artifact proves *what*
 collectives/memory the program needs, the analytic model supplies the
 *per-step totals*.
 """
