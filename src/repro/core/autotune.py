@@ -423,6 +423,7 @@ def autotune_combination(space: OptimizationSpace, *,
                        "sig": group_signature(g, im.fusion),
                        "traffic_bytes": im.traffic_bytes,
                        "flops": im.flops,
+                       "grid_steps": im.grid_steps,
                        "elems": "+".join(c.elem.name
                                          for c in im.fusion.calls),
                        "reps": reps, "warmup": warmup, "inner": inner}

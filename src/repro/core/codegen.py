@@ -145,9 +145,10 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
     array, scratch — in its ``predictor.operand_carrier`` form (rank
     >= 2, lane-dense vectors, ``(1, 1)`` scalars), the layout the
     predictor's block legality and VMEM count assume; the body reshapes
-    blocks back to the elementaries' natural ranks.  ``interpret=False``
-    compiles Mosaic kernels and needs a TPU.  ``name`` names the kernel
-    (``group_label``).
+    blocks back to the elementaries' natural ranks, except in a depth-1
+    group, whose elementwise body runs on the carrier blocks.
+    ``interpret=False`` compiles Mosaic kernels and needs a TPU.
+    ``name`` names the kernel (``group_label``).
     """
     _require_pallas_platform(interpret)
     f = impl.fusion
@@ -201,6 +202,16 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
     def carrier(v: Var) -> tuple[tuple[int, ...], tuple[int, ...]]:
         return operand_carrier(v.shape, natural_block(v), v.dtype, hw)
 
+    # a depth-1 group holds only vectors over its one axis and scalars,
+    # and its calls are elementwise maps and whole-block reductions, so
+    # where every vector has the same carrier block the body computes on
+    # that 2-D block as it is: Mosaic lays a rank-1 block out a sublane
+    # per vreg, which at a 2**20-element block takes it 10 s to compile
+    vec_blocks = {carrier(v)[1] for v in (*f.external_inputs,
+                                          *(c.out for c in f.calls))
+                  if len(v.shape) == 1}
+    flat = f.depth == 1 and len(vec_blocks) == 1
+
     def is_row(v: Var) -> bool:
         """A vector carried as one ``(1, n)`` row (blocks move along its
         lanes) rather than a lane-dense view (blocks of whole rows)."""
@@ -224,10 +235,12 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
         return index_map
 
     def load(v: Var, ref, idx=Ellipsis):
-        """A carrier block read back at the elementaries' natural rank."""
+        """A carrier block read back at the elementaries' natural rank
+        (as it is, in a ``flat`` body)."""
         if v.shape == ():
             return ref[0, 0]
-        return jnp.reshape(ref[idx], natural_block(v))
+        return jnp.reshape(ref[idx],
+                           carrier(v)[1] if flat else natural_block(v))
 
     # ---- input specs ------------------------------------------------------
     in_specs = []
@@ -394,6 +407,11 @@ class CompiledProgram:
         """Each group's name in a device trace, in ``group_impls`` order."""
         return [group_label(i, im.fusion)
                 for i, im in enumerate(self.group_impls)]
+
+    @property
+    def grid_steps(self) -> int:
+        """Grid steps one call runs, summed over the plan's groups."""
+        return sum(im.grid_steps for im in self.group_impls)
 
     def __call__(self, **inputs):
         with tracing.span(tracing.DISPATCH):

@@ -16,9 +16,13 @@ paper's (calling order, routine variant, block size, serial iterations):
 
 The predicted runtime is the paper's model:  ``t = max(t_transfer,
 t_compute) + t_launch`` assuming full overlap of DMA and compute
-(§4.2 "we assume full overlap of computation and data transfers").
-Dominated implementations (no better on traffic, flops and VMEM) are
-pruned, as the paper prunes implementations using more on-chip memory.
+(§4.2 "we assume full overlap of computation and data transfers"),
+plus what the grid costs: a fixed price per grid step and the one
+block fetch and write-back the pipeline cannot overlap.  Without the
+grid terms every block size of a 1-D group costs the same, and the
+smallest legal block wins.  Dominated implementations (no faster and
+no smaller in VMEM) are pruned, as the paper prunes implementations
+using more on-chip memory.
 """
 from __future__ import annotations
 
@@ -61,6 +65,10 @@ class HardwareModel:
     hbm_bw: float = 819e9               # bytes/s
     vmem_bytes: int = 64 * 1024 * 1024  # usable VMEM budget (of 128 MiB)
     launch_overhead_s: float = 2e-6     # per-kernel dispatch cost
+    # fixed cost of one Pallas grid step, measured on a v5e: AXPYDOT at
+    # n=2**26 ran 524288 steps of (1, 128) blocks in 142046.9 us, 0.271
+    # us a step, far above the 512 B per operand each step moves
+    grid_step_s: float = 2.7e-7
     # minimum efficient tile (sublane, lane) for f32
     min_tile: tuple[int, int] = (8, 128)
 
@@ -86,15 +94,20 @@ class HardwareModel:
         return (max(1, self.min_tile[0] * 4 // size), self.min_tile[1])
 
     def group_cost(self, traffic_bytes: float, flops: float,
-                   dtype=np.float32) -> float:
+                   dtype=np.float32, steps: int = 0,
+                   fill_bytes: float = 0.0) -> float:
         """Predicted seconds for one fused group given its §5 features
         — the paper's roofline: ``max(traffic/bw, flops/rate) +
-        launch``.  This is the formula ``cost_impl`` charges per group
-        and the feature map ``refit`` regresses against, kept in one
-        place so the two can never drift."""
+        launch`` — plus its grid: ``steps`` grid steps at
+        ``grid_step_s`` each, and ``fill_bytes`` (one step's blocks in
+        and out, the pipeline's first fetch and last write-back) over
+        the bandwidth.  This is the formula ``cost_impl`` charges per
+        group and the feature map ``refit`` regresses against, kept in
+        one place so the two can never drift."""
         t_transfer = traffic_bytes / self.hbm_bw
         t_compute = flops / (self.peak_flops * self.flops_scale(dtype))
-        return max(t_transfer, t_compute) + self.launch_overhead_s
+        return (max(t_transfer, t_compute) + self.launch_overhead_s
+                + steps * self.grid_step_s + fill_bytes / self.hbm_bw)
 
     @classmethod
     def calibrate(cls, backend: str | None = None,
@@ -115,7 +128,11 @@ class HardwareModel:
         flops, 1]`` against measured seconds: the slopes invert to an
         *effective* bandwidth and flop rate (what the machine actually
         sustained on fused groups — micro-benchmark peaks never are),
-        the intercept is the per-dispatch overhead.
+        the intercept is the per-dispatch overhead.  ``grid_step_s``
+        stays analytic: a record that carries ``grid_steps`` has that
+        many steps' cost taken off its ``t_meas`` first (a record left
+        with no time is skipped); one without the field is regressed
+        as measured.
 
         Strict fallback semantics, so the result is always a usable
         model:
@@ -142,6 +159,7 @@ class HardwareModel:
                 t = float(rec["t_meas"])
                 tr = float(rec.get("traffic_bytes", math.nan))
                 fl = float(rec.get("flops", math.nan))
+                t -= float(rec.get("grid_steps", 0)) * self.grid_step_s
             except (KeyError, TypeError, ValueError):
                 continue
             if not (math.isfinite(t) and t > 0 and math.isfinite(tr)
@@ -211,18 +229,26 @@ class Impl:
     t_transfer: float = 0.0
     t_compute: float = 0.0
     t_pred: float = 0.0
+    n_phases: int = 1                   # leading phase axis (1: none)
 
     @property
     def grid(self) -> tuple[int, ...]:
         sizes = dict(zip(self.fusion.axis_roots, self.fusion.axis_sizes))
         return tuple(-(-sizes[a] // b) for a, b in zip(self.order, self.blocks))
 
+    @property
+    def grid_steps(self) -> int:
+        """Grid steps the group's kernel runs in one call, the phase
+        axis of a multi-phase group included."""
+        return self.n_phases * math.prod(self.grid)
+
     def block_of(self, root: int) -> int:
         return self.blocks[self.order.index(root)]
 
     def describe(self) -> str:
         return (f"{self.fusion!r} order={self.order} blocks={self.blocks} "
-                f"grid={self.grid} traffic={self.traffic_bytes/1e6:.2f}MB "
+                f"grid={self.grid} steps={self.grid_steps} "
+                f"traffic={self.traffic_bytes/1e6:.2f}MB "
                 f"flops={self.flops/1e6:.2f}MF vmem={self.vmem_bytes/1e3:.0f}KB "
                 f"t={self.t_pred*1e6:.2f}us")
 
@@ -392,6 +418,11 @@ def cost_impl(f: Fusion, g: Graph, order: tuple[int, ...],
     def block_bytes(v: Var) -> float:
         return padded_bytes(carrier(v)[1], v.dtype, hw)
 
+    # one block of every input and output: what a step moves, and what
+    # the pipeline fetches before its first step and writes back after
+    # its last, with no other transfer to hide behind
+    fill = sum(block_bytes(v) for v in f.external_inputs + f.outputs)
+
     vmem = 0.0
     for v in f.external_inputs:
         vmem += 2 * block_bytes(v)
@@ -406,10 +437,11 @@ def cost_impl(f: Fusion, g: Graph, order: tuple[int, ...],
     dt = fusion_dtype(f)
     t_t = traffic / hw.hbm_bw
     t_c = flops / (hw.peak_flops * hw.flops_scale(dt))
-    t = hw.group_cost(traffic, flops, dt)
+    t = hw.group_cost(traffic, flops, dt, steps=n_phases * math.prod(grid),
+                      fill_bytes=fill)
     return Impl(fusion=f, order=order, blocks=blocks, traffic_bytes=traffic,
                 flops=flops, vmem_bytes=vmem, t_transfer=t_t, t_compute=t_c,
-                t_pred=t)
+                t_pred=t, n_phases=n_phases)
 
 
 def enumerate_impls(f: Fusion, g: Graph, hw: HardwareModel = V5E,
@@ -419,7 +451,10 @@ def enumerate_impls(f: Fusion, g: Graph, hw: HardwareModel = V5E,
     Block sizes per axis come from ``block_granules``, so every emitted
     block is one Mosaic accepts for every operand the axis indexes.
     Pruning (paper §4.2): drop implementations that exceed the VMEM
-    budget (the occupancy analogue) and Pareto-dominated ones.
+    budget (the occupancy analogue) and Pareto-dominated ones on
+    ``(t_pred, vmem_bytes)``: one that is no faster and needs no less
+    VMEM than another goes; a faster one that needs more VMEM stays.
+    The survivors come fastest first.
 
     Fusions that consume a reduction in-kernel (fusion rule 2, relaxed)
     only admit grid orders under which every consumed reduction is
@@ -454,16 +489,17 @@ def enumerate_impls(f: Fusion, g: Graph, hw: HardwareModel = V5E,
     cands = [c for c in cands if c.vmem_bytes <= hw.vmem_bytes]
     if not cands:
         return []
-    # Pareto prune on (traffic, vmem); flops identical across impls
-    cands.sort(key=lambda c: (c.t_pred, c.vmem_bytes))
+    return _pareto_time_vmem(cands, max_impls)
+
+
+def _pareto_time_vmem(cands: list[Impl], max_impls: int) -> list[Impl]:
+    """The candidates no other beats on both ``t_pred`` and
+    ``vmem_bytes``, fastest first, at most ``max_impls``.  Sorted by
+    time then VMEM, a candidate is dominated exactly when an earlier
+    survivor needs no more VMEM than it (ties included)."""
     kept: list[Impl] = []
-    for c in cands:
-        if any(k.traffic_bytes <= c.traffic_bytes and k.vmem_bytes <= c.vmem_bytes
-               and (k.traffic_bytes, k.vmem_bytes) != (c.traffic_bytes, c.vmem_bytes)
-               for k in kept):
-            continue
-        if any(k.traffic_bytes == c.traffic_bytes and k.vmem_bytes == c.vmem_bytes
-               for k in kept):
+    for c in sorted(cands, key=lambda c: (c.t_pred, c.vmem_bytes)):
+        if any(k.vmem_bytes <= c.vmem_bytes for k in kept):
             continue
         kept.append(c)
         if len(kept) >= max_impls:

@@ -162,18 +162,30 @@ def test_quantize_roundtrip_bound(n, scale):
 
 
 def test_predictor_monotonic_in_traffic():
-    """More HBM traffic never predicts faster (same flops/overhead)."""
-    from repro.core.predictor import V5E
+    """More HBM traffic never predicts faster (same flops/overhead).
+
+    The overhead a block choice costs (grid steps, pipeline fill) is
+    fixed by the blocks, so each kept impl is compared with every grid
+    order of its own blocks, where only the traffic moves."""
+    import itertools
+    from repro.core.predictor import V5E, cost_impl
     from repro.blas import REGISTRY
     seq = REGISTRY["BiCGK"]
     g = trace(seq.script, seq.shapes(512))
     space = build_space(g)
-    for impls in space.impls_by_fusion.values():
-        for a in impls:
-            for b in impls:
-                if (a.traffic_bytes <= b.traffic_bytes
-                        and a.flops == b.flops):
-                    assert a.t_pred <= b.t_pred + 1e-12
+    n_unequal = 0
+    for f in space.fusions:
+        for im in space.impls_by_fusion[f.key]:
+            blk = dict(zip(im.order, im.blocks))
+            impls = [cost_impl(f, g, o, tuple(blk[r] for r in o), V5E)
+                     for o in itertools.permutations(f.axis_roots)]
+            for a in impls:
+                for b in impls:
+                    n_unequal += a.traffic_bytes < b.traffic_bytes
+                    if (a.traffic_bytes <= b.traffic_bytes
+                            and a.flops == b.flops):
+                        assert a.t_pred <= b.t_pred + 1e-12
+    assert n_unequal > 0
 
 
 # ---------------------------------------------------------------------------
