@@ -10,7 +10,7 @@ from .codegen import (BatchedProgram, CompiledProgram, PackedDispatch,
 from .compiler import MODES, CompileReport, FusionCompiler
 from .elementary import (ArgSpec, Elementary, Kind, Monoid, make_map,
                          make_nested_map, make_nested_map_reduce, make_reduce,
-                         make_tensor_map)
+                         make_tensor_map, make_tensor_map_reduce)
 from .fusion import Fusion, analyse_group, enumerate_fusions, saves_traffic
 from .graph import CallNode, Graph, Var, trace
 from .plan import (ExecutionPlan, GroupPlan, PackedPlan, build_packed_plan,
@@ -39,8 +39,8 @@ __all__ = [
     "enumerate_combinations", "enumerate_fusions", "enumerate_impls",
     "exhaustive_best_combination", "graph_signature", "iter_combinations",
     "make_map", "make_nested_map", "make_nested_map_reduce", "make_reduce",
-    "make_tensor_map", "measure_callable", "measure_group",
-    "measure_program", "saves_traffic",
+    "make_tensor_map", "make_tensor_map_reduce", "measure_callable",
+    "measure_group", "measure_program", "saves_traffic",
     "synthetic_inputs", "trace",
     "unfused_combination",
 ]

@@ -280,14 +280,21 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
         vroots = roots_of(v)
         if not vroots:
             return (slice(None), slice(None))
+
+        def along(r, width):
+            # a whole-axis block sits at offset 0: a static slice, as
+            # Mosaic refuses a dynamic lane offset it cannot prove is a
+            # multiple of 128 (a (1, 16) per-head vector)
+            if spatial_grid[pos[r]] == 1:
+                return slice(None)
+            return pl.ds(pl.program_id(gofs + pos[r]) * width, width)
+
         if len(vroots) == 1:
-            pid = pl.program_id(gofs + pos[vroots[0]])
             rows, lanes = carrier(v)[1]
             if is_row(v):
-                return (slice(None), pl.ds(pid * lanes, lanes))
-            return (pl.ds(pid * rows, rows), slice(None))
-        return tuple(pl.ds(pl.program_id(gofs + pos[r]) * blk[r], blk[r])
-                     for r in vroots)
+                return (slice(None), along(vroots[0], lanes))
+            return (along(vroots[0], rows), slice(None))
+        return tuple(along(r, blk[r]) for r in vroots)
 
     n_in = len(f.external_inputs)
     n_out = len(f.outputs)
@@ -412,6 +419,20 @@ class CompiledProgram:
     def grid_steps(self) -> int:
         """Grid steps one call runs, summed over the plan's groups."""
         return sum(im.grid_steps for im in self.group_impls)
+
+    @property
+    def input_passes(self) -> dict[str, int]:
+        """How many times one call streams each input from HBM: the sum,
+        over the plan's groups that read it, of the group's
+        ``n_phases`` (a multi-phase kernel passes over its inputs once a
+        phase).  A block a grid order fetches again within one pass is
+        charged in ``Impl.traffic_bytes``, not here."""
+        passes = dict.fromkeys(self.plan.input_names, 0)
+        for im in self.group_impls:
+            for v in im.fusion.external_inputs:
+                if v.is_input:
+                    passes[v.name] += im.n_phases
+        return passes
 
     def __call__(self, **inputs):
         with tracing.span(tracing.DISPATCH):

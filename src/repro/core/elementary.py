@@ -23,6 +23,12 @@ nested map (mapped map)     (i, j)     (i, j)      —
 mapped reduce               (i, j)     (i,)/(j,)   (j,)/(i,)
 ==========================  =========  ==========  ============
 
+Past the paper, a depth-3 map-reduce (``make_tensor_map_reduce``) maps
+over two axes and reduces over the third, its operands indexed by
+subsets of the three: a contraction such as every head of a decode step
+against one shared cache, ``s[h, t] = sum_c q[h, c] k[t, c]``, whose
+cache operand ``k`` is invariant over the head axis.
+
 The per-element first-order function ``fn`` is written *block-
 polymorphically*: it receives jnp arrays whose shapes are either the full
 operands (dense / XLA backend) or VMEM-resident blocks (Pallas backend)
@@ -45,8 +51,8 @@ import numpy as np
 class Kind(enum.Enum):
     MAP = "map"                      # depth-1, no reduce axes
     REDUCE = "reduce"                # depth-1, output ()
-    NESTED_MAP = "nested_map"        # depth-2, no reduce axes
-    NESTED_MAP_REDUCE = "nested_map_reduce"  # depth-2, one reduce axis
+    NESTED_MAP = "nested_map"        # depth >= 2, no reduce axes
+    NESTED_MAP_REDUCE = "nested_map_reduce"  # depth >= 2, one reduce axis
 
 
 class Monoid(enum.Enum):
@@ -128,10 +134,12 @@ class Elementary:
 
     def __post_init__(self):
         depth = len(self.formal_axes)
-        # the paper stops at depth 2; deeper maps (batched matrices,
-        # tensor contractions) are a compatible extension — every layer
-        # downstream (trace axes, fusion legality, impl enumeration,
-        # codegen index maps) is rank-generic
+        # the paper stops at depth 2.  Depth 3 is carried through every
+        # layer downstream: trace axes, fusion legality (a group's calls
+        # share one axis set, so a depth-3 call never shares a group with
+        # a depth-2 one), impl enumeration over all grid orders, and
+        # codegen's index maps; MLA_DECODE_ATTN's contractions exercise
+        # it.  Nothing deeper is exercised by any program or test.
         assert depth >= 1, "elementary needs at least one iteration axis"
         for spec in self.in_specs:
             assert all(0 <= a < depth for a in spec.axes)
@@ -245,6 +253,27 @@ def make_nested_map_reduce(name: str, fn: Callable,
         in_specs=tuple(ArgSpec(tuple(a)) for a in in_axes), out_axes=(out_axis,),
         fn=fn, monoid=monoid, flops_per_point=flops_per_point, elem=elem,
     )
+
+
+def make_tensor_map_reduce(name: str, fn: Callable,
+                           in_axes: Sequence[Sequence[int]],
+                           reduce_axis: int) -> Elementary:
+    """Depth-3 map over two axes of a sum over the third,
+    ``reduce_axis``.  ``in_axes`` indexes each operand by a subset of the
+    axes, as in ``make_nested_map``; the output keeps the other two axes
+    in order.  E.g. with axes ``(h, t, c)``:
+
+    scores (reduce_axis=2, ``in_axes=[(0, 2), (1, 2)]``): s_ht = sum_c q_hc k_tc
+    values (reduce_axis=1, ``in_axes=[(0, 1), (1, 2)]``): o_hc = sum_t w_ht v_tc
+
+    ``fn`` computes the partial sum over the blocks it is given, as in
+    ``make_nested_map_reduce``; a multiply and an add per point."""
+    return Elementary(
+        name=name, kind=Kind.NESTED_MAP_REDUCE,
+        formal_axes=("a0", "a1", "a2"),
+        in_specs=tuple(ArgSpec(tuple(a)) for a in in_axes),
+        out_axes=tuple(a for a in range(3) if a != reduce_axis),
+        fn=fn, flops_per_point=2.0)
 
 
 # ---------------------------------------------------------------------------

@@ -7,6 +7,14 @@
   kernel's name, so the device trace names a group's kernel the same way
   whatever the plan's signature.
 
+Counters of one call, which a plan fixes (no trace needed):
+
+* ``CompiledProgram.grid_steps``: grid steps, summed over the groups.
+* ``CompiledProgram.input_passes``: ``{input name: passes}``, the times
+  the call streams each input from HBM, the sum of ``n_phases`` over the
+  groups that read it (MLA's latent cache ``ckv``: 2, one pass to score,
+  one to weight).
+
 A span is a ``jax.profiler.TraceAnnotation``: with no profiler running it
 costs well under a microsecond, so it is always on.
 """

@@ -2,7 +2,8 @@
 
 These extend ``blas.elementary_lib`` with the non-multilinear pieces a
 decoder step needs: the rmsnorm scale map, softmax stages, attention
-contractions and the precision-matched AdamW moment updates.
+contractions, latent attention's (MLA) per-head stages and the
+precision-matched AdamW moment updates.
 
 Bitwise discipline (DESIGN.md §10): every ``fn`` body is written so the
 fused whole-program XLA computation reproduces the corresponding
@@ -21,10 +22,14 @@ XLA.  Two non-obvious consequences:
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
-from repro.core.elementary import (Monoid, make_map, make_nested_map_reduce)
+from repro.core.elementary import (Monoid, col, make_map, make_nested_map,
+                                   make_nested_map_reduce,
+                                   make_tensor_map_reduce)
 
 # --- rmsnorm -----------------------------------------------------------------
 
@@ -69,6 +74,56 @@ attn_out = make_nested_map_reduce(
         precision="highest")[..., 0, 0, :],
     in_axes=[(0, 1), (0,)], out_axis=1, flops_per_point=2)
 
+# --- latent attention (MLA), absorbed form ----------------------------------
+#
+# DeepSeek-V2 (arXiv:2405.04434, section 2.1): every head reads one shared
+# latent cache ckv (n, rank) and one shared rotary key cache kr (n, rope).
+# Axes: h (heads), t (cache positions), c (latent or rotary width).
+
+#: DeepSeek-V2-Lite's published widths (its config.json)
+MLA_HEADS, MLA_RANK, MLA_ROPE, MLA_NOPE = 16, 512, 64, 128
+
+#: The softmax scale DeepSeek's code applies: (qk_nope_head_dim +
+#: qk_rope_head_dim) ** -0.5, times mscale ** 2 where YaRN's mscale =
+#: 0.1 * mscale_all_dim * ln(factor) + 1, with rope_scaling's factor 40
+#: and mscale_all_dim 0.707: 192 ** -0.5 * 1.2608 ** 2 = 0.1147214
+MLA_SCALE = (MLA_NOPE + MLA_ROPE) ** -0.5 * (
+    0.1 * 0.707 * math.log(40) + 1.0) ** 2
+
+# s_ht = sum_c q_hc k_tc over (h, t, c): the score of every head against
+# one cache row block, the cache invariant over h (latent and rotary parts)
+mla_score = make_tensor_map_reduce(
+    "mla_score",
+    lambda q, k: jnp.einsum("...hc,...tc->...ht", q, k,
+                            precision="highest"),
+    in_axes=[(0, 2), (1, 2)], reduce_axis=2)
+
+# s = scale * (latent score + rotary score)
+mla_logits = make_nested_map(
+    "mla_logits", lambda a, b: MLA_SCALE * (a + b),
+    in_axes=[(0, 1), (0, 1)], flops_per_point=2)
+
+# softmax over t, per head: max, exp(s - max), sum, divide
+mla_max = make_nested_map_reduce(
+    "mla_max", lambda s: jnp.max(s, axis=-1), in_axes=[(0, 1)],
+    out_axis=0, monoid=Monoid.MAX, flops_per_point=1)
+mla_exp_sub = make_nested_map(
+    "mla_exp_sub", lambda s, m: jnp.exp(s - col(m)),
+    in_axes=[(0, 1), (0,)], flops_per_point=2, pad_safe=False)
+mla_sum = make_nested_map_reduce(
+    "mla_sum", lambda e: jnp.sum(e, axis=-1), in_axes=[(0, 1)],
+    out_axis=0, flops_per_point=1)
+mla_div = make_nested_map(
+    "mla_div", lambda e, z: e / col(z), in_axes=[(0, 1), (0,)],
+    flops_per_point=1)
+
+# o_hc = sum_t p_ht ckv_tc over (h, t, c): the weighted latent rows
+mla_value = make_tensor_map_reduce(
+    "mla_value",
+    lambda p, v: jnp.einsum("...ht,...tc->...hc", p, v,
+                            precision="highest"),
+    in_axes=[(0, 1), (1, 2)], reduce_axis=1)
+
 # --- AdamW (precision-matched variants of repro.optim.fused) -----------------
 
 ema_pm = make_map(
@@ -83,5 +138,6 @@ from repro.optim.fused import adam_dir, apply_lr  # noqa: E402,F401
 
 ALL = {e.name: e for e in [
     rms_scale, exp_map, exp_sub, rsqrt_map, div_by, attn_score, attn_out,
+    mla_score, mla_logits, mla_max, mla_exp_sub, mla_sum, mla_div, mla_value,
     ema_pm, ema_sq_pm, adam_dir, apply_lr,
 ]}

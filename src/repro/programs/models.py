@@ -14,6 +14,11 @@ repo's reference implementations (``repro.kernels.ref`` /
   (oracle: ``kernels.ref.decode_attention`` at Hq = Hkv = 1).
 * ``FUSED_ADAMW`` — the optimizer step of ``repro.optim.fused`` with
   precision-matched scalar inputs (oracle: ``kernels.ref.adamw``).
+* ``MLA_DECODE_ATTN`` — DeepSeek-V2-Lite's latent attention (MLA) at
+  decode, absorbed form, at its published widths: 16 heads scored
+  against one shared latent cache, softmax per head, the latent rows
+  weighted (oracles: the float64 ``reference``, and
+  ``mla_unabsorbed_reference``, the architecture's own equations).
 
 Size notes (pinned empirically, see DESIGN.md §10): matvec-bearing
 graphs (``LM_BLOCK``, ``LM_DECODE_ATTN``) are bitwise against the
@@ -26,6 +31,8 @@ never mistake the head axis for the padded axis.
 """
 from __future__ import annotations
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.blas import elementary_lib as lib
@@ -219,3 +226,74 @@ _register(Program(
     pad_values={"p": 0.0, "grad": 0.0, "m": 0.0, "v": 0.0, "lr": 0.0,
                 "b1": 0.0, "omb1": 0.0, "b2": 0.0, "omb2": 0.0,
                 "eps": 0.0, "wd": 0.0, "c1": 0.0, "c2": 0.0}))
+
+
+# --- MLA_DECODE_ATTN:  o_lat = softmax(scale (q_lat ckv^T + q_rope kr^T)) ckv
+
+#: DeepSeek-V2-Lite's softmax scale (``model_lib`` derives it)
+MLA_SCALE = mlib.MLA_SCALE
+
+
+def _mla_script(g, q_lat, q_rope, ckv, kr):
+    s_lat = g.apply(mlib.mla_score, q_lat, ckv, name="s_lat")
+    s_rope = g.apply(mlib.mla_score, q_rope, kr, name="s_rope")
+    s = g.apply(mlib.mla_logits, s_lat, s_rope, name="s")
+    mx = g.apply(mlib.mla_max, s, name="mx")
+    e = g.apply(mlib.mla_exp_sub, s, mx, name="e")
+    z = g.apply(mlib.mla_sum, e, name="z")
+    p = g.apply(mlib.mla_div, e, z, name="p")
+    o_lat = g.apply(mlib.mla_value, p, ckv, name="o_lat")
+    return (o_lat,)
+
+
+def _mla_ref(q_lat, q_rope, ckv, kr):
+    s = MLA_SCALE * (q_lat @ ckv.T + q_rope @ kr.T)
+    e = np.exp(s - np.max(s, axis=1, keepdims=True))
+    return ((e / np.sum(e, axis=1, keepdims=True)) @ ckv,)
+
+
+def mla_program(heads: int = mlib.MLA_HEADS, rank: int = mlib.MLA_RANK,
+                rope: int = mlib.MLA_ROPE,
+                name: str = "MLA_DECODE_ATTN") -> Program:
+    """Absorbed MLA decode attention for one session of ``n`` cached
+    positions, every one valid (decode happens at the last position).
+
+    Inputs: the absorbed queries ``q_lat[h] = W_UK[h] q_nope[h]``
+    ``(heads, rank)`` and the rotated ``q_rope`` ``(heads, rope)``; the
+    latent cache ``ckv`` ``(n, rank)`` and the rotated key cache ``kr``
+    ``(n, rope)``, as DeepSeek's cache stores ``k_pe``.  Output: the
+    weighted latent rows ``o_lat`` ``(heads, rank)``; ``W_UV[h]^T
+    o_lat[h]`` is head h's output (``W_UK[h]``, ``W_UV[h]``: ``(rank,
+    128)``, as in ``mla_unabsorbed_reference``).  The defaults are DeepSeek-V2-Lite's;
+    smaller widths are for tests."""
+    return Program(
+        name, "M", _mla_script,
+        lambda n: {"q_lat": (heads, rank), "q_rope": (heads, rope),
+                   "ckv": (n, rank), "kr": (n, rope)},
+        _mla_ref,
+        lambda n: heads * n * (4.0 * rank + 2.0 * rope + 7.0))
+
+
+_register(mla_program())
+
+
+def mla_unabsorbed_reference(q_nope, q_rope, ckv, kr, w_uk, w_uv):
+    """MLA decode attention as the architecture writes it, float32
+    ``jax.numpy`` at full matmul precision, no compiler: head h's keys
+    are ``[ckv W_UK[h] ; kr]`` and its values ``ckv W_UV[h]``, attended
+    by the query ``[q_nope[h] ; q_rope[h]]`` with an ordinary softmax.
+
+    ``q_nope`` ``(h, nope)``, ``q_rope`` ``(h, rope)``, ``ckv`` ``(n,
+    rank)``, ``kr`` ``(n, rope)``, ``w_uk`` ``(h, rank, nope)``, ``w_uv``
+    ``(h, rank, v)``; returns every head's output ``(h, v)``."""
+    with jax.default_matmul_precision("highest"):
+        h, n = q_nope.shape[0], ckv.shape[0]
+        keys = jnp.concatenate(
+            [jnp.einsum("tc,hcd->htd", ckv, w_uk),
+             jnp.broadcast_to(kr, (h, n, kr.shape[-1]))], axis=-1)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        p = jax.nn.softmax(MLA_SCALE * jnp.einsum("hd,htd->ht", q, keys),
+                           axis=-1)
+        values = jnp.einsum("tc,hcd->htd", ckv, w_uv)
+        return jnp.einsum("ht,htd->hd", p, values)
+

@@ -10,7 +10,8 @@ from repro.programs import (ADAMW_HYPERS, BLAS, MODELS, REGISTRY, Program,
 
 PAPER_SEQUENCES = ["AXPYDOT", "ATAX", "BiCGK", "SGEMV", "SGEMVT", "SSCAL",
                    "GEMVER", "GESUMMV", "MADD", "VADD", "WAXPBY"]
-MODEL_SEQUENCES = ["LM_RMSNORM", "LM_BLOCK", "LM_DECODE_ATTN", "FUSED_ADAMW"]
+MODEL_SEQUENCES = ["LM_RMSNORM", "LM_BLOCK", "LM_DECODE_ATTN", "FUSED_ADAMW",
+                   "MLA_DECODE_ATTN"]
 
 
 def test_groups_partition_the_registry():

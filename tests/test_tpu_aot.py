@@ -10,7 +10,9 @@ refuse, at the sizes the chip smoke run serves:
 * GEMVER at 8192 — vectors sharing a matrix's sublane axis;
 * LM_RMSNORM at 4096 — a consumed scalar reduction in VMEM scratch;
 * LM_DECODE_ATTN at 32768 — a (n, 48) operand blocked in memory order;
-* ATAX at 16384 — a 1 GiB matrix with a consumed vector reduction.
+* ATAX at 16384 — a 1 GiB matrix with a consumed vector reduction;
+* MLA_DECODE_ATTN at 131072 — depth-3 contractions over (heads, cache,
+  latent) and a per-head (1, 16) vector carried in VMEM scratch.
 
 Each Pallas compile must contain a Mosaic kernel (``tpu_custom_call``).
 The topology is described inside a fixture, never at import: only one
@@ -70,6 +72,7 @@ def _compile(prog, shapes, sharding, lead=()):
     ("LM_RMSNORM", 4096),
     ("LM_DECODE_ATTN", 32768),
     ("ATAX", 16384),
+    ("MLA_DECODE_ATTN", 131072),
 ])
 def test_pallas_program_compiles_for_v5e(name, n, one_chip, tpu_codegen):
     prog = REGISTRY[name]
