@@ -419,6 +419,16 @@ def verify_plan(plan: ExecutionPlan, g: Graph, hw: HardwareModel = V5E,
                     ok = False
         if ok:
             if plan.backend == "pallas":
+                if f.stream_root is not None and any(
+                        b != f.axis_sizes[f.axis_roots.index(r)]
+                        for b, r in zip(gp.blocks, order)
+                        if r != f.stream_root):
+                    out.append(diag(
+                        "RPL214", loc,
+                        f"online-softmax group blocks {gp.blocks} split an "
+                        f"axis other than the streamed one",
+                        "every other axis must be one whole block; pick "
+                        "a block enumerate_impls emits"))
                 for c in consumed_reductions(f, g):
                     if not accumulable(c.out, f, g, order):
                         out.append(diag(

@@ -50,12 +50,14 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import tracing
 from .diagnostics import UnsupportedGroupError, VerificationError
-from .elementary import Monoid
-from .fusion import Fusion, call_phases, consumed_reductions
+from .elementary import Monoid, col
+from .fusion import (ACC, DIV, MAX, Fusion, call_phases,
+                     consumed_reductions)
 from .graph import Graph, Var
 from .plan import ExecutionPlan, PackedPlan, build_plan
 from .predictor import (V5E, HardwareModel, Impl, accumulable,
-                        operand_carrier, reduce_roots_of)
+                        online_accumulators, operand_carrier,
+                        reduce_roots_of)
 from .scheduler import Combination
 
 #: scoped VMEM Mosaic may use per kernel beyond the predictor's budget
@@ -110,6 +112,20 @@ def _monoid_sum(monoid: Monoid, x, axes):
     return jnp.min(x, axis=axes)
 
 
+def _broadcast(x, src: tuple[int, ...], dst: tuple[int, ...]):
+    """Block ``x`` over axis roots ``src`` shaped to broadcast onto a
+    block over ``dst`` (the cases ``fusion.broadcastable`` admits)."""
+    if len(src) == 1 and len(dst) == 2 and src[0] == dst[0]:
+        return col(x)
+    return x
+
+
+def _rescaled(acc, alpha):
+    """A running sum carried to a new running max: ``acc`` times
+    ``alpha = exp(m_old - m_new)``, broadcast onto it."""
+    return acc * alpha
+
+
 def _require_pallas_platform(interpret: bool) -> None:
     """Compiled Pallas kernels need a TPU; elsewhere the caller must ask
     for the interpreter explicitly — it is never a silent fallback."""
@@ -147,12 +163,23 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
     predictor's block legality and VMEM count assume; the body reshapes
     blocks back to the elementaries' natural ranks, except in a depth-1
     group, whose elementwise body runs on the carrier blocks.
+    An online-softmax group (``fusion.online_roles``) compiles to one
+    sweep over its streamed axis, every other axis one whole block: VMEM
+    scratch carries a running max (started at the dtype's lowest finite
+    value, so ``exp(m_old - m_new)`` is never ``nan``) and a running sum
+    per reduction over the axis.  Each step takes the max to the block's,
+    rescales every sum by ``exp(m_old - m_new)`` before adding the
+    block's part, and feeds a division's numerator on in its place; the
+    last step divides each sum by its divisor's finished sum and writes
+    the outputs (DESIGN.md §2).
+
     ``interpret=False`` compiles Mosaic kernels and needs a TPU.
     ``name`` names the kernel (``group_label``).
     """
     _require_pallas_platform(interpret)
     f = impl.fusion
     order, spatial_grid = impl.order, impl.grid
+    online = f.stream_root is not None
     pos = {r: i for i, r in enumerate(order)}
     blk = {r: b for r, b in zip(order, impl.blocks)}
     group_names = "+".join(c.elem.name for c in f.calls)
@@ -174,6 +201,13 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
                 f"{order}, so no scratch accumulator can carry its "
                 f"finished value; use an accumulable order "
                 f"(enumerate_impls only emits those) or split the group")
+    if online and any(n > 1 for r, n in zip(order, spatial_grid)
+                      if r != f.stream_root):
+        raise UnsupportedGroupError.single(
+            "RPL214", f"plan.group[{group_names}]",
+            f"pallas backend cannot emit online-softmax group "
+            f"[{group_names}] with grid {spatial_grid}: every axis but the "
+            f"streamed one must be one whole block")
 
     # every value a call reads must be resolvable inside the kernel: an
     # external input, an earlier map output, or a consumed reduction's
@@ -190,7 +224,7 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
                 f"pallas backend cannot emit group [{group_names}]: call "
                 f"'{c.elem.name}' consumes the output of {bad}, which "
                 f"never becomes visible inside the kernel")
-        if (not c.elem.is_reduction) or c.idx in consumed_idx:
+        if online or not c.elem.is_reduction or c.idx in consumed_idx:
             resolvable.add(c.out)
 
     def roots_of(v: Var) -> tuple[int, ...]:
@@ -253,7 +287,7 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
     for v in f.outputs:
         shape, block = carrier(v)
         rr = reduce_roots_of(v, f, g)
-        if not rr or accumulable(v, f, g, order):
+        if not rr or accumulable(v, f, g, order) or online:
             out_specs.append(pl.BlockSpec(block, make_index_map(v)))
             out_shapes.append(jax.ShapeDtypeStruct(shape, v.dtype))
             out_mode.append(("acc", tuple(pos[r] for r in rr)) if rr
@@ -271,7 +305,7 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
     # full-size carrier buffers: the finished value of phase p, read back
     # via dynamic block slices from phase p+1 on
     scratch_shapes, scratch_at = [], {}
-    for c in consumed:
+    for c in consumed + online_accumulators(f):
         scratch_at[c.idx] = len(scratch_shapes)
         scratch_shapes.append(pltpu.VMEM(carrier(c.out)[0], c.out.dtype))
 
@@ -365,8 +399,62 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
                     def _accum(ref=ref, cval=cval, m=c.elem.monoid):
                         ref[...] = m.combine(ref[...], cval)
 
+    def online_kernel(*refs):
+        in_refs = refs[:n_in]
+        out_refs = refs[n_in:n_in + n_out]
+        run = {c.out: refs[n_in + n_out + scratch_at[c.idx]]
+               for c in online_accumulators(f)}
+        step = pl.program_id(pos[f.stream_root])
+
+        @pl.when(step == 0)
+        def _start():
+            for c, role in zip(f.calls, f.roles):
+                if c.out in run:
+                    ref = run[c.out]
+                    first = jnp.finfo(ref.dtype).min if role == MAX else 0
+                    ref[...] = jnp.full(ref.shape, first, ref.dtype)
+
+        # a vector input carried as a row stays one: a contraction of it
+        # then yields rows too, which Mosaic reduces and broadcasts,
+        # where it refuses the relayout of a rank-1 contraction output
+        env: dict[Var, Any] = {}
+        for v, ref in zip(f.external_inputs, in_refs):
+            env[v] = ref[...] if is_row(v) else load(v, ref)
+        divisor: dict[Var, Var] = {}
+        for c, role in zip(f.calls, f.roles):
+            args = [env[a] for a in c.args]
+            if role == MAX:
+                m_old = load(c.out, run[c.out])
+                val = jnp.maximum(m_old, c.elem.fn(*args))
+                alpha, m_roots = jnp.exp(m_old - val), roots_of(c.out)
+            elif role == DIV:
+                num, den = c.elem.div_args
+                val = args[num]
+                divisor[c.out] = c.args[den]
+            elif role == ACC:
+                val = _rescaled(load(c.out, run[c.out]), _broadcast(
+                    alpha, m_roots, roots_of(c.out))) + c.elem.fn(*args)
+            else:
+                val = c.elem.fn(*args)
+            if c.out in run:
+                ref = run[c.out]
+                ref[...] = jnp.reshape(val, ref.shape).astype(ref.dtype)
+            env[c.out] = val
+
+        @pl.when(step == spatial_grid[pos[f.stream_root]] - 1)
+        def _finish():
+            for v, ref in zip(f.outputs, out_refs):
+                val = env[v]
+                for a in v.producer.args:
+                    if a in divisor:
+                        den = divisor[a]
+                        val = val / _broadcast(env[den], roots_of(den),
+                                               roots_of(v))
+                ref[...] = jnp.reshape(val, ref.shape).astype(ref.dtype)
+
     call = pl.pallas_call(
-        kernel, grid=grid, in_specs=in_specs, out_specs=out_specs,
+        online_kernel if online else kernel, grid=grid, in_specs=in_specs,
+        out_specs=out_specs,
         out_shape=tuple(out_shapes), interpret=interpret,
         scratch_shapes=tuple(scratch_shapes), name=name,
         compiler_params=pltpu.CompilerParams(

@@ -68,7 +68,9 @@ CODES: dict[str, tuple[str, str]] = {
     "RPL212": ("error", "grid order invalid for the bound fusion"),
     "RPL213": ("error", "block size illegal for the bound fusion axis"),
     "RPL214": ("error", "consumed reduction not accumulable under the "
-                        "plan's grid order (pallas phase contract)"),
+                        "plan's grid order (pallas phase contract), or an "
+                        "online-softmax group blocked off its streamed "
+                        "axis"),
     "RPL215": ("error", "group VMEM footprint (blocks + consumed-reduction "
                         "scratch) exceeds the budget"),
     "RPL216": ("error", "group input routing disagrees with the graph's "

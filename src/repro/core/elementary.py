@@ -131,15 +131,28 @@ class Elementary:
     # of pad_safe calls; ``exp``/``rsqrt`` (zero maps to 1 / inf) must
     # set False so the engine falls back to per-lane masking.
     pad_safe: bool = True
+    # What the online softmax (fusion.online_roles, DESIGN.md §2) may
+    # read off a call; each is a claim about ``fn`` in real arithmetic.
+    # ``exp_sub_args=(x, m)``: a map computing exp(args[x] - args[m]),
+    # args[m] broadcast over the axes of args[x] it lacks.
+    exp_sub_args: tuple[int, int] | None = None
+    # ``div_args=(num, den)``: a map computing args[num] / args[den],
+    # args[den] broadcast likewise.
+    div_args: tuple[int, int] | None = None
+    # the arguments ``fn`` is linear in, each with the others held fixed:
+    # scaling one by a factor constant over the reduce axes scales the
+    # output by it.
+    linear_args: tuple[int, ...] = ()
 
     def __post_init__(self):
         depth = len(self.formal_axes)
         # the paper stops at depth 2.  Depth 3 is carried through every
         # layer downstream: trace axes, fusion legality (a group's calls
-        # share one axis set, so a depth-3 call never shares a group with
-        # a depth-2 one), impl enumeration over all grid orders, and
-        # codegen's index maps; MLA_DECODE_ATTN's contractions exercise
-        # it.  Nothing deeper is exercised by any program or test.
+        # share one axis set, so a depth-3 call shares a group with a
+        # depth-2 one only in an online softmax), impl enumeration over
+        # all grid orders, and codegen's index maps; MLA_DECODE_ATTN's
+        # contractions exercise it.  Nothing deeper is exercised by any
+        # program or test.
         assert depth >= 1, "elementary needs at least one iteration axis"
         for spec in self.in_specs:
             assert all(0 <= a < depth for a in spec.axes)
@@ -180,15 +193,19 @@ def col(v):
 # ---------------------------------------------------------------------------
 
 def make_map(name: str, fn: Callable, arity: int, *, scalar_args: Sequence[int] = (),
-             flops_per_point: float = 1.0, pad_safe: bool = True) -> Elementary:
-    """Depth-1 map over lists; ``scalar_args`` are broadcast () arguments."""
+             flops_per_point: float = 1.0, pad_safe: bool = True,
+             **props) -> Elementary:
+    """Depth-1 map over lists; ``scalar_args`` are broadcast () arguments.
+    ``props`` (here and in the constructors below): the online-softmax
+    properties of ``fn`` (``exp_sub_args``, ``div_args``,
+    ``linear_args``)."""
     specs = tuple(
         ArgSpec(() if i in set(scalar_args) else (0,)) for i in range(arity)
     )
     return Elementary(
         name=name, kind=Kind.MAP, formal_axes=("i",), in_specs=specs,
         out_axes=(0,), fn=fn, flops_per_point=flops_per_point,
-        pad_safe=pad_safe,
+        pad_safe=pad_safe, **props,
     )
 
 
@@ -205,17 +222,19 @@ def make_reduce(name: str, monoid: Monoid = Monoid.SUM, *,
         name=name, kind=Kind.REDUCE, formal_axes=("i",),
         in_specs=(ArgSpec((0,)),), out_axes=(), fn=fn, monoid=monoid,
         flops_per_point=flops_per_point,
+        linear_args=(0,) if monoid is Monoid.SUM else (),
     )
 
 
 def make_nested_map(name: str, fn: Callable, in_axes: Sequence[Sequence[int]], *,
                     flops_per_point: float = 1.0, elem: tuple[int, int] = (8, 128),
-                    pad_safe: bool = True) -> Elementary:
+                    pad_safe: bool = True, **props) -> Elementary:
     """Depth-2 map producing a matrix indexed (i, j)."""
     return Elementary(
         name=name, kind=Kind.NESTED_MAP, formal_axes=("i", "j"),
         in_specs=tuple(ArgSpec(tuple(a)) for a in in_axes), out_axes=(0, 1),
         fn=fn, flops_per_point=flops_per_point, elem=elem, pad_safe=pad_safe,
+        **props,
     )
 
 
@@ -239,7 +258,8 @@ def make_nested_map_reduce(name: str, fn: Callable,
                            in_axes: Sequence[Sequence[int]],
                            out_axis: int, *, monoid: Monoid = Monoid.SUM,
                            flops_per_point: float = 2.0,
-                           elem: tuple[int, int] = (8, 128)) -> Elementary:
+                           elem: tuple[int, int] = (8, 128),
+                           **props) -> Elementary:
     """Depth-2 map over ``out_axis`` of a reduce over the other axis.
 
     E.g. gemv (out_axis=0, reduce over j):  y_i = sum_j A_ij x_j
@@ -252,12 +272,13 @@ def make_nested_map_reduce(name: str, fn: Callable,
         name=name, kind=Kind.NESTED_MAP_REDUCE, formal_axes=("i", "j"),
         in_specs=tuple(ArgSpec(tuple(a)) for a in in_axes), out_axes=(out_axis,),
         fn=fn, monoid=monoid, flops_per_point=flops_per_point, elem=elem,
+        **props,
     )
 
 
 def make_tensor_map_reduce(name: str, fn: Callable,
                            in_axes: Sequence[Sequence[int]],
-                           reduce_axis: int) -> Elementary:
+                           reduce_axis: int, **props) -> Elementary:
     """Depth-3 map over two axes of a sum over the third,
     ``reduce_axis``.  ``in_axes`` indexes each operand by a subset of the
     axes, as in ``make_nested_map``; the output keeps the other two axes
@@ -273,7 +294,7 @@ def make_tensor_map_reduce(name: str, fn: Callable,
         formal_axes=("a0", "a1", "a2"),
         in_specs=tuple(ArgSpec(tuple(a)) for a in in_axes),
         out_axes=tuple(a for a in range(3) if a != reduce_axis),
-        fn=fn, flops_per_point=2.0)
+        fn=fn, flops_per_point=2.0, **props)
 
 
 # ---------------------------------------------------------------------------
@@ -292,4 +313,5 @@ rsqrt_map = make_map("rsqrt", lambda x: jax.lax.rsqrt(x), arity=1,
 # exp(x - m) with a broadcast (reduce-finished) max — the softmax core;
 # a zero lane maps to exp(-m), not zero
 exp_sub = make_map("exp_sub", lambda x, m: jnp.exp(x - m), arity=2,
-                   scalar_args=(1,), flops_per_point=2, pad_safe=False)
+                   scalar_args=(1,), flops_per_point=2, pad_safe=False,
+                   exp_sub_args=(0, 1))

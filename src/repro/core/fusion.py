@@ -23,6 +23,11 @@ Legality rules, transposed from CUDA thread blocks to Pallas grids:
 4. **Connectivity / usefulness.**  Members must be connected through
    shared data (an internal edge or a shared input array); anything else
    spares no memory transfers and is pruned (§4.2).
+
+**Online softmax.**  A group that holds a softmax chain over one axis t
+(``online_roles``) takes rule 1 over the union of its calls' axes and
+replaces rule 2: its MAX over t is consumed in the same sweep over t,
+as a running max that rescales every sum it feeds (DESIGN.md §2).
 """
 from __future__ import annotations
 
@@ -30,7 +35,11 @@ import dataclasses
 import itertools
 from typing import Iterable
 
+from .elementary import Monoid
 from .graph import CallNode, Graph, Var
+
+#: the roles of an online-softmax group's calls (``online_roles``)
+PRE, MAX, EXP, DIV, ACC = "pre", "max", "exp", "div", "acc"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -43,6 +52,10 @@ class Fusion:
     internal_vars: tuple[Var, ...]         # stay in VMEM
     external_inputs: tuple[Var, ...]       # streamed from HBM
     outputs: tuple[Var, ...]               # written to HBM
+    # an online-softmax group: the one axis its kernel streams, and each
+    # call's role (``online_roles``); None and () for any other group
+    stream_root: int | None = None
+    roles: tuple[str, ...] = ()
 
     @property
     def key(self) -> frozenset:
@@ -57,10 +70,100 @@ class Fusion:
         return f"Fusion[{names}]"
 
 
+def broadcastable(src: tuple[int, ...], dst: tuple[int, ...]) -> bool:
+    """True iff a block over axis roots ``src`` broadcasts onto one over
+    ``dst`` the way the online kernel spells it (``codegen._broadcast``):
+    a scalar, the same axes, or one axis of a two-axis block."""
+    return (not src or src == dst
+            or (len(src) == 1 and len(dst) == 2 and src[0] in dst))
+
+
+def online_roles(g: Graph, members: list[CallNode],
+                 idxset: set[int]) -> tuple[int, tuple[str, ...]] | None:
+    """``(t, roles)`` if ``members`` (topo order) form an online-softmax
+    group over the axis root t, else None.
+
+    The shape, read off the graph and the elementaries' properties:
+
+    * ``max``: the one MAX reduction consumed in the group, over t alone;
+    * ``exp``: maps ``exp(x - m)`` (``exp_sub_args``) of the max;
+    * ``div``: maps ``num / den`` (``div_args``) of an ``exp`` output by
+      an ``acc`` output;
+    * ``acc``: SUM reductions over t alone, linear in their one argument
+      that is an ``exp`` or ``div`` output;
+    * ``pre``: every other call, reading none of these and not reducing
+      over t.
+
+    Every call iterates over t, the group writes only ``acc`` outputs,
+    and the max's (and each divisor's) axes broadcast onto each ``acc``
+    output.  The kernel keeps a running max m and a running sum per
+    ``acc``; each t block rescales the sums by ``exp(m_old - m_new)``,
+    and the divisions wait for the last block (DESIGN.md §2)."""
+    maxes = [c for c in members if c.elem.is_reduction
+             and c.elem.monoid is Monoid.MAX
+             and any(cc.idx in idxset for cc in g.consumers(c.out))]
+    if len(maxes) != 1:
+        return None
+    mx = maxes[0]
+
+    def roots(v: Var) -> tuple[int, ...]:
+        return tuple(g.axis_root(a) for a in v.axis_ids)
+
+    def reduced(c: CallNode) -> set[int]:
+        return set(g.call_axis_roots(c)) - set(roots(c.out))
+
+    if len(reduced(mx)) != 1:
+        return None
+    (t,) = reduced(mx)
+    role: dict[Var, str] = {}         # the role of each member's output
+    divisor: dict[Var, Var] = {}      # div output -> its den
+    for c in members:
+        if t not in g.call_axis_roots(c):
+            return None
+        late = [i for i, a in enumerate(c.args) if role.get(a, PRE) != PRE]
+        e = c.elem
+        if c is mx:
+            r = MAX if not late else None
+        elif not late:
+            r = PRE if t not in reduced(c) else None
+        elif e.exp_sub_args and not e.is_reduction:
+            x, m = e.exp_sub_args
+            r = (EXP if late == [m] and c.args[m] is mx.out
+                 and t in roots(c.args[x]) else None)
+        elif e.div_args and not e.is_reduction:
+            num, den = e.div_args
+            r = (DIV if sorted(late) == sorted((num, den))
+                 and role[c.args[num]] == EXP and role[c.args[den]] == ACC
+                 else None)
+            if r:
+                divisor[c.out] = c.args[den]
+        elif (e.monoid is Monoid.SUM and reduced(c) == {t}
+              and len(late) == 1):
+            a = c.args[late[0]]
+            r = (ACC if late[0] in e.linear_args and role[a] in (EXP, DIV)
+                 and c.args.count(a) == 1
+                 and broadcastable(roots(mx.out), roots(c.out))
+                 and (a not in divisor or broadcastable(
+                     roots(divisor[a]), roots(c.out))) else None)
+        else:
+            r = None
+        if r is None:
+            return None
+        role[c.out] = r
+        consumed_outside = any(cc.idx not in idxset
+                               for cc in g.consumers(c.out))
+        if r != ACC and (g.escapes(c.out) or consumed_outside):
+            return None
+    return t, tuple(role[c.out] for c in members)
+
+
 def consumed_reductions(f: Fusion, g: Graph) -> tuple[CallNode, ...]:
     """Reduction members whose output is consumed *inside* ``f`` — the
     calls whose finished value a multi-phase pallas kernel must carry in
-    a VMEM scratch accumulator (rule 2, relaxed)."""
+    a VMEM scratch accumulator (rule 2, relaxed).  An online-softmax
+    group has none: its running max and sums are read unfinished."""
+    if f.stream_root is not None:
+        return ()
     idxset = {c.idx for c in f.calls}
     return tuple(c for c in f.calls if c.elem.is_reduction
                  and any(cc.idx in idxset for cc in g.consumers(c.out)))
@@ -107,40 +210,44 @@ def analyse_group(g: Graph, members: Iterable[CallNode],
         return None
     idxset = {c.idx for c in members}
 
-    # rule 1: identical unified axis sets
-    ref_roots = None
-    for c in members:
-        roots = tuple(sorted(g.call_axis_roots(c)))
-        if len(set(roots)) != len(roots):
-            return None  # degenerate: same axis twice
-        if ref_roots is None:
-            ref_roots = roots
-        elif roots != ref_roots:
-            return None
+    call_roots = [tuple(sorted(g.call_axis_roots(c))) for c in members]
+    if any(len(set(roots)) != len(roots) for roots in call_roots):
+        return None  # degenerate: same axis twice
+    online = online_roles(g, members, idxset)
     root_to_size = {}
     for c in members:
         for r, s in zip(g.call_axis_roots(c), c.axis_sizes):
             root_to_size[r] = s
 
-    # rule 2 (relaxed): a reduction output consumed inside the fusion is
-    # legal iff every consumed reduce-axis set can sit as an innermost
-    # suffix of ONE grid order — i.e. the consumed sets form a chain
-    # under inclusion.  Codegen then emits a multi-phase kernel carrying
-    # the finished value in VMEM scratch; otherwise the group is
-    # rejected and the partition search falls back to smaller fusions
-    # (the documented group-split, DESIGN.md §2).
-    rootset = set(ref_roots)
-    consumed_sets: list[set[int]] = []
-    for c in members:
-        if not c.elem.is_reduction:
-            continue
-        if any(cc.idx in idxset for cc in g.consumers(c.out)):
-            out_roots = {g.axis_root(a) for a in c.out.axis_ids}
-            consumed_sets.append(rootset - out_roots)
-    consumed_sets.sort(key=len)
-    for small, big in zip(consumed_sets, consumed_sets[1:]):
-        if not small <= big:
+    if online is not None:
+        # an online-softmax group iterates over the union of its calls'
+        # axes, and its one consumed MAX needs no phase (rules 1-2)
+        ref_roots = tuple(sorted(root_to_size))
+    else:
+        # rule 1: identical unified axis sets
+        ref_roots = call_roots[0]
+        if any(roots != ref_roots for roots in call_roots):
             return None
+
+        # rule 2 (relaxed): a reduction output consumed inside the fusion
+        # is legal iff every consumed reduce-axis set can sit as an
+        # innermost suffix of ONE grid order — i.e. the consumed sets
+        # form a chain under inclusion.  Codegen then emits a multi-phase
+        # kernel carrying the finished value in VMEM scratch; otherwise
+        # the group is rejected and the partition search falls back to
+        # smaller fusions (the documented group-split, DESIGN.md §2).
+        rootset = set(ref_roots)
+        consumed_sets: list[set[int]] = []
+        for c in members:
+            if not c.elem.is_reduction:
+                continue
+            if any(cc.idx in idxset for cc in g.consumers(c.out)):
+                out_roots = {g.axis_root(a) for a in c.out.axis_ids}
+                consumed_sets.append(rootset - out_roots)
+        consumed_sets.sort(key=len)
+        for small, big in zip(consumed_sets, consumed_sets[1:]):
+            if not small <= big:
+                return None
 
     # rule 3: convexity
     if reach is None:
@@ -196,14 +303,16 @@ def analyse_group(g: Graph, members: Iterable[CallNode],
                 seen_vars.add(a)
                 ext_inputs.append(a)
 
-    roots = ref_roots or ()
+    stream_root, roles = online or (None, ())
     return Fusion(
         calls=tuple(members),
-        axis_roots=roots,
-        axis_sizes=tuple(root_to_size[r] for r in roots),
+        axis_roots=ref_roots,
+        axis_sizes=tuple(root_to_size[r] for r in ref_roots),
         internal_vars=tuple(internal),
         external_inputs=tuple(ext_inputs),
         outputs=tuple(outputs),
+        stream_root=stream_root,
+        roles=roles,
     )
 
 

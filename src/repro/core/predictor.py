@@ -32,8 +32,8 @@ import math
 
 import numpy as np
 
-from .fusion import Fusion, call_phases, consumed_reductions
-from .graph import Graph, Var
+from .fusion import ACC, MAX, Fusion, call_phases, consumed_reductions
+from .graph import CallNode, Graph, Var
 
 #: a refit needs at least this many group records before the regression
 #: is better-determined than the analytic constants it would replace
@@ -378,6 +378,13 @@ def accumulable(v: Var, f: Fusion, g: Graph, order: tuple[int, ...]) -> bool:
     return set(order[-k:]) == rr
 
 
+def online_accumulators(f: Fusion) -> tuple[CallNode, ...]:
+    """An online-softmax group's calls that keep a running value in VMEM
+    scratch across its sweep: the max and every sum over the streamed
+    axis (none for any other group)."""
+    return tuple(c for c, r in zip(f.calls, f.roles) if r in (MAX, ACC))
+
+
 def cost_impl(f: Fusion, g: Graph, order: tuple[int, ...],
               blocks: tuple[int, ...], hw: HardwareModel) -> Impl:
     sizes = dict(zip(f.axis_roots, f.axis_sizes))
@@ -398,7 +405,7 @@ def cost_impl(f: Fusion, g: Graph, order: tuple[int, ...],
         traffic += v.nbytes * var_streams(v, g, order, grid) * n_phases
     for v in f.outputs:
         rr = reduce_roots_of(v, f, g)
-        if not rr or accumulable(v, f, g, order):
+        if not rr or accumulable(v, f, g, order) or f.stream_root is not None:
             traffic += v.nbytes
         else:
             nparts = math.prod(grid[order.index(r)] for r in rr)
@@ -430,8 +437,9 @@ def cost_impl(f: Fusion, g: Graph, order: tuple[int, ...],
         vmem += 2 * block_bytes(v)
     for v in f.internal_vars:
         vmem += block_bytes(v)
-    for c in consumed:
-        # full-size scratch accumulator carrying the finished reduction
+    for c in consumed + online_accumulators(f):
+        # full-size scratch accumulator carrying the finished reduction,
+        # or an online group's running max or sum
         vmem += padded_bytes(carrier(c.out)[0], c.out.dtype, hw)
 
     dt = fusion_dtype(f)
@@ -470,7 +478,16 @@ def enumerate_impls(f: Fusion, g: Graph, hw: HardwareModel = V5E,
     gran = block_granules(f, g, hw)
     consumed = consumed_reductions(f, g)
     cands: list[Impl] = []
-    if depth == 1:
+    if f.stream_root is not None:
+        # one sweep over the streamed axis, innermost; every other axis
+        # one whole block, so the contractions over them finish in a step
+        t = f.stream_root
+        order = tuple(r for r in roots if r != t) + (t,)
+        whole = tuple(sizes[roots.index(r)] for r in order[:-1])
+        for b in _divisor_blocks(sizes[roots.index(t)], gran[t],
+                                 maximum=1 << 16):
+            cands.append(cost_impl(f, g, order, whole + (b,), hw))
+    elif depth == 1:
         for b in _divisor_blocks(sizes[0], gran[roots[0]], maximum=1 << 22):
             cands.append(cost_impl(f, g, roots, (b,), hw))
     else:

@@ -52,7 +52,7 @@ from repro.core.elementary import exp_map, exp_sub, rsqrt_map  # noqa: E402,F401
 # w_i = e_i / z with the reduce-finished normalizer z broadcast
 div_by = make_map(
     "div_by", lambda z, e: e / z, arity=2, scalar_args=(0,),
-    flops_per_point=1)
+    flops_per_point=1, div_args=(1, 0))
 
 # --- attention contractions --------------------------------------------------
 
@@ -72,7 +72,8 @@ attn_out = make_nested_map_reduce(
         "...hgs,...shd->...hgd",
         w[..., None, None, :], V[..., :, None, :],
         precision="highest")[..., 0, 0, :],
-    in_axes=[(0, 1), (0,)], out_axis=1, flops_per_point=2)
+    in_axes=[(0, 1), (0,)], out_axis=1, flops_per_point=2,
+    linear_args=(0, 1))
 
 # --- latent attention (MLA), absorbed form ----------------------------------
 #
@@ -109,20 +110,21 @@ mla_max = make_nested_map_reduce(
     out_axis=0, monoid=Monoid.MAX, flops_per_point=1)
 mla_exp_sub = make_nested_map(
     "mla_exp_sub", lambda s, m: jnp.exp(s - col(m)),
-    in_axes=[(0, 1), (0,)], flops_per_point=2, pad_safe=False)
+    in_axes=[(0, 1), (0,)], flops_per_point=2, pad_safe=False,
+    exp_sub_args=(0, 1))
 mla_sum = make_nested_map_reduce(
     "mla_sum", lambda e: jnp.sum(e, axis=-1), in_axes=[(0, 1)],
-    out_axis=0, flops_per_point=1)
+    out_axis=0, flops_per_point=1, linear_args=(0,))
 mla_div = make_nested_map(
     "mla_div", lambda e, z: e / col(z), in_axes=[(0, 1), (0,)],
-    flops_per_point=1)
+    flops_per_point=1, div_args=(0, 1))
 
 # o_hc = sum_t p_ht ckv_tc over (h, t, c): the weighted latent rows
 mla_value = make_tensor_map_reduce(
     "mla_value",
     lambda p, v: jnp.einsum("...ht,...tc->...hc", p, v,
                             precision="highest"),
-    in_axes=[(0, 1), (1, 2)], reduce_axis=1)
+    in_axes=[(0, 1), (1, 2)], reduce_axis=1, linear_args=(0, 1))
 
 # --- AdamW (precision-matched variants of repro.optim.fused) -----------------
 

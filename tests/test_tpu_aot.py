@@ -12,13 +12,16 @@ refuse, at the sizes the chip smoke run serves:
 * LM_DECODE_ATTN at 32768 — a (n, 48) operand blocked in memory order;
 * ATAX at 16384 — a 1 GiB matrix with a consumed vector reduction;
 * MLA_DECODE_ATTN at 131072 — depth-3 contractions over (heads, cache,
-  latent) and a per-head (1, 16) vector carried in VMEM scratch.
+  latent) and a per-head (1, 16) vector carried in VMEM scratch, all in
+  one online-softmax kernel (also cut to 128 steps over the cache).
 
 Each Pallas compile must contain a Mosaic kernel (``tpu_custom_call``).
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this
 file.
 """
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -26,7 +29,7 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import FusionCompiler
+from repro.core import FusionCompiler, codegen
 from repro.programs import REGISTRY
 from repro.serving import ServingEngine
 
@@ -80,6 +83,24 @@ def test_pallas_program_compiles_for_v5e(name, n, one_chip, tpu_codegen):
     compiled = cc.compile(prog.script, prog.shapes(n))
     hlo = _compile(compiled, prog.shapes(n), one_chip)
     assert hlo.count("tpu_custom_call") >= compiled.n_groups
+
+
+def test_online_softmax_kernel_compiles_for_v5e(one_chip, tpu_codegen):
+    """MLA_DECODE_ATTN's plan at the benchmark's size is one
+    online-softmax kernel; it compiles as the predictor blocks it and
+    with the cache cut into 128 steps of 1024 positions."""
+    prog, n = REGISTRY["MLA_DECODE_ATTN"], 131072
+    cc = FusionCompiler(backend="pallas", cache=None)
+    compiled = cc.compile(prog.script, prog.shapes(n))
+    (im,) = compiled.group_impls
+    t = im.fusion.stream_root
+    assert t is not None
+    (gp,) = compiled.plan.groups
+    blocks = tuple(1024 if r == t else b for r, b in zip(im.order, im.blocks))
+    plan = dataclasses.replace(
+        compiled.plan, groups=(dataclasses.replace(gp, blocks=blocks),))
+    for p in (compiled, codegen.compile_plan(compiled.graph, plan)):
+        assert "tpu_custom_call" in _compile(p, prog.shapes(n), one_chip)
 
 
 def test_jnp_block_compiles_for_v5e(one_chip, tpu_codegen):
