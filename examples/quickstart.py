@@ -11,12 +11,12 @@ import time
 
 import numpy as np
 
-from repro.blas import REGISTRY, make_inputs
 from repro.core import FusionCompiler
+from repro.programs import BLAS, make_inputs
 
 def main():
     n = 2048
-    seq = REGISTRY["BiCGK"]
+    seq = BLAS["BiCGK"]
     cc = FusionCompiler()
 
     prog, report = cc.compile(seq.script, seq.shapes(n), report=True)

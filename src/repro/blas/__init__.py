@@ -1,7 +1,7 @@
-"""repro.blas — fusible BLAS elementary-function library + the paper's
-11 evaluation sequences."""
+"""repro.blas — fusible BLAS elementary-function library and synthetic
+call chains.  The paper's 11 sequences register in ``repro.programs``
+(``programs.BLAS``)."""
 from . import elementary_lib
-from .sequences import REGISTRY, Sequence, make_inputs, make_synthetic_chain
+from .synthetic import make_synthetic_chain
 
-__all__ = ["REGISTRY", "Sequence", "elementary_lib", "make_inputs",
-           "make_synthetic_chain"]
+__all__ = ["elementary_lib", "make_synthetic_chain"]

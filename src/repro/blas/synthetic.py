@@ -1,29 +1,14 @@
-"""The paper's 11 BLAS sequences — compatibility re-export.
+"""Synthetic elementary-call chains of arbitrary length.
 
-Registration moved to the generalized registry (``repro.programs``,
-DESIGN.md §10): the sequence scripts live in ``repro.programs.blas``
-and register into ``programs.BLAS``, which this module re-exports as
-``REGISTRY`` so every historical import site (``blas.REGISTRY``,
-``blas.Sequence``, ``blas.make_inputs``) keeps working unchanged —
-and keeps holding exactly the 11 paper sequences, not the model
-workloads registered alongside them.
+The paper's sequences are at most 5 calls; these chains scale the
+search and the backends past the hand-sized scripts.
 """
 from __future__ import annotations
 
-# importing the registry submodule initializes the repro.programs
-# package, which registers the BLAS and model program groups
-from repro.programs.registry import BLAS as REGISTRY
-from repro.programs.registry import Program as Sequence
-from repro.programs.registry import make_inputs
-
 from . import elementary_lib as lib
 
-__all__ = ["REGISTRY", "Sequence", "make_inputs", "make_synthetic_chain"]
+__all__ = ["make_synthetic_chain"]
 
-
-# ---------------------------------------------------------------------------
-# synthetic sequences — scale the search past the paper's hand-sized scripts
-# ---------------------------------------------------------------------------
 
 def make_synthetic_chain(n_calls: int, *, reduce_consume: bool = False,
                          gemv: bool = False, scalar_input: bool = False):
@@ -31,7 +16,7 @@ def make_synthetic_chain(n_calls: int, *, reduce_consume: bool = False,
 
     Mimics the dataflow of long vector pipelines (paper sequences are
     ≤ 5 calls; serving-scale graphs are not).  Returns ``(script,
-    shapes_fn, reference)`` in the ``Sequence`` calling convention so
+    shapes_fn, reference)`` in the ``Program`` calling convention so
     tests and benchmarks can drive the full compiler pipeline on graphs
     of arbitrary length.
 

@@ -2,8 +2,8 @@
 map/reduce elementary functions (Filipovič et al., 2013)."""
 from .autotune import (AutotuneReport, CandidateTiming, autotune_combination,
                        bandwidth_sweep, calibrate_hardware, group_key,
-                       impl_group_key, measure_callable, measure_group,
-                       measure_program, predict_combination, synthetic_inputs)
+                       measure_callable, measure_group, measure_program,
+                       synthetic_inputs)
 from .cache import BucketStats, CacheStats, PlanCache, default_cache
 from .codegen import (BatchedProgram, CompiledProgram, PackedDispatch,
                       PackedProgram, compile_plan_packed)
@@ -34,8 +34,7 @@ __all__ = [
     "build_packed_plan", "build_plan", "build_space",
     "calibrate_hardware", "canonical_pack_order", "compile_plan_packed",
     "default_cache", "group_key", "group_signature",
-    "impl_group_key", "pack_signature", "plan_fingerprint",
-    "predict_combination",
+    "pack_signature", "plan_fingerprint",
     "enumerate_combinations", "enumerate_fusions", "enumerate_impls",
     "exhaustive_best_combination", "graph_signature", "iter_combinations",
     "make_map", "make_nested_map", "make_nested_map_reduce", "make_reduce",

@@ -286,42 +286,6 @@ def _valid_group_record(rec) -> bool:
             and _finite_time(rec.get("t_meas")))
 
 
-def impl_group_key(g: Graph, im: Impl, fingerprint: str) -> str:
-    """Per-group table key computed straight from a bound ``Impl``
-    (the plan-free form of what ``autotune_combination`` keys)."""
-    order_pos = tuple(im.fusion.axis_roots.index(r) for r in im.order)
-    return group_key(group_signature(g, im.fusion), order_pos, im.blocks,
-                     fingerprint)
-
-
-def predict_combination(g: Graph, combo: Combination, hw: HardwareModel, *,
-                        backend: str = "jnp", interpret: bool = False,
-                        cache: PlanCache | None = None) -> float:
-    """Predicted seconds for one combination under the **two-phase
-    predictor** (DESIGN.md §8): a group present in ``cache``'s
-    per-group measured-cost table costs its measured time; an unseen
-    group costs ``hw.group_cost`` over its traffic/flops features —
-    with ``hw`` a refit model, that is the regression trained on the
-    very same table.  With ``cache=None`` (or an empty table) this
-    reduces exactly to the analytic ``sum(im.t_pred)`` recosted under
-    ``hw``."""
-    from .predictor import cost_impl, fusion_dtype
-    fp = hw_fingerprint(backend, interpret)
-    total = 0.0
-    for im in combo.impls:              # order is irrelevant to a sum
-        t = None
-        if cache is not None:
-            rec = cache.get_measurement(impl_group_key(g, im, fp))
-            if _valid_group_record(rec):
-                t = float(rec["t_meas"])
-        if t is None:
-            # re-derive features under ``hw`` (traffic/flops are
-            # hw-independent, but this keeps one costing code path)
-            t = cost_impl(im.fusion, g, im.order, im.blocks, hw).t_pred
-        total += t
-    return total
-
-
 def autotune_combination(space: OptimizationSpace, *,
                          hw: HardwareModel = V5E, backend: str = "jnp",
                          interpret: bool = False,

@@ -66,13 +66,13 @@ def serve_blas(args) -> dict:
     populates the plan cache, compile #2 (a restarted worker in the same
     process) is served from it, and each request dispatches exactly one
     jitted call."""
-    from repro.blas import REGISTRY, make_inputs
+    from repro.programs import BLAS, make_inputs
     from repro.core import V5E, FusionCompiler, PlanCache
 
-    if args.blas not in REGISTRY:
+    if args.blas not in BLAS:
         raise SystemExit(f"unknown sequence {args.blas!r}; "
-                         f"choose from {', '.join(REGISTRY)}")
-    seq = REGISTRY[args.blas]
+                         f"choose from {', '.join(BLAS)}")
+    seq = BLAS[args.blas]
     cache = PlanCache()
     mode = "autotune" if args.autotune else "best"
     # calibrated constants make the predicted candidate ordering (which
@@ -129,15 +129,15 @@ def serve_blas(args) -> dict:
 def serve_engine(args) -> dict:
     """Mixed-size synthetic workload through the batched ServingEngine
     (``--sharded``: the mesh-sharded variant)."""
-    from repro.blas import REGISTRY, make_inputs
+    from repro.programs import BLAS, make_inputs
     from repro.core import FusionCompiler
     from repro.serving import ServingEngine, ShardedServingEngine
 
     names = [s.strip() for s in args.blas.split(",")]
     for nm in names:
-        if nm not in REGISTRY:
+        if nm not in BLAS:
             raise SystemExit(f"unknown sequence {nm!r}; "
-                             f"choose from {', '.join(REGISTRY)}")
+                             f"choose from {', '.join(BLAS)}")
     if args.sizes:
         sizes = [int(s) for s in args.sizes.split(",")]
     else:
@@ -169,7 +169,7 @@ def serve_engine(args) -> dict:
     workload = []
     for i in range(args.requests):
         nm, n = names[i % len(names)], sizes[i % len(sizes)]
-        workload.append((nm, n, make_inputs(REGISTRY[nm], n,
+        workload.append((nm, n, make_inputs(BLAS[nm], n,
                                             seed=args.seed + i)))
 
     t0 = time.perf_counter()

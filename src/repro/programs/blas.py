@@ -9,9 +9,8 @@ against the honest baseline (paper §5.1).
 Tags (paper Table 1): F = improvable by fusion, S = by specialization,
 B = has a direct CUBLAS equivalent.
 
-Registration lives in the general ``repro.programs`` registry; the
-historical ``repro.blas.sequences`` module re-exports this group, so
-``blas.REGISTRY`` still holds exactly these 11.
+They register in the ``programs.BLAS`` group, which holds exactly
+these 11.
 """
 from __future__ import annotations
 

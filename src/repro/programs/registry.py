@@ -6,11 +6,9 @@ factory parameterized by the workload size, a reference implementation,
 and optional serving metadata (an input factory for well-conditioned
 random instances, explicit per-input pad identities).
 
-This generalizes ``repro.blas.sequences``: the paper's 11 BLAS
-sequences register here (``repro.programs.blas``) next to LM decode-step
-workloads (``repro.programs.models``) — the serving engine, benchmarks
-and tests drive both through one interface.  ``repro.blas`` re-exports
-the BLAS slice (``blas.REGISTRY``) so nothing downstream moved.
+The paper's 11 BLAS sequences register here (``repro.programs.blas``)
+next to LM decode-step workloads (``repro.programs.models``) — the
+serving engine, benchmarks and tests drive both through one interface.
 """
 from __future__ import annotations
 
@@ -24,9 +22,8 @@ import numpy as np
 class Program:
     """One registered workload.
 
-    The first six fields are the historical ``blas.Sequence`` layout
-    (positional compatibility preserved); the rest are serving metadata
-    new registrations may carry.
+    The first six fields define the workload; the rest are serving
+    metadata a registration may carry.
     """
 
     name: str
@@ -45,9 +42,6 @@ class Program:
     #: the engine analyze (and fall back to per-lane masking).
     pad_values: Mapping[str, Any] | None = None
 
-
-#: Back-compat alias — ``blas.Sequence`` has always been this shape.
-Sequence = Program
 
 #: All registered programs, by name.
 REGISTRY: dict[str, Program] = {}

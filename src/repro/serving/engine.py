@@ -206,8 +206,8 @@ class ServingEngine:
       max_batch: largest requests-per-dispatch; batch sizes quantize to
         powers of two up to this, bounding jit re-traces.
       min_bucket: floor of the power-of-two shape buckets.
-      registry: ``{name: Sequence}`` of servable sequences (defaults to
-        the paper's ``blas.REGISTRY``).
+      registry: ``{name: Program}`` of servable sequences (defaults to
+        the paper's ``programs.BLAS``).
       mode: search mode for bucket compiles (``"best"`` default;
         ``"autotune"`` measures the compiler's ``autotune_budget`` top
         candidates per bucket at warm/compile time — DESIGN.md §8 —
@@ -234,8 +234,8 @@ class ServingEngine:
                  mode: str = "best", max_pack: int = 8,
                  backend: str | None = None):
         if registry is None:
-            from ..blas import REGISTRY
-            registry = REGISTRY
+            from ..programs import BLAS
+            registry = BLAS
         if max_pack < 1:
             raise ValueError(f"max_pack must be >= 1, got {max_pack}")
         if backend is not None:

@@ -10,13 +10,14 @@ import sys
 import numpy as np
 import pytest
 
-from repro.blas import REGISTRY, elementary_lib as lib, make_inputs
+from repro.blas import elementary_lib as lib
 from repro.core import (FusionCompiler, HardwareModel, PlanCache,
                         autotune_combination, bandwidth_sweep,
                         best_combination, build_plan, calibrate_hardware,
                         codegen, enumerate_combinations, graph_signature,
                         measure_group, measure_program, synthetic_inputs)
 from repro.core import autotune as autotune_mod
+from repro.programs import BLAS, make_inputs
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -108,15 +109,15 @@ class TestCalibration:
 # ---------------------------------------------------------------------------
 
 class TestMeasuredSearch:
-    @pytest.mark.parametrize("name", list(REGISTRY))
+    @pytest.mark.parametrize("name", list(BLAS))
     def test_winner_never_slower_than_best_plan(self, name):
         """Acceptance criterion: the autotuned plan's measured runtime
-        is <= the ``mode='best'`` plan's on every REGISTRY sequence.
+        is <= the ``mode='best'`` plan's on every BLAS sequence.
         Candidate 0 of the predicted-order stream IS the best plan, and
         the winner is the measured argmin over a set containing it, so
         this holds within a single measurement pass by construction —
         the assert locks the construction."""
-        seq = REGISTRY[name]
+        seq = BLAS[name]
         cc = _tuned_compiler(cache=None)
         g = cc.trace(seq.script, seq.shapes(128))
         space = cc.space(g)
@@ -134,7 +135,7 @@ class TestMeasuredSearch:
 
     @pytest.mark.parametrize("name", ["AXPYDOT", "GEMVER", "BiCGK"])
     def test_autotune_mode_numerics(self, name):
-        seq = REGISTRY[name]
+        seq = BLAS[name]
         cc = _tuned_compiler(cache=PlanCache())
         prog = cc.compile(seq.script, seq.shapes(256), mode="autotune")
         assert cc.last_autotune is not None
@@ -158,7 +159,7 @@ class TestMeasuredSearch:
             return real(*a, **k)
 
         monkeypatch.setattr(codegen, "compile_plan", counting)
-        seq = REGISTRY["BiCGK"]
+        seq = BLAS["BiCGK"]
         cc = _tuned_compiler(PlanCache())
         prog = cc.compile(seq.script, seq.shapes(256), mode="autotune")
         assert calls["n"] == 1
@@ -169,7 +170,7 @@ class TestMeasuredSearch:
                                        rtol=1e-4, atol=1e-3)
 
     def test_report_candidates_in_predicted_order(self):
-        seq = REGISTRY["GEMVER"]
+        seq = BLAS["GEMVER"]
         cc = _tuned_compiler(cache=None, budget=4)
         g = cc.trace(seq.script, seq.shapes(128))
         space = cc.space(g)
@@ -198,7 +199,7 @@ class TestMeasuredCostCache:
         """Acceptance criterion: a second autotune compile of the same
         program performs zero measurements (plan-layer hit)."""
         cache = PlanCache()
-        seq = REGISTRY["BiCGK"]
+        seq = BLAS["BiCGK"]
         _tuned_compiler(cache).compile(seq.script, seq.shapes(256),
                                        mode="autotune")
 
@@ -220,7 +221,7 @@ class TestMeasuredCostCache:
         re-runs but every group is served from the measured-cost
         table — zero new measurements (``group_table_hit_rate == 1.0``,
         the PR acceptance gate)."""
-        seq = REGISTRY["GEMVER"]
+        seq = BLAS["GEMVER"]
         c1 = PlanCache(disk_dir=str(tmp_path))
         _tuned_compiler(c1).compile(seq.script, seq.shapes(256),
                                     mode="autotune")
@@ -262,7 +263,7 @@ class TestMeasuredCostCache:
         """The budget is a cache-key component (deeper search != shallow
         search), but measurements are shared per candidate — growing
         the budget re-measures nothing already in the table."""
-        seq = REGISTRY["GEMVER"]
+        seq = BLAS["GEMVER"]
         cache = PlanCache(disk_dir=str(tmp_path))
         _tuned_compiler(cache, budget=2).compile(
             seq.script, seq.shapes(256), mode="autotune")
@@ -290,7 +291,7 @@ class TestMeasuredCostCache:
         """Regression: a dict record missing a finite t_meas (schema
         drift) must not crash the search or poison its key — it is
         dropped and re-measured once."""
-        seq = REGISTRY["VADD"]
+        seq = BLAS["VADD"]
         cache = PlanCache(disk_dir=str(tmp_path))
         _tuned_compiler(cache, budget=2).compile(
             seq.script, seq.shapes(256), mode="autotune")
@@ -343,7 +344,7 @@ class TestMeasuredCostCache:
         serve program-level lookups exactly — a candidate they cover is
         never re-measured, and the report says where its time came
         from."""
-        seq = REGISTRY["VADD"]
+        seq = BLAS["VADD"]
         cc = _tuned_compiler(cache=None, budget=2)
         g = cc.trace(seq.script, seq.shapes(256))
         space = cc.space(g)
@@ -413,7 +414,7 @@ class TestDifferentialOracle:
     @pytest.mark.parametrize("name,n", [
         ("AXPYDOT", 1 << 20), ("BiCGK", 768), ("GEMVER", 768)])
     def test_sum_of_group_times_tracks_whole_program(self, name, n):
-        seq = REGISTRY[name]
+        seq = BLAS[name]
         cc = FusionCompiler(cache=None)
         g = cc.trace(seq.script, seq.shapes(n))
         space = cc.space(g)
@@ -453,7 +454,7 @@ class TestGroupTransfer:
         (localized signatures make group records program-independent)."""
         n = 256
         cache = PlanCache()
-        seq = REGISTRY["AXPYDOT"]
+        seq = BLAS["AXPYDOT"]
         cc = _tuned_compiler(cache)
         cc.compile(seq.script, seq.shapes(n), mode="autotune")
         assert len(cache.group_records()) >= 1
@@ -523,14 +524,14 @@ class TestBandwidthSweep:
 
 AUTOTUNE_WARM_SCRIPT = """
 import json
-from repro.blas import REGISTRY
+from repro.programs import BLAS
 from repro.core import FusionCompiler, PlanCache
 
 cache = PlanCache()   # REPRO_PLAN_CACHE_DIR from the environment
 cc = FusionCompiler(cache=cache, autotune_budget=2, autotune_reps=1,
                     autotune_warmup=1)
 for name in ("AXPYDOT", "VADD"):
-    seq = REGISTRY[name]
+    seq = BLAS[name]
     cc.compile(seq.script, seq.shapes(64), mode="autotune")
 print(json.dumps(cache.stats.as_dict()))
 """
@@ -568,7 +569,7 @@ def test_autotune_concurrent_writers(tmp_path, monkeypatch):
     cache = PlanCache(disk_dir=d)
     cc = _tuned_compiler(cache, budget=2)
     for name in ("AXPYDOT", "VADD"):
-        seq = REGISTRY[name]
+        seq = BLAS[name]
         cc.compile(seq.script, seq.shapes(64), mode="autotune")
     assert cache.stats.disk_hits == 2          # plans from disk
     assert cache.stats.meas_writes == 0        # nothing re-measured
@@ -584,7 +585,7 @@ class TestEngineWiring:
         found by the unbatched path (identical plan keys)."""
         cache = PlanCache()
         cc = _tuned_compiler(cache)
-        seq = REGISTRY["VADD"]
+        seq = BLAS["VADD"]
         cc.compile(seq.script, seq.shapes(256), mode="autotune")
 
         def boom(*a, **k):
@@ -606,7 +607,7 @@ class TestEngineWiring:
         engine = ServingEngine(compiler=_tuned_compiler(PlanCache()),
                                max_batch=4, min_bucket=64, mode="autotune")
         engine.warm("AXPYDOT", [100], trace_batches=False)
-        seq = REGISTRY["AXPYDOT"]
+        seq = BLAS["AXPYDOT"]
         engine.submit("AXPYDOT", 100, make_inputs(seq, 100, seed=1))
         (res,) = engine.drain()
         z, r = seq.reference(**make_inputs(seq, 100, seed=1))
@@ -622,27 +623,27 @@ class TestModeValidation:
     @pytest.mark.parametrize("bad", [True, False])
     def test_bool_mode_rejected(self, bad):
         cc = FusionCompiler(cache=None)
-        seq = REGISTRY["VADD"]
+        seq = BLAS["VADD"]
         with pytest.raises(ValueError, match="valid modes.*best"):
             cc.compile(seq.script, seq.shapes(128), mode=bad)
 
     def test_unknown_string_mode_rejected(self):
         cc = FusionCompiler(cache=None)
-        seq = REGISTRY["VADD"]
+        seq = BLAS["VADD"]
         with pytest.raises(ValueError,
                            match="'best', 'unfused', 'autotune'"):
             cc.compile(seq.script, seq.shapes(128), mode="bogus")
 
     def test_search_rejects_bool_directly(self):
         cc = FusionCompiler(cache=None)
-        seq = REGISTRY["VADD"]
+        seq = BLAS["VADD"]
         space = cc.space(cc.trace(seq.script, seq.shapes(128)))
         with pytest.raises(ValueError, match="valid modes"):
             cc.search(space, True)
 
     def test_integer_modes_still_work(self):
         cc = FusionCompiler(cache=None)
-        seq = REGISTRY["VADD"]
+        seq = BLAS["VADD"]
         prog = cc.compile(seq.script, seq.shapes(128), mode=1)
         inputs = make_inputs(seq, 128, seed=2)
         np.testing.assert_allclose(
@@ -653,7 +654,7 @@ class TestModeValidation:
         """Out-of-range ranks used to clamp to the last combination —
         silently, and caching a duplicate plan under the wrong key."""
         cc = FusionCompiler(cache=None)
-        seq = REGISTRY["SSCAL"]                  # exactly 1 combination
+        seq = BLAS["SSCAL"]                  # exactly 1 combination
         with pytest.raises(ValueError, match="out of range"):
             cc.compile(seq.script, seq.shapes(128), mode=5)
         with pytest.raises(ValueError, match=">= 0"):
@@ -668,7 +669,7 @@ class TestCompileAll:
     def test_records_stats_and_reuses_cache(self):
         cache = PlanCache()
         cc = FusionCompiler(cache=cache)
-        seq = REGISTRY["GEMVER"]       # n=256: >= 4 legal combinations
+        seq = BLAS["GEMVER"]       # n=256: >= 4 legal combinations
         res1 = cc.compile_all(seq.script, seq.shapes(256), limit=4)
         assert len(res1) == 4
         assert cache.stats.plan_misses == 4      # visible to telemetry
@@ -683,7 +684,7 @@ class TestCompileAll:
     def test_shares_keys_with_integer_mode_compile(self):
         cache = PlanCache()
         cc = FusionCompiler(cache=cache)
-        seq = REGISTRY["BiCGK"]
+        seq = BLAS["BiCGK"]
         res = cc.compile_all(seq.script, seq.shapes(128), limit=3)
         before = cache.stats.program_hits
         prog = cc.compile(seq.script, seq.shapes(128), mode=1)
@@ -692,7 +693,7 @@ class TestCompileAll:
 
     def test_truncates_at_space_size(self):
         cc = FusionCompiler(cache=PlanCache())
-        seq = REGISTRY["SSCAL"]                  # tiny space
+        seq = BLAS["SSCAL"]                  # tiny space
         res = cc.compile_all(seq.script, seq.shapes(128), limit=50)
         n = len(res)
         assert 0 < n < 50
@@ -702,7 +703,7 @@ class TestCompileAll:
 
     def test_programs_run(self):
         cc = FusionCompiler(cache=PlanCache())
-        seq = REGISTRY["AXPYDOT"]
+        seq = BLAS["AXPYDOT"]
         res = cc.compile_all(seq.script, seq.shapes(128), limit=3)
         inputs = make_inputs(seq, 128, seed=4)
         want = seq.reference(**inputs)

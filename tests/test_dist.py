@@ -17,6 +17,7 @@ import pytest
 
 from repro.core import FusionCompiler, PlanCache
 from repro.dist import moe_ep, sharding
+from repro.programs import BLAS
 from repro.serving import ServingEngine, ShardedServingEngine, replica_fill
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -84,8 +85,8 @@ def test_supported_needs_mesh():
 def test_sharded_engine_single_device_fallback():
     """On a 1-device ('data',) mesh the sharded engine degrades to the
     base engine: same results, plain batched programs."""
-    from repro.blas import REGISTRY, make_inputs
     from repro.launch.mesh import make_data_mesh
+    from repro.programs import make_inputs
     if len(jax.devices()) != 1:
         pytest.skip("needs the default single-device CPU runtime")
     mesh = make_data_mesh(1)
@@ -94,7 +95,7 @@ def test_sharded_engine_single_device_fallback():
     shd = ShardedServingEngine(mesh, compiler=FusionCompiler(cache=PlanCache()),
                                max_batch=4, min_bucket=64)
     assert shd.n_replicas == 1 and shd.max_batch == 4
-    wl = [("AXPYDOT", 100, make_inputs(REGISTRY["AXPYDOT"], 100, seed=i))
+    wl = [("AXPYDOT", 100, make_inputs(BLAS["AXPYDOT"], 100, seed=i))
           for i in range(6)]
     r1 = {r.rid: r for r in base.serve(wl)}
     r2 = {r.rid: r for r in shd.serve(wl)}
@@ -222,16 +223,16 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import numpy as np
-from repro.blas import REGISTRY, make_inputs
+from repro.programs import BLAS, make_inputs
 from repro.core import FusionCompiler, PlanCache
 from repro.serving import ServingEngine, ShardedServingEngine
 
 # 16 requests per (sequence, bucket) on 8 replicas -> 2-row blocks per
 # replica, the bit-stable regime (see ShardedServingEngine docstring)
 wl, i = [], 0
-for name in REGISTRY:
+for name in BLAS:
     for _ in range(16):
-        wl.append((name, 100, make_inputs(REGISTRY[name], 100, seed=i)))
+        wl.append((name, 100, make_inputs(BLAS[name], 100, seed=i)))
         i += 1
 
 single = ServingEngine(compiler=FusionCompiler(cache=PlanCache()),
@@ -247,7 +248,7 @@ for k in r1:
         mismatch.append(r1[k].sequence)
 ref_bad = []
 for rid, (name, n, inputs) in enumerate(wl):
-    ref = REGISTRY[name].reference(
+    ref = BLAS[name].reference(
         **{k: np.asarray(v, np.float64) for k, v in inputs.items()})
     for o, r in zip(r2[rid].outputs, ref):
         if not np.allclose(np.asarray(o, np.float64), r, rtol=1e-4,
@@ -262,13 +263,12 @@ print(json.dumps({"mismatch": sorted(set(mismatch)),
 
 
 def test_sharded_engine_bitwise_equal_all_sequences():
-    """Every REGISTRY sequence served through the 8-replica sharded
+    """Every BLAS sequence served through the 8-replica sharded
     engine returns bitwise-identical outputs to the single-device
     engine, and matches the float64 numpy oracle."""
     out = _run(EQ_SCRIPT, timeout=1200)
     assert out["n_replicas"] == 8
-    assert out["n"] == 16 * len(__import__("repro.blas",
-                                           fromlist=["REGISTRY"]).REGISTRY)
+    assert out["n"] == 16 * len(BLAS)
     assert not out["mismatch"], f"bitwise mismatch: {out['mismatch']}"
     assert not out["ref_bad"], f"oracle mismatch: {out['ref_bad']}"
     assert all(r > 0 for r in out["replica_rows"])   # every replica used
@@ -279,20 +279,20 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json
 import numpy as np
-from repro.blas import REGISTRY, make_inputs
+from repro.programs import BLAS, make_inputs
 from repro.core import FusionCompiler, PlanCache
 from repro.serving import ShardedServingEngine
 
 eng = ShardedServingEngine(compiler=FusionCompiler(cache=PlanCache()),
                            max_batch=8, min_bucket=64)
-wl = [("AXPYDOT", 100, make_inputs(REGISTRY["AXPYDOT"], 100, seed=i))
+wl = [("AXPYDOT", 100, make_inputs(BLAS["AXPYDOT"], 100, seed=i))
       for i in range(5)]          # 5 requests over 8 replicas: uneven
 for name, n, inputs in wl:
     eng.submit(name, n, inputs)
 res = {r.rid: r for r in eng.drain()}
 bad = []
 for rid, (name, n, inputs) in enumerate(wl):
-    ref = REGISTRY[name].reference(
+    ref = BLAS[name].reference(
         **{k: np.asarray(v, np.float64) for k, v in inputs.items()})
     for o, r in zip(res[rid].outputs, ref):
         if not np.allclose(np.asarray(o, np.float64), r, rtol=1e-4,
@@ -323,13 +323,13 @@ CACHE_WARM_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import json, sys
-from repro.blas import REGISTRY
+from repro.programs import BLAS
 from repro.core import FusionCompiler, PlanCache
 
 cache = PlanCache(disk_dir=sys.argv[1] if len(sys.argv) > 1 else None)
 cc = FusionCompiler(cache=cache)
 for name in ("GEMVER", "AXPYDOT", "ATAX", "BiCGK"):
-    seq = REGISTRY[name]
+    seq = BLAS[name]
     cc.compile(seq.script, seq.shapes(64))
 print(json.dumps(cache.stats.as_dict()))
 """
@@ -339,7 +339,6 @@ def test_plan_cache_concurrent_writers(tmp_path):
     """Two processes warming the same REPRO_PLAN_CACHE_DIR concurrently
     leave a consistent cache: every entry parses, no temp litter, and a
     fresh compiler is served from disk without re-searching."""
-    from repro.blas import REGISTRY
     from repro.core.plan import ExecutionPlan
 
     d = str(tmp_path / "plans")
@@ -366,7 +365,7 @@ def test_plan_cache_concurrent_writers(tmp_path):
     cache = PlanCache(disk_dir=d)
     cc = FusionCompiler(cache=cache)
     for name in ("GEMVER", "AXPYDOT", "ATAX", "BiCGK"):
-        seq = REGISTRY[name]
+        seq = BLAS[name]
         cc.compile(seq.script, seq.shapes(64))
     st = cache.stats
     assert st.disk_hits == 4 and st.plan_misses == 0

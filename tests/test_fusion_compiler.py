@@ -3,16 +3,17 @@ scheduling — the paper's §3.2/§4.2 behaviours."""
 import numpy as np
 import pytest
 
-from repro.blas import REGISTRY, elementary_lib as lib
+from repro.blas import elementary_lib as lib
 from repro.core import (V5E, FusionCompiler, analyse_group, best_combination,
                         build_space, enumerate_fusions, make_tensor_map,
                         saves_traffic, trace, unfused_combination)
 from repro.core.predictor import (accumulable, cost_impl, enumerate_impls,
                                   fusion_dtype, reduce_roots_of, var_streams)
+from repro.programs import BLAS
 
 
 def _graph(name, n=256):
-    seq = REGISTRY[name]
+    seq = BLAS[name]
     return trace(seq.script, seq.shapes(n))
 
 
@@ -86,7 +87,7 @@ class TestLegality:
 
 
 class TestSchedule:
-    @pytest.mark.parametrize("name", list(REGISTRY))
+    @pytest.mark.parametrize("name", list(BLAS))
     def test_partition_covers(self, name):
         g = _graph(name)
         space = build_space(g)
@@ -95,7 +96,7 @@ class TestSchedule:
         assert covered == list(range(len(g.calls)))
 
     def test_best_no_worse_than_unfused(self):
-        for name in REGISTRY:
+        for name in BLAS:
             g = _graph(name)
             space = build_space(g)
             assert (best_combination(space).t_pred
@@ -180,7 +181,7 @@ class TestDtypeCostModel:
         assert V5E.flops_scale(np.float64) == V5E.f32_scale / 2
 
     def test_fusion_dtype_is_widest_stream(self):
-        seq = REGISTRY["VADD"]
+        seq = BLAS["VADD"]
         g16 = trace(seq.script, seq.shapes(256), dtype=np.float16)
         f = next(f for f in enumerate_fusions(g16) if len(f.calls) == 2)
         assert fusion_dtype(f) == np.float16
@@ -188,7 +189,7 @@ class TestDtypeCostModel:
     def test_cost_tracks_itemsize(self):
         """Halving the itemsize halves traffic and (for a sub-4-byte
         dtype) doubles the modelled compute rate."""
-        seq = REGISTRY["VADD"]
+        seq = BLAS["VADD"]
         impls = {}
         for dt in (np.float32, np.float16):
             g = trace(seq.script, seq.shapes(1 << 20), dtype=dt)

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import ref
 from repro.kernels.adamw import adamw_update
 from repro.kernels.bicgk import bicgk
 from repro.kernels.decode_attention import decode_attention
@@ -91,15 +91,6 @@ def test_decode_attention(B, Hq, Hkv, S, d):
     want = ref.decode_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=3e-4, atol=3e-4)
-
-
-def test_ops_fallback_on_odd_shapes():
-    """Public API degrades to the jnp reference for unaligned shapes."""
-    x = jnp.asarray(randn(7, 33))
-    g = jnp.asarray(randn(33))
-    got = ops.rmsnorm(x, g, use_pallas=True)     # 33 % 128 != 0 -> ref
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref.rmsnorm(x, g)),
-                               rtol=1e-6)
 
 
 def test_fused_adamw_matches_pallas_and_ref():

@@ -1,6 +1,6 @@
 """Cross-sequence packed dispatch (DESIGN.md §9): PackedPlan merging +
 canonical order, packed codegen bitwise-equal to the unpacked batched
-path (all REGISTRY sequences, reduce- and map-rooted mixes, single-
+path (all BLAS sequences, reduce- and map-rooted mixes, single-
 member packs, heterogeneous batch sizes), order-independent pack
 caching (memory and disk), the engine's pack-aware drain (cold-member
 fallback, pack warm, queue-wait telemetry), and the ``bucket_of``
@@ -8,10 +8,10 @@ fallback, pack warm, queue-wait telemetry), and the ``bucket_of``
 import numpy as np
 import pytest
 
-from repro.blas import REGISTRY, make_inputs
 from repro.core import (FusionCompiler, PackedPlan, PlanCache,
                         build_packed_plan, build_plan, canonical_pack_order,
                         pack_signature, plan_fingerprint)
+from repro.programs import BLAS, make_inputs
 from repro.serving import ServingEngine, bucket_of
 
 BUCKET = 128
@@ -24,14 +24,14 @@ def _engine(max_batch=4, max_pack=8, **kw):
 
 
 def _members(names, n=BUCKET):
-    return [(REGISTRY[nm].script, REGISTRY[nm].shapes(n)) for nm in names]
+    return [(BLAS[nm].script, BLAS[nm].shapes(n)) for nm in names]
 
 
 def _batched_inputs(name, n, batch, seed=0):
     return {k: np.stack([np.asarray(v) for v in vs]) for k, vs in
-            {k: [make_inputs(REGISTRY[name], n, seed=seed + i)[k]
+            {k: [make_inputs(BLAS[name], n, seed=seed + i)[k]
                  for i in range(batch)]
-             for k in REGISTRY[name].shapes(n)}.items()}
+             for k in BLAS[name].shapes(n)}.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -43,7 +43,7 @@ class TestPackedPlan:
         cc = FusionCompiler(cache=None)
         plans = []
         for nm in names:
-            g = cc.trace(REGISTRY[nm].script, REGISTRY[nm].shapes(BUCKET))
+            g = cc.trace(BLAS[nm].script, BLAS[nm].shapes(BUCKET))
             plans.append(build_plan(g, cc.search(cc.space(g), "best"),
                                     "jnp"))
         return plans
@@ -110,7 +110,7 @@ class TestPackedCodegen:
                          for i, (nm, b) in enumerate(zip(names, batches))]
         packed_outs = dispatch(member_inputs)
         for nm, inputs, outs in zip(names, member_inputs, packed_outs):
-            seq = REGISTRY[nm]
+            seq = BLAS[nm]
             prog = cc.compile_batched(seq.script, seq.shapes(n),
                                       max_batch=max_batch)
             ref = prog(**inputs)
@@ -121,9 +121,9 @@ class TestPackedCodegen:
                 np.testing.assert_array_equal(np.asarray(o), np.asarray(r))
 
     def test_all_registry_sequences_bitwise_equal(self):
-        """Every REGISTRY sequence, packed together, bit-for-bit the
+        """Every BLAS sequence, packed together, bit-for-bit the
         per-sequence batched dispatch."""
-        self._parity(list(REGISTRY))
+        self._parity(list(BLAS))
 
     def test_reduce_and_map_rooted_mix(self):
         # AXPYDOT/ATAX reduce-rooted, VADD/SSCAL map-rooted
@@ -188,31 +188,59 @@ class TestPackCache:
 # ---------------------------------------------------------------------------
 
 def _mixed_workload(names, n=100, per=4, seed=0):
-    return [(nm, n, make_inputs(REGISTRY[nm], n, seed=seed + i))
+    return [(nm, n, make_inputs(BLAS[nm], n, seed=seed + i))
             for i, nm in enumerate(names * per)]
+
+
+def _assert_bitwise(results_a, results_b):
+    """Every request's outputs identical, matched by rid."""
+    a = {r.rid: r for r in results_a}
+    b = {r.rid: r for r in results_b}
+    assert set(a) == set(b)
+    for rid in a:
+        assert len(a[rid].outputs) == len(b[rid].outputs)
+        for x, y in zip(a[rid].outputs, b[rid].outputs):
+            np.testing.assert_array_equal(x, y)
 
 
 class TestEnginePacking:
     def test_mixed_drain_bitwise_equals_unpacked_all_registry(self):
-        """All 11 REGISTRY sequences mixed in one drain: the packed
+        """All 11 BLAS sequences mixed in one drain: the packed
         engine's outputs are bitwise those of a max_pack=1 engine."""
-        names = list(REGISTRY)
+        names = list(BLAS)
         workload = _mixed_workload(names, per=2)
         packed = _engine(max_batch=2, max_pack=8)
         unpacked = _engine(max_batch=2, max_pack=1)
         for e in (packed, unpacked):        # warm so the drain packs
             for nm in names:
                 e.warm(nm, [100], trace_batches=False, trace_packs=False)
-        rp = {r.rid: r for r in packed.serve(workload)}
-        ru = {r.rid: r for r in unpacked.serve(workload)}
+        rp, ru = packed.serve(workload), unpacked.serve(workload)
         assert packed.n_packed_dispatches >= 1
         assert unpacked.n_packed_dispatches == 0
         assert packed.n_dispatches < unpacked.n_dispatches
-        assert set(rp) == set(ru)
-        for rid in rp:
-            assert len(rp[rid].outputs) == len(ru[rid].outputs)
-            for a, b in zip(rp[rid].outputs, ru[rid].outputs):
-                np.testing.assert_array_equal(a, b)
+        _assert_bitwise(rp, ru)
+
+    def test_packed_gate_mix(self):
+        """The packing gate: 32 requests over all 11 BLAS sequences at
+        the dispatch-bound buckets 64/100/128, every key warmed and its
+        packs pre-traced.  The packed engine forms packs, needs at most
+        half the unpacked engine's dispatches, and returns its outputs
+        bit for bit."""
+        names, sizes = list(BLAS), (64, 100, 128)
+        workload = []
+        for i in range(32):
+            nm, n = names[i % len(names)], sizes[i // len(names) % len(sizes)]
+            workload.append((nm, n, make_inputs(BLAS[nm], n, seed=i)))
+        packed = _engine(max_batch=8, max_pack=8)
+        unpacked = _engine(max_batch=8, max_pack=1)
+        for e in (packed, unpacked):
+            for nm in names:
+                e.warm(nm, sizes, trace_packs=False)
+            e.warm_packs()
+        rp, ru = packed.serve(workload), unpacked.serve(workload)
+        assert packed.n_packed_dispatches >= 1
+        assert unpacked.n_dispatches / packed.n_dispatches >= 2.0
+        _assert_bitwise(rp, ru)
 
     def test_cold_member_falls_back_to_unpacked(self):
         engine = _engine(max_batch=4, max_pack=8)

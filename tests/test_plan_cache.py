@@ -6,16 +6,17 @@ import time
 import numpy as np
 import pytest
 
-from repro.blas import REGISTRY, make_inputs, make_synthetic_chain
+from repro.blas import make_synthetic_chain
 from repro.core import (FusionCompiler, PlanCache, build_plan, build_space,
                         codegen, exhaustive_best_combination, graph_signature,
                         scheduler, trace)
 from repro.core.plan import ExecutionPlan
 from repro.core.predictor import V5E
+from repro.programs import BLAS, make_inputs
 
 
 def _space(name, n=256):
-    seq = REGISTRY[name]
+    seq = BLAS[name]
     g = trace(seq.script, seq.shapes(n))
     return g, build_space(g)
 
@@ -25,7 +26,7 @@ def _space(name, n=256):
 # ---------------------------------------------------------------------------
 
 class TestDPSearch:
-    @pytest.mark.parametrize("name", list(REGISTRY))
+    @pytest.mark.parametrize("name", list(BLAS))
     def test_dp_matches_exhaustive(self, name):
         """The bitmask DP finds exactly the exhaustive optimum on every
         seed sequence (acceptance criterion)."""
@@ -109,7 +110,7 @@ class TestExecutionPlan:
 
         # rebind against a FRESH trace of the same script (the disk-cache
         # cold-process path) and check numerics against the oracle
-        seq = REGISTRY[name]
+        seq = BLAS[name]
         g2 = trace(seq.script, seq.shapes(256))
         assert graph_signature(g2) == plan.signature
         prog = codegen.compile_plan(g2, plan2, hw=V5E)
@@ -127,7 +128,7 @@ class TestExecutionPlan:
         assert sum(i.t_pred for i in impls) == pytest.approx(combo.t_pred)
 
     def test_signature_distinguishes_shapes_and_dtypes(self):
-        seq = REGISTRY["BiCGK"]
+        seq = BLAS["BiCGK"]
         s1 = graph_signature(trace(seq.script, seq.shapes(256)))
         s2 = graph_signature(trace(seq.script, seq.shapes(512)))
         s3 = graph_signature(trace(seq.script, seq.shapes(256),
@@ -147,7 +148,7 @@ class TestCache:
         re-traces or re-searches."""
         cache = PlanCache()
         cc = FusionCompiler(cache=cache)
-        seq = REGISTRY["BiCGK"]
+        seq = BLAS["BiCGK"]
         p1 = cc.compile(seq.script, seq.shapes(512))
 
         def boom(*a, **k):
@@ -162,7 +163,7 @@ class TestCache:
     def test_key_miss_on_different_shape_mode_backend(self):
         cache = PlanCache()
         cc = FusionCompiler(cache=cache)
-        seq = REGISTRY["BiCGK"]
+        seq = BLAS["BiCGK"]
         cc.compile(seq.script, seq.shapes(256))
         cc.compile(seq.script, seq.shapes(512))            # shape miss
         cc.compile(seq.script, seq.shapes(256), mode="unfused")  # mode miss
@@ -174,7 +175,7 @@ class TestCache:
         search via the plan layer even though its program layer entry
         was populated by the first (same keys)."""
         cache = PlanCache()
-        seq = REGISTRY["GEMVER"]
+        seq = BLAS["GEMVER"]
         FusionCompiler(cache=cache).compile(seq.script, seq.shapes(256))
         FusionCompiler(cache=cache).compile(seq.script, seq.shapes(256))
         assert cache.stats.program_hits == 1
@@ -183,7 +184,7 @@ class TestCache:
     def test_disk_layer_cold_process(self, tmp_path, monkeypatch):
         """A cold process (empty in-memory cache, same disk dir) loads
         the plan from disk and never searches."""
-        seq = REGISTRY["GEMVER"]
+        seq = BLAS["GEMVER"]
         c1 = PlanCache(disk_dir=str(tmp_path))
         FusionCompiler(cache=c1).compile(seq.script, seq.shapes(256))
         assert c1.stats.disk_writes == 1
@@ -282,7 +283,7 @@ class TestCache:
 
     def test_cache_disabled(self):
         cc = FusionCompiler(cache=None)
-        seq = REGISTRY["VADD"]
+        seq = BLAS["VADD"]
         p1 = cc.compile(seq.script, seq.shapes(256))
         p2 = cc.compile(seq.script, seq.shapes(256))
         assert p1 is not p2
@@ -326,7 +327,7 @@ class TestWholeProgramRuntime:
         """The program fn is pure/positional — batch it with vmap (the
         serving case)."""
         import jax
-        seq = REGISTRY["VADD"]
+        seq = BLAS["VADD"]
         cc = FusionCompiler(cache=None)
         prog = cc.compile(seq.script, seq.shapes(128))
         batched = jax.vmap(lambda w, y, z: prog.fn(w, y, z))
@@ -340,7 +341,7 @@ class TestWholeProgramRuntime:
     def test_block_until_ready_non_array_leaves(self):
         """Regression: tree-mapping block_until_ready over Python
         scalars must not crash."""
-        seq = REGISTRY["AXPYDOT"]
+        seq = BLAS["AXPYDOT"]
         cc = FusionCompiler(cache=None)
         prog = cc.compile(seq.script, seq.shapes(256))
         out = prog(**make_inputs(seq, 256))
@@ -351,7 +352,7 @@ class TestWholeProgramRuntime:
         """Codegen no longer hardcodes float32: a float64 trace yields
         float64 outputs (jnp backend; x64 off truncates to f32 values
         but dtype plumbing is what's under test via the plan)."""
-        seq = REGISTRY["VADD"]
+        seq = BLAS["VADD"]
         g = trace(seq.script, seq.shapes(128), dtype=np.float64)
         assert all(v.dtype == np.float64 for v in g.inputs)
         assert all(c.out.dtype == np.float64 for c in g.calls)

@@ -1,12 +1,12 @@
 """The generalized program registry (repro.programs, DESIGN.md §10):
-group structure, the backward-compatible ``repro.blas`` re-export,
-registration invariants, and per-program input factories."""
+group structure, registration invariants, and per-program input
+factories."""
 import numpy as np
 import pytest
 
-from repro import blas, programs
-from repro.programs import (ADAMW_HYPERS, BLAS, MODELS, REGISTRY, Program,
-                            Sequence, make_inputs, register)
+from repro import programs
+from repro.programs import (ADAMW_HYPERS, BLAS, MODELS, REGISTRY,
+                            make_inputs, register)
 
 PAPER_SEQUENCES = ["AXPYDOT", "ATAX", "BiCGK", "SGEMV", "SGEMVT", "SSCAL",
                    "GEMVER", "GESUMMV", "MADD", "VADD", "WAXPBY"]
@@ -24,16 +24,9 @@ def test_groups_partition_the_registry():
 
 
 def test_blas_module_reexports_the_blas_group():
-    """Every historical import site keeps working AND keeps seeing only
-    the 11 paper sequences."""
-    assert blas.REGISTRY is BLAS
-    assert blas.Sequence is Program
-    assert blas.make_inputs is make_inputs
-    assert sorted(blas.REGISTRY) == sorted(PAPER_SEQUENCES)
-
-
-def test_sequence_is_program_alias():
-    assert Sequence is Program
+    """The package-level BLAS group holds exactly the 11 paper
+    sequences, none of the model workloads registered beside them."""
+    assert sorted(programs.BLAS) == sorted(PAPER_SEQUENCES)
 
 
 def test_register_rejects_duplicate_names():

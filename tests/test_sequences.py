@@ -3,14 +3,14 @@ compiler pipeline, on both backends, against numpy oracles."""
 import numpy as np
 import pytest
 
-from repro.blas import REGISTRY, make_inputs
 from repro.core import FusionCompiler
+from repro.programs import BLAS, make_inputs
 
 SIZES = {"jnp": 1024, "pallas": 256}
 
 
 def _run(name, backend, n, mode="best"):
-    seq = REGISTRY[name]
+    seq = BLAS[name]
     cc = FusionCompiler(backend=backend, interpret=True)
     prog = cc.compile(seq.script, seq.shapes(n), mode=mode)
     inputs = make_inputs(seq, n, seed=3)
@@ -22,12 +22,12 @@ def _run(name, backend, n, mode="best"):
         np.testing.assert_allclose(np.asarray(o), r, rtol=1e-4, atol=1e-3)
 
 
-@pytest.mark.parametrize("name", list(REGISTRY))
+@pytest.mark.parametrize("name", list(BLAS))
 def test_jnp_backend(name):
     _run(name, "jnp", SIZES["jnp"])
 
 
-@pytest.mark.parametrize("name", list(REGISTRY))
+@pytest.mark.parametrize("name", list(BLAS))
 def test_pallas_backend(name):
     _run(name, "pallas", SIZES["pallas"])
 

@@ -2,24 +2,18 @@
 sweep: shape buckets, reduction-safe padding against numpy oracles,
 vmap horizontal fusion bitwise-equal to single dispatch, one plan per
 (sequence, bucket), per-bucket cache stats, and the hardened error
-paths (unfused singletons, empty enumeration, unknown kwargs, timing
-parity)."""
-import os
-import sys
-
+paths (unfused singletons, empty enumeration, unknown kwargs)."""
 import numpy as np
 import pytest
 
-from repro.blas import REGISTRY, Sequence, make_inputs
 from repro.blas import elementary_lib as lib
 from repro.core import (FusionCompiler, Monoid, OptimizationSpace, PlanCache,
-                        codegen, scheduler)
+                        scheduler)
+from repro.programs import BLAS, Program, make_inputs
 from repro.serving import (ServingEngine, bucket_of, input_pad_values,
                            pad_to_shape)
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-# sizes kept small so the full REGISTRY sweep (matrices included) is fast
+# sizes kept small so the full BLAS sweep (matrices included) is fast
 SIZES = (96, 100, 128)
 BUCKET = 128
 
@@ -60,13 +54,13 @@ def test_pad_to_shape():
 
 
 # ---------------------------------------------------------------------------
-# padding safety: every REGISTRY sequence, padded to a larger bucket,
+# padding safety: every BLAS sequence, padded to a larger bucket,
 # matches its numpy reference on the unpadded slice
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("name", list(REGISTRY))
+@pytest.mark.parametrize("name", list(BLAS))
 def test_padding_safety_registry(name):
-    seq = REGISTRY[name]
+    seq = BLAS[name]
     engine = _engine()
     n = 100                                   # pads 100 -> bucket 128
     results = engine.serve([(name, n, make_inputs(seq, n, seed=7))])
@@ -84,7 +78,7 @@ def test_padding_safety_registry(name):
 def test_padding_safety_sum_reductions_batched(name):
     """The SUM-reduction sequences, mixed sizes in one engine run: the
     zero-padded lanes must be invisible to the dot products."""
-    seq = REGISTRY[name]
+    seq = BLAS[name]
     engine = _engine()
     workload = [(name, n, make_inputs(seq, n, seed=i))
                 for i, n in enumerate(SIZES * 2)]
@@ -104,7 +98,7 @@ def test_batched_bitwise_equals_single_padded_dispatch(name):
     """Horizontal fusion adds zero numerical difference: every row of
     the engine's batched result is bit-for-bit the one-request-per-
     dispatch result on the same padded inputs."""
-    seq = REGISTRY[name]
+    seq = BLAS[name]
     cc = FusionCompiler(cache=PlanCache())
     prog_b = cc.compile_batched(seq.script, seq.shapes(BUCKET), max_batch=4)
     prog_s = cc.compile(seq.script, seq.shapes(BUCKET))
@@ -143,7 +137,7 @@ def test_max_reduce_padded_with_identity():
     def script(g, x):
         return (g.apply(lib.max_reduce, x, name="m"),)
 
-    maxseq = Sequence("MAXR", "", script, lambda n: {"x": (n,)},
+    maxseq = Program("MAXR", "", script, lambda n: {"x": (n,)},
                       lambda x: (np.max(x),), lambda n: float(n))
     engine = _engine(registry={"MAXR": maxseq})
     g = engine.compiler.trace(script, {"x": (BUCKET,)})
@@ -182,14 +176,14 @@ def test_drain_preserves_queue_on_compile_failure():
         t = g.apply(lib.max_reduce, s)
         return (g.apply(lib.axpy, t, y, y),)
 
-    bad = Sequence("BAD", "", bad_script,
+    bad = Program("BAD", "", bad_script,
                    lambda n: {"x": (n,), "y": (n // 2,), "alpha": ()},
                    lambda x, y, alpha: (np.max(alpha * x) * y + y,),
                    lambda n: float(n))
-    registry = dict(REGISTRY)
+    registry = dict(BLAS)
     registry["BAD"] = bad
     engine = _engine(registry=registry)
-    engine.submit("VADD", 100, make_inputs(REGISTRY["VADD"], 100, seed=0))
+    engine.submit("VADD", 100, make_inputs(BLAS["VADD"], 100, seed=0))
     engine.submit("BAD", 100, {"x": np.ones(100, np.float32),
                                "y": np.ones(50, np.float32),
                                "alpha": np.float32(2.0)})
@@ -223,7 +217,7 @@ def test_one_plan_per_sequence_bucket():
     bucket) and serves every later request from cache."""
     engine = _engine()
     names = ["AXPYDOT", "VADD"]
-    workload = [(nm, n, make_inputs(REGISTRY[nm], n, seed=n))
+    workload = [(nm, n, make_inputs(BLAS[nm], n, seed=n))
                 for nm in names for n in [96, 100, 128, 200]] * 2
     results = engine.serve(workload)
     assert len(results) == 16
@@ -245,7 +239,7 @@ def test_one_plan_per_sequence_bucket():
 
 def test_fewer_dispatches_than_requests():
     engine = _engine(max_batch=8)
-    seq = REGISTRY["WAXPBY"]
+    seq = BLAS["WAXPBY"]
     workload = [("WAXPBY", 100, make_inputs(seq, 100, seed=i))
                 for i in range(16)]
     engine.serve(workload)
@@ -262,7 +256,7 @@ def test_warm_then_serve_never_compiles():
     # warm also pre-builds the pack composition over the warmed keys (§9)
     assert sorted(st0) == ["SSCAL/128", "SSCAL/256",
                            "pack/SSCAL/128+SSCAL/256"]
-    workload = [("SSCAL", n, make_inputs(REGISTRY["SSCAL"], n, seed=n))
+    workload = [("SSCAL", n, make_inputs(BLAS["SSCAL"], n, seed=n))
                 for n in (96, 100, 128, 200)]
     results = engine.serve(workload)
     assert len(results) == 4
@@ -274,7 +268,7 @@ def test_warm_then_serve_never_compiles():
 def test_open_loop_serve_reports_latency():
     engine = _engine()
     engine.warm("VADD", [100])
-    seq = REGISTRY["VADD"]
+    seq = BLAS["VADD"]
     workload = [("VADD", 100, make_inputs(seq, 100, seed=i))
                 for i in range(8)]
     results = engine.serve(workload, rate_hz=2000.0)
@@ -297,7 +291,7 @@ def test_unknown_sequence_rejected():
 # ---------------------------------------------------------------------------
 
 def test_unknown_kwargs_raise_typeerror():
-    seq = REGISTRY["AXPYDOT"]
+    seq = BLAS["AXPYDOT"]
     cc = FusionCompiler(cache=None)
     prog = cc.compile(seq.script, seq.shapes(128))
     inputs = make_inputs(seq, 128)
@@ -311,7 +305,7 @@ def test_unknown_kwargs_raise_typeerror():
 
 
 def test_unfused_combination_names_dropped_call():
-    seq = REGISTRY["VADD"]
+    seq = BLAS["VADD"]
     cc = FusionCompiler(cache=None)
     g = cc.trace(seq.script, seq.shapes(128))
     space = cc.space(g)
@@ -324,33 +318,10 @@ def test_unfused_combination_names_dropped_call():
 
 
 def test_integer_mode_empty_enumeration_is_clear_error():
-    seq = REGISTRY["SSCAL"]
+    seq = BLAS["SSCAL"]
     cc = FusionCompiler(cache=None)
     g = cc.trace(seq.script, seq.shapes(128))
     empty = OptimizationSpace(graph=g, fusions=[], impls_by_fusion={})
     with pytest.raises(ValueError, match="no legal combination"):
         cc.search(empty, 2)
 
-
-# ---------------------------------------------------------------------------
-# benchmark-harness parity: identical plans must measure ~1.0x
-# ---------------------------------------------------------------------------
-
-def test_identical_plans_measure_parity():
-    """The BENCH_fusion ATAX anomaly: two programs compiled from the
-    SAME combination must time within noise of each other with the
-    hardened harness (interleaved batches + min-of-batches, so machine-
-    speed drift hits both programs equally)."""
-    sys.path.insert(0, REPO)
-    from benchmarks.blas_sequences import _time_pair
-
-    seq = REGISTRY["BiCGK"]
-    cc = FusionCompiler(cache=None)
-    g = cc.trace(seq.script, seq.shapes(512))
-    best = scheduler.best_combination(cc.space(g))
-    prog_a = codegen.compile_combination(g, best, backend="jnp")
-    prog_b = codegen.compile_combination(g, best, backend="jnp")
-    inputs = make_inputs(seq, 512)
-    t_a, t_b = _time_pair(prog_a, prog_b, inputs, iters=7)
-    ratio = t_a / t_b
-    assert 0.5 < ratio < 2.0, f"identical plans measured {ratio:.2f}x"

@@ -11,8 +11,8 @@ import jax
 import numpy as np
 import pytest
 
-from repro.blas import REGISTRY
 from repro.core import FusionCompiler
+from repro.programs import BLAS
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -59,13 +59,13 @@ def test_end_to_end_bicg_solver_iteration():
     r = rng.standard_normal(n).astype(np.float32)
 
     cc = FusionCompiler()
-    bicgk = cc.compile(REGISTRY["BiCGK"].script, REGISTRY["BiCGK"].shapes(n))
+    bicgk = cc.compile(BLAS["BiCGK"].script, BLAS["BiCGK"].shapes(n))
     q, s = bicgk(A=A, p=p, r=r)
     np.testing.assert_allclose(np.asarray(q), A @ p, rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(s), A.T @ r, rtol=1e-4, atol=1e-4)
 
-    axpydot = cc.compile(REGISTRY["AXPYDOT"].script,
-                         REGISTRY["AXPYDOT"].shapes(n))
+    axpydot = cc.compile(BLAS["AXPYDOT"].script,
+                         BLAS["AXPYDOT"].shapes(n))
     alpha = np.float32(0.3)
     z, rr = axpydot(w=r, v=np.asarray(q), u=p, alpha=alpha)
     np.testing.assert_allclose(np.asarray(z), r - alpha * np.asarray(q),
@@ -76,7 +76,7 @@ def test_end_to_end_bicg_solver_iteration():
 
 
 def test_compile_report_stages():
-    seq = REGISTRY["GEMVER"]
+    seq = BLAS["GEMVER"]
     cc = FusionCompiler()
     prog, rep = cc.compile(seq.script, seq.shapes(512), report=True)
     assert rep.n_fusions >= 5

@@ -38,7 +38,6 @@ def main() -> int:
         text = f.read()
 
     sys.path.insert(0, os.path.join(REPO, "src"))
-    sys.path.insert(0, REPO)                  # benchmarks.* namespace pkg
 
     ns: dict = {}
     py = blocks(text, "python")
