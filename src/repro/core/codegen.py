@@ -55,9 +55,9 @@ from .fusion import (ACC, DIV, MAX, Fusion, call_phases,
                      consumed_reductions)
 from .graph import Graph, Var
 from .plan import ExecutionPlan, PackedPlan, build_plan
-from .predictor import (V5E, HardwareModel, Impl, accumulable,
-                        online_accumulators, operand_carrier,
-                        reduce_roots_of)
+from .predictor import (V5E, Carrier, HardwareModel, Impl, accumulable,
+                        carrier_swapped, online_accumulators,
+                        operand_carrier, reduce_roots_of)
 from .scheduler import Combination
 
 #: scoped VMEM Mosaic may use per kernel beyond the predictor's budget
@@ -158,11 +158,13 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
     ``NotImplementedError`` — the group-split contract (DESIGN.md §2).
 
     Every value crosses the kernel boundary — BlockSpec, partials
-    array, scratch — in its ``predictor.operand_carrier`` form (rank
-    >= 2, lane-dense vectors, ``(1, 1)`` scalars), the layout the
-    predictor's block legality and VMEM count assume; the body reshapes
-    blocks back to the elementaries' natural ranks, except in a depth-1
-    group, whose elementwise body runs on the carrier blocks.
+    array — in its ``predictor.operand_carrier`` form (rank >= 2,
+    lane-dense vectors, ``(1, 1)`` scalars, a narrow matrix with its
+    last two dims swapped as XLA stores it), the layout the predictor's
+    block legality and VMEM count assume; the body takes blocks back to
+    the elementaries' natural order and ranks, except in a depth-1
+    group, whose elementwise body runs on the carrier blocks.  Scratch
+    stays in VMEM and holds its carrier in the value's natural order.
     An online-softmax group (``fusion.online_roles``) compiles to one
     sweep over its streamed axis, every other axis one whole block: VMEM
     scratch carries a running max (started at the dtype's lowest finite
@@ -233,23 +235,30 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
     def natural_block(v: Var) -> tuple[int, ...]:
         return tuple(blk[r] for r in roots_of(v))
 
-    def carrier(v: Var) -> tuple[tuple[int, ...], tuple[int, ...]]:
+    def carrier(v: Var) -> Carrier:
         return operand_carrier(v.shape, natural_block(v), v.dtype, hw)
+
+    def to_carrier(v: Var, x, shape: tuple[int, ...]):
+        """``x``, in ``v``'s natural order, laid out as ``shape``, which
+        holds ``v``'s carrier (behind leading unit dims, for partials)."""
+        if carrier(v).swapped:
+            x = jnp.swapaxes(x, -1, -2)
+        return jnp.reshape(x, shape)
 
     # a depth-1 group holds only vectors over its one axis and scalars,
     # and its calls are elementwise maps and whole-block reductions, so
     # where every vector has the same carrier block the body computes on
     # that 2-D block as it is: Mosaic lays a rank-1 block out a sublane
     # per vreg, which at a 2**20-element block takes it 10 s to compile
-    vec_blocks = {carrier(v)[1] for v in (*f.external_inputs,
-                                          *(c.out for c in f.calls))
+    vec_blocks = {carrier(v).block for v in (*f.external_inputs,
+                                             *(c.out for c in f.calls))
                   if len(v.shape) == 1}
     flat = f.depth == 1 and len(vec_blocks) == 1
 
     def is_row(v: Var) -> bool:
         """A vector carried as one ``(1, n)`` row (blocks move along its
         lanes) rather than a lane-dense view (blocks of whole rows)."""
-        return len(v.shape) == 1 and carrier(v)[1][0] == 1
+        return len(v.shape) == 1 and carrier(v).block[0] == 1
 
     def make_index_map(v: Var, lead_roots: tuple[int, ...] = ()):
         vroots = roots_of(v)
@@ -265,27 +274,32 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
                 body = (0, gid) if row else (gid, 0)
             else:
                 body = tuple(gids[pos[r]] for r in vroots)
+                if carrier(v).swapped:
+                    body = body[:-2] + (body[-1], body[-2])
             return lead + body
         return index_map
 
-    def load(v: Var, ref, idx=Ellipsis):
-        """A carrier block read back at the elementaries' natural rank
-        (as it is, in a ``flat`` body)."""
+    def load(v: Var, ref, idx=Ellipsis, scratch: bool = False):
+        """A carrier block (a ``scratch`` buffer's, in natural order)
+        read back in the elementaries' natural order and rank (as it
+        is, in a ``flat`` body)."""
         if v.shape == ():
             return ref[0, 0]
-        return jnp.reshape(ref[idx],
-                           carrier(v)[1] if flat else natural_block(v))
+        x = ref[idx]
+        if carrier(v).swapped and not scratch:
+            x = jnp.swapaxes(x, -1, -2)
+        return jnp.reshape(x, carrier(v).block if flat else natural_block(v))
 
     # ---- input specs ------------------------------------------------------
     in_specs = []
     for v in f.external_inputs:
-        in_specs.append(pl.BlockSpec(carrier(v)[1], make_index_map(v)))
+        in_specs.append(pl.BlockSpec(carrier(v).block, make_index_map(v)))
 
     # ---- output specs -----------------------------------------------------
     out_specs, out_shapes, out_mode = [], [], []
     # out_mode: ('map',), ('acc', reduce_pos), ('partial', lead_axes)
     for v in f.outputs:
-        shape, block = carrier(v)
+        shape, block, _ = carrier(v)
         rr = reduce_roots_of(v, f, g)
         if not rr or accumulable(v, f, g, order) or online:
             out_specs.append(pl.BlockSpec(block, make_index_map(v)))
@@ -307,7 +321,8 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
     scratch_shapes, scratch_at = [], {}
     for c in consumed + online_accumulators(f):
         scratch_at[c.idx] = len(scratch_shapes)
-        scratch_shapes.append(pltpu.VMEM(carrier(c.out)[0], c.out.dtype))
+        scratch_shapes.append(pltpu.VMEM(carrier(c.out).natural.shape,
+                                         c.out.dtype))
 
     def scratch_index(v: Var):
         """The current grid cell's block of a scratch carrier."""
@@ -324,7 +339,7 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
             return pl.ds(pl.program_id(gofs + pos[r]) * width, width)
 
         if len(vroots) == 1:
-            rows, lanes = carrier(v)[1]
+            rows, lanes = carrier(v).block
             if is_row(v):
                 return (slice(None), along(vroots[0], lanes))
             return (along(vroots[0], rows), slice(None))
@@ -351,7 +366,8 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
                 # consumers at later phases see the finished reduction
                 sref = scratch_refs[scratch_at[c.idx]]
                 idx = scratch_index(c.out)
-                cval = jnp.reshape(val, carrier(c.out)[1]).astype(sref.dtype)
+                cval = jnp.reshape(val, carrier(c.out).natural.block
+                                   ).astype(sref.dtype)
                 rr = reduce_roots_of(c.out, f, g)
                 is_first = functools.reduce(
                     jnp.logical_and,
@@ -366,14 +382,14 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
                                  m=c.elem.monoid):
                     sref[idx] = m.combine(sref[idx], cval)
 
-                env[c.out] = load(c.out, sref, idx)
+                env[c.out] = load(c.out, sref, idx, scratch=True)
             elif not c.elem.is_reduction:
                 env[c.out] = val
             if c.out in out_index:
                 i = out_index[c.out]
                 mode, aux = out_mode[i]
                 ref = out_refs[i]
-                cval = jnp.reshape(val, ref.shape).astype(ref.dtype)
+                cval = to_carrier(c.out, val, ref.shape).astype(ref.dtype)
                 if mode == "map" or mode == "partial":
                     if multi:
                         @pl.when(gate)
@@ -424,7 +440,7 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
         for c, role in zip(f.calls, f.roles):
             args = [env[a] for a in c.args]
             if role == MAX:
-                m_old = load(c.out, run[c.out])
+                m_old = load(c.out, run[c.out], scratch=True)
                 val = jnp.maximum(m_old, c.elem.fn(*args))
                 alpha, m_roots = jnp.exp(m_old - val), roots_of(c.out)
             elif role == DIV:
@@ -432,7 +448,8 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
                 val = args[num]
                 divisor[c.out] = c.args[den]
             elif role == ACC:
-                val = _rescaled(load(c.out, run[c.out]), _broadcast(
+                acc = load(c.out, run[c.out], scratch=True)
+                val = _rescaled(acc, _broadcast(
                     alpha, m_roots, roots_of(c.out))) + c.elem.fn(*args)
             else:
                 val = c.elem.fn(*args)
@@ -450,7 +467,7 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
                         den = divisor[a]
                         val = val / _broadcast(env[den], roots_of(den),
                                                roots_of(v))
-                ref[...] = jnp.reshape(val, ref.shape).astype(ref.dtype)
+                ref[...] = to_carrier(v, val, ref.shape).astype(ref.dtype)
 
     call = pl.pallas_call(
         online_kernel if online else kernel, grid=grid, in_specs=in_specs,
@@ -462,13 +479,17 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
     )
 
     def run(*ext_vals):
-        vals = [jnp.reshape(jnp.asarray(x, v.dtype), carrier(v)[0])
+        # a swapped carrier is a swapaxes of the input, which XLA lowers
+        # to a bitcast of the array as it stores it
+        vals = [to_carrier(v, jnp.asarray(x, v.dtype), carrier(v).shape)
                 for v, x in zip(f.external_inputs, ext_vals)]
         raw = call(*vals)
         outs = []
         for v, r, (mode, aux) in zip(f.outputs, raw, out_mode):
             if mode == "partial":
                 r = _monoid_sum(v.producer.elem.monoid, r, aux)
+            if carrier(v).swapped:
+                r = jnp.swapaxes(r, -1, -2)
             outs.append(jnp.reshape(r, v.shape))
         return tuple(outs)
 
@@ -492,6 +513,9 @@ class CompiledProgram:
     plan: ExecutionPlan
     group_impls: list[Impl]        # topological order, bound to `graph`
     fn: Callable                   # jitted (*input_vals) -> tuple(outputs)
+    #: the values a Pallas kernel takes or gives with their last two dims
+    #: swapped (``predictor.carrier_swapped``), in plan order
+    transposed_operands: tuple[str, ...] = ()
 
     @property
     def n_groups(self) -> int:
@@ -627,14 +651,30 @@ def _group_fns(g: Graph, plan: ExecutionPlan, impls: list[Impl],
     return fns
 
 
+def _transposed_operands(plan: ExecutionPlan, impls: list[Impl],
+                         hw: HardwareModel) -> tuple[str, ...]:
+    """The names of the values some Pallas kernel of the plan carries
+    swapped, each once, in plan order (none on the jnp backend)."""
+    if plan.backend != "pallas":
+        return ()
+    names: dict[str, None] = {}
+    for im in impls:
+        for v in im.fusion.external_inputs + im.fusion.outputs:
+            if carrier_swapped(v.shape, v.dtype, hw):
+                names[v.name] = None
+    return tuple(names)
+
+
 def compile_plan(g: Graph, plan: ExecutionPlan, hw: HardwareModel = V5E,
                  interpret: bool = False, jit: bool = True) -> CompiledProgram:
     """ExecutionPlan -> executable (one jitted whole-program function)."""
     impls = plan.bind(g, hw)
     fns = _group_fns(g, plan, impls, hw, interpret)
     program = _program_fn(plan, impls, fns, plan.backend)
-    return CompiledProgram(graph=g, plan=plan, group_impls=impls,
-                           fn=jax.jit(program) if jit else program)
+    return CompiledProgram(
+        graph=g, plan=plan, group_impls=impls,
+        fn=jax.jit(program) if jit else program,
+        transposed_operands=_transposed_operands(plan, impls, hw))
 
 
 def compile_plan_batched(g: Graph, plan: ExecutionPlan, max_batch: int = 8,

@@ -169,6 +169,18 @@ class Graph:
     def mark_outputs(self, *vs: Var):
         self.outputs = list(vs)
 
+    def drop_dead_calls(self):
+        """Drop the calls whose values reach no output (renumbering the
+        rest): planned, a dead call is a group with nothing to write."""
+        live, kept = set(self.outputs), []
+        for c in reversed(self.calls):
+            if c.out in live:
+                kept.append(c)
+                live.update(c.args)
+        self.calls = kept[::-1]
+        for i, c in enumerate(self.calls):
+            c.idx = i
+
     # -- queries ----------------------------------------------------------
     def axis_root(self, aid: int) -> int:
         return self.uf.find(aid)
@@ -206,7 +218,7 @@ def trace(script: Callable, input_shapes: dict[str, Sequence[int]],
 
     The script receives the graph (to call ``g.apply``) via a thin API
     object and the input Vars as keyword arguments; whatever it returns is
-    marked as graph outputs.
+    marked as graph outputs, and calls that reach none are dropped.
     """
     g = Graph()
     kwargs = {k: g.add_input(k, shp, dtype) for k, shp in input_shapes.items()}
@@ -214,5 +226,6 @@ def trace(script: Callable, input_shapes: dict[str, Sequence[int]],
     if isinstance(result, Var):
         result = (result,)
     g.mark_outputs(*result)
+    g.drop_dead_calls()
     g.validate()
     return g

@@ -29,6 +29,7 @@ from __future__ import annotations
 import dataclasses
 import itertools
 import math
+from typing import NamedTuple
 
 import numpy as np
 
@@ -276,9 +277,44 @@ def _round_up(x: int, m: int) -> int:
     return -(-x // m) * m
 
 
+class Carrier(NamedTuple):
+    """A value's array and block shapes at the kernel boundary, and
+    whether its last two dims are stored swapped (``swapped``)."""
+
+    shape: tuple[int, ...]
+    block: tuple[int, ...]
+    swapped: bool = False
+
+    @property
+    def natural(self) -> "Carrier":
+        """The same carrier in the value's own dim order: what a VMEM
+        scratch buffer, which never crosses to HBM, holds."""
+        if not self.swapped:
+            return self
+        return Carrier(_swap_last(self.shape), _swap_last(self.block))
+
+
+def _swap_last(dims: tuple[int, ...]) -> tuple[int, ...]:
+    return (*dims[:-2], dims[-1], dims[-2])
+
+
+def carrier_swapped(shape: tuple[int, ...], dtype, hw: HardwareModel) -> bool:
+    """Whether a value of rank >= 2 is carried with its last two dims
+    swapped: exactly when the swapped shape pads to fewer (sublane,
+    lane) tiles than the natural one, a tie keeping the natural order.
+    XLA's TPU layout makes the same choice for an array it stores (a
+    float32 (131072, 64) is laid out ``{0,1}``), so a carrier in that
+    order reaches the kernel as a bitcast, where the natural order
+    costs a padded relayout copy before every call."""
+    if len(shape) < 2:
+        return False
+    return padded_bytes(_swap_last(shape), dtype, hw) < padded_bytes(
+        shape, dtype, hw)
+
+
 def operand_carrier(shape: tuple[int, ...], block: tuple[int, ...], dtype,
-                    hw: HardwareModel) -> tuple[tuple[int, ...], tuple[int, ...]]:
-    """``(array shape, block shape)`` a value takes at the kernel boundary.
+                    hw: HardwareModel) -> Carrier:
+    """The ``Carrier`` a value takes at the kernel boundary.
 
     Mosaic tiles the last two dims of every operand (sublane, lane) and
     refuses rank-1 blocks under a leading batch axis, so values are
@@ -290,19 +326,24 @@ def operand_carrier(shape: tuple[int, ...], block: tuple[int, ...], dtype,
       ``(b / lane, lane)``;
     * any other vector is one ``(1, n)`` row, blocked ``(1, b)`` (``b``
       a multiple of the lane count, or the whole row);
-    * rank >= 2 values keep their shape and block.
+    * rank >= 2 values keep their shape and block, the last two dims
+      swapped in both where ``carrier_swapped`` says so (a (n, 64)
+      cache is carried (64, n), its long axis on the lanes).
 
-    The kernel reshapes rank-1 blocks back to ``(b,)`` for the
-    elementary bodies, so they stay block-polymorphic."""
+    The kernel takes blocks back to the elementaries' natural order and
+    rank, so they stay block-polymorphic."""
     if not shape:
-        return (1, 1), (1, 1)
+        return Carrier((1, 1), (1, 1))
     if len(shape) == 1:
         sub, lane = hw.min_tile_for(dtype)
         (n,), (b,) = shape, block
         if b % (sub * lane) == 0:
-            return (n // lane, lane), (b // lane, lane)
-        return (1, n), (1, b)
-    return tuple(shape), tuple(block)
+            return Carrier((n // lane, lane), (b // lane, lane))
+        return Carrier((1, n), (1, b))
+    if carrier_swapped(shape, dtype, hw):
+        return Carrier(_swap_last(tuple(shape)), _swap_last(tuple(block)),
+                       True)
+    return Carrier(tuple(shape), tuple(block))
 
 
 def padded_bytes(shape: tuple[int, ...], dtype, hw: HardwareModel) -> int:
@@ -317,20 +358,23 @@ def padded_bytes(shape: tuple[int, ...], dtype, hw: HardwareModel) -> int:
 def block_granules(f: Fusion, g: Graph, hw: HardwareModel) -> dict[int, int]:
     """Per axis root, the multiple every block of that axis must be
     (the full axis is always legal): the strictest tiling rule of any
-    value the fusion touches, in each value's own memory order.
+    value the fusion touches, in each value's carrier order.
 
     The last dim of a value takes the lane count (a vector's only dim is
     the lane dim of its carrier), the second-to-last dim of a rank >= 2
-    value the sublane count.  All are powers of two, so the max is the
-    lcm."""
+    value the sublane count; a value carried swapped across the kernel
+    boundary (``carrier_swapped``) takes them the other way round.  All
+    are powers of two, so the max is the lcm."""
     gran = {r: 1 for r in f.axis_roots}
-    vs = f.external_inputs + f.outputs + f.internal_vars
-    for v in vs:
+    carried = set(f.external_inputs + f.outputs)
+    for v in f.external_inputs + f.outputs + f.internal_vars:
         roots = [g.axis_root(a) for a in v.axis_ids]
         if not roots:
             continue
         sub, lane = hw.min_tile_for(v.dtype)
-        rules = [1] * (len(roots) - 2) + [sub, lane][-len(roots):]
+        swapped = v in carried and carrier_swapped(v.shape, v.dtype, hw)
+        last_two = [lane, sub] if swapped else [sub, lane]
+        rules = [1] * (len(roots) - 2) + last_two[-len(roots):]
         for r, m in zip(roots, rules):
             gran[r] = max(gran[r], m)
     return gran
@@ -418,12 +462,12 @@ def cost_impl(f: Fusion, g: Graph, order: tuple[int, ...],
     # every buffer counts at its padded carrier size — what codegen's
     # BlockSpecs and scratch shapes actually occupy (a partials block
     # only adds leading unit dims, which pad nothing)
-    def carrier(v: Var):
+    def carrier(v: Var) -> Carrier:
         block = tuple(blk[g.axis_root(a)] for a in v.axis_ids)
         return operand_carrier(v.shape, block, v.dtype, hw)
 
     def block_bytes(v: Var) -> float:
-        return padded_bytes(carrier(v)[1], v.dtype, hw)
+        return padded_bytes(carrier(v).block, v.dtype, hw)
 
     # one block of every input and output: what a step moves, and what
     # the pipeline fetches before its first step and writes back after
@@ -436,11 +480,12 @@ def cost_impl(f: Fusion, g: Graph, order: tuple[int, ...],
     for v in f.outputs:
         vmem += 2 * block_bytes(v)
     for v in f.internal_vars:
-        vmem += block_bytes(v)
+        # computed inside the kernel, so held in the value's own order
+        vmem += padded_bytes(carrier(v).natural.block, v.dtype, hw)
     for c in consumed + online_accumulators(f):
         # full-size scratch accumulator carrying the finished reduction,
         # or an online group's running max or sum
-        vmem += padded_bytes(carrier(c.out)[0], c.out.dtype, hw)
+        vmem += padded_bytes(carrier(c.out).natural.shape, c.out.dtype, hw)
 
     dt = fusion_dtype(f)
     t_t = traffic / hw.hbm_bw

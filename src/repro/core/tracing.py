@@ -12,8 +12,12 @@ Counters of one call, which a plan fixes (no trace needed):
 * ``CompiledProgram.grid_steps``: grid steps, summed over the groups.
 * ``CompiledProgram.input_passes``: ``{input name: passes}``, the times
   the call streams each input from HBM, the sum of ``n_phases`` over the
-  groups that read it (MLA's latent cache ``ckv``: 2, one pass to score,
-  one to weight).
+  groups that read it (MLA's latent cache ``ckv``: 1 in the online-softmax
+  plan, 2 in the split plan, one pass to score and one to weight).
+* ``CompiledProgram.transposed_operands``: the values a Pallas kernel
+  carries with their last two dims swapped, as XLA stores them
+  (``predictor.carrier_swapped``): MLA's ``kr``, decode attention's
+  ``K`` and ``V``; none on the BLAS programs.
 
 A span is a ``jax.profiler.TraceAnnotation``: with no profiler running it
 costs well under a microsecond, so it is always on.

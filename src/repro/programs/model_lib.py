@@ -65,12 +65,15 @@ attn_score = make_nested_map_reduce(
     in_axes=[(0, 1), (1,)], out_axis=0, flops_per_point=2)
 
 # o_d = sum_s w_s V_sd — the weighted value sum, phrased as the
-# reference's GQA einsum with unit h/g dims (see module docstring).
+# reference's GQA einsum with unit h/g dims (see module docstring).  V's
+# unit head dim leads: behind s it pads every V row of a Pallas block to
+# its own (8, 128) tile, and a 4096-row block then asks Mosaic for 147 MB
+# of VMEM on a v5e, which has 128.
 attn_out = make_nested_map_reduce(
     "attn_out",
     lambda V, w: jnp.einsum(
-        "...hgs,...shd->...hgd",
-        w[..., None, None, :], V[..., :, None, :],
+        "...hgs,...hsd->...hgd",
+        w[..., None, None, :], V[..., None, :, :],
         precision="highest")[..., 0, 0, :],
     in_axes=[(0, 1), (0,)], out_axis=1, flops_per_point=2,
     linear_args=(0, 1))

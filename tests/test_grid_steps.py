@@ -37,7 +37,7 @@ def _check_axpydot(cp, hw):
     assert im.vmem_bytes <= hw.vmem_bytes
     (n,), (b,) = im.fusion.axis_sizes, im.blocks
     assert operand_carrier((n,), (b,), np.float32, hw) == (
-        (n // 128, 128), (b // 128, 128))
+        (n // 128, 128), (b // 128, 128), False)
 
 
 def _check_gemver(cp, hw):

@@ -167,6 +167,35 @@ def test_multi_step_grids_on_the_interpreter():
     assert _err(cp(**inp), _reference(PUBLISHED, inp)) < TOL
 
 
+@pytest.mark.parametrize("prog,block,swapped", [
+    (SMALL, 128, ("ckv", "kr")), (PUBLISHED, 256, ("kr",))],
+    ids=["small", "published"])
+def test_swapped_kr_matches_both_oracles_over_several_steps(prog, block,
+                                                           swapped):
+    """The rotary key cache kr (n, rope) is narrow (so is the small
+    instance's latent cache, rank 32), so the kernel takes it swapped,
+    (rope, n), in blocks along its lanes, and turns each block back:
+    over several steps of t the program still meets the float64
+    reference and the unabsorbed architecture."""
+    n = 1024
+    shapes = prog.shapes(n)
+    g = trace(prog.script, shapes)
+    (im,) = best_combination(build_space(g)).impls
+    t = im.fusion.stream_root
+    blocks = tuple(block if r == t else b for r, b in zip(im.order, im.blocks))
+    im = cost_impl(im.fusion, g, im.order, blocks, V5E)
+    cp = codegen.compile_combination(g, Combination((im,), im.t_pred),
+                                     backend="pallas", interpret=True)
+    assert cp.transposed_operands == swapped
+    assert im.grid_steps == n // block
+    inp = make_inputs(prog, n, seed=8)
+    assert _err(cp(**inp), _reference(prog, inp)) < TOL
+    heads, rank = shapes["q_lat"]
+    absorbed, w_uv, want = _unabsorbed_case(heads, rank, shapes["kr"][1],
+                                            n, seed=9)
+    assert _err(mla_head_outputs(cp(**absorbed), w_uv), want) < TOL
+
+
 # ---------------------------------------------------------------------------
 # the plan at the benchmark's size, and the counters
 # ---------------------------------------------------------------------------
@@ -239,6 +268,7 @@ PLANS = {
                         ((3,), (0, 1), (16384, 256)),
                         ((4,), (0,), (16384,))],
     ("AXPYDOT", 1 << 26): [((0, 1, 2), (0,), (1048576,))],
+    ("ATAX", 16384): [((0, 1), (0, 1), (16384, 256))],
 }
 
 

@@ -156,6 +156,18 @@ def test_lm_decode_attn_online_kernel_matches_its_reference(seed):
     assert _err(cp(**inp), want) < TOL
 
 
+@pytest.mark.parametrize("block", [128, 512])
+def test_lm_decode_attn_takes_k_and_v_swapped(block):
+    """K and V (n, 48) are narrow: the kernel takes both swapped, (48,
+    n), blocked along their lanes, and still meets the reference over
+    several steps of t."""
+    cp = _reblocked(ATTN, 1024, block)
+    assert cp.transposed_operands == ("K", "V")
+    assert cp.group_impls[0].grid_steps == 1024 // block
+    inp, want = _rising(ATTN, 1024, seed=4)
+    assert _err(cp(**inp), want) < TOL
+
+
 def test_a_kernel_that_skips_the_rescale_fails_the_limit(monkeypatch):
     """Planted fault: the running output ``acc`` (h, c) is not carried to
     the new running max (the running sum still is).  The same inputs

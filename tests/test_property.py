@@ -69,6 +69,9 @@ def build(spec):
 
 @settings(max_examples=30, deadline=None)
 @given(random_script())
+# dead calls: op 0's value reaches no output
+@example(spec=(2, [("u", 0, 0), ("u", 0, 1)], False, 1))
+@example(spec=(2, [("b", 0, 0, 0), ("u", 0, 1)], False, 1))
 def test_random_scripts_best_matches_oracle(spec):
     script, shapes = build(spec)
     cc = FusionCompiler()

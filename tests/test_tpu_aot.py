@@ -16,11 +16,15 @@ refuse, at the sizes the chip smoke run serves:
   one online-softmax kernel (also cut to 128 steps over the cache).
 
 Each Pallas compile must contain a Mosaic kernel (``tpu_custom_call``).
+A narrow matrix reaches its kernel in XLA's own layout, as a bitcast
+with no relayout copy before the kernel (``predictor.carrier_swapped``,
+checked against XLA's entry layouts here).
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every test worker imports this
 file.
 """
 import dataclasses
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +33,8 @@ import pytest
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import FusionCompiler, codegen
+from repro.core import V5E, FusionCompiler, codegen
+from repro.core.predictor import carrier_swapped
 from repro.programs import REGISTRY
 from repro.serving import ServingEngine
 
@@ -85,6 +90,81 @@ def test_pallas_program_compiles_for_v5e(name, n, one_chip, tpu_codegen):
     assert hlo.count("tpu_custom_call") >= compiled.n_groups
 
 
+def _copies(hlo: str) -> list[tuple[str, str]]:
+    """``(result type, opcode)`` of every copy and transpose in ``hlo``."""
+    return re.findall(r"= (.+?) (copy|copy-start|transpose)\(", hlo)
+
+
+def _relayouts(hlo: str, rows: int, cols: int) -> list[tuple[str, str]]:
+    """The copies and transposes of a ``(rows, cols)`` matrix (batched
+    or not), in either order."""
+    return [c for c in _copies(hlo)
+            if f"{rows},{cols}]" in c[0] or f"{cols},{rows}]" in c[0]]
+
+
+def _kernel_operands(hlo: str) -> str:
+    """The operand layout constraints of every Mosaic kernel."""
+    return " ".join(re.findall(
+        r"tpu_custom_call\", operand_layout_constraints=\{(.*?)\}, \w+=", hlo))
+
+
+@pytest.mark.parametrize("name,n,width", [
+    ("MLA_DECODE_ATTN", 131072, 64),
+    ("LM_DECODE_ATTN", 32768, 48),
+])
+def test_narrow_operands_reach_kernel_without_copy(name, n, width,
+                                                   one_chip, tpu_codegen):
+    """MLA's kr (n, 64) and decode attention's K and V (n, 48) are
+    stored by XLA with their long axis as lanes; carried swapped, each
+    reaches its kernel as a bitcast, with no relayout copy before it,
+    and no entry parameter of rank 2 is copied."""
+    prog = REGISTRY[name]
+    compiled = FusionCompiler(backend="pallas", cache=None).compile(
+        prog.script, prog.shapes(n))
+    hlo = _compile(compiled, prog.shapes(n), one_chip)
+    assert _relayouts(hlo, n, width) == []
+    assert not re.findall(r"= f32\[\d+,\d+\]\S* copy\(%input_vals", hlo)
+    assert f"f32[{width},{n}]{{1,0}}" in _kernel_operands(hlo)
+
+
+@pytest.mark.parametrize("name,n,copies", [
+    ("GEMVER", 8192, {"copy": 2, "copy-start": 1}),
+    ("AXPYDOT", 1 << 24, {"copy": 1}),
+    ("ATAX", 16384, {}),
+])
+def test_wide_programs_keep_their_copies(name, n, copies, one_chip,
+                                         tpu_codegen):
+    """No operand of the BLAS programs is narrow, so their compiled
+    programs keep the copies they had (two scalars and a move into
+    VMEM on GEMVER, a scalar on AXPYDOT) and no transpose."""
+    prog = REGISTRY[name]
+    compiled = FusionCompiler(backend="pallas", cache=None).compile(
+        prog.script, prog.shapes(n))
+    assert compiled.transposed_operands == ()
+    hlo = _compile(compiled, prog.shapes(n), one_chip)
+    ops = [op for _, op in _copies(hlo)]
+    assert {op: ops.count(op) for op in set(ops)} == copies
+
+
+@pytest.mark.parametrize("shape", [
+    (131072, 64), (32768, 48), (1024, 64), (256, 64), (128, 64), (136, 64),
+    (131072, 8), (4096, 100), (8, 32768, 48),
+    (16, 64), (16, 512), (1, 64), (131072, 127), (131072, 128),
+], ids=str)
+def test_carrier_orientation_is_xla_entry_layout(shape, one_chip,
+                                                 tpu_codegen):
+    """XLA stores a float32 array with its last two dims swapped exactly
+    when ``carrier_swapped`` carries it so (a tie keeps row-major).  A
+    JAX whose XLA chooses otherwise fails here, rather than quietly
+    bringing back a relayout copy before every kernel."""
+    x = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+    fmt = jax.jit(lambda a: a * 2).lower(x).compile().input_formats[0][0]
+    lead, r = tuple(range(len(shape) - 2)), len(shape)
+    want = (r - 1, r - 2) if carrier_swapped(shape, jnp.float32, V5E) \
+        else (r - 2, r - 1)
+    assert fmt.layout.major_to_minor == lead + want
+
+
 def test_online_softmax_kernel_compiles_for_v5e(one_chip, tpu_codegen):
     """MLA_DECODE_ATTN's plan at the benchmark's size is one
     online-softmax kernel; it compiles as the predictor blocks it and
@@ -121,7 +201,13 @@ def test_engine_masked_batch_compiles_for_v5e(one_chip, tpu_codegen):
     assert masked
     prog = engine.compiler.compile_batched(script, shapes, max_batch=2,
                                            backend="pallas")
-    assert "tpu_custom_call" in _compile(prog, shapes, one_chip, lead=(2,))
+    hlo = _compile(prog, shapes, one_chip, lead=(2,))
+    assert "tpu_custom_call" in hlo
+    # K and V, (2, 32768, 48) stored {1,2,0}, reach their kernels as
+    # bitcasts to (2, 48, 32768): no copy of them, and no transpose
+    assert "f32[2,48,32768]{2,1,0}" in _kernel_operands(hlo)
+    assert _relayouts(hlo, 32768, 48) == []
+    assert "transpose" not in [op for _, op in _copies(hlo)]
 
 
 def test_sharded_gemver_compiles_for_four_chips(topo, tpu_codegen):
