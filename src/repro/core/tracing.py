@@ -5,7 +5,10 @@
 * Group labels (``codegen.group_label``, e.g. ``g0_rank2_update_gemtv``):
   each fused group's ``jax.named_scope`` and, on the Pallas backend, its
   kernel's name, so the device trace names a group's kernel the same way
-  whatever the plan's signature.
+  whatever the plan's signature.  A model program's elementaries share a
+  prefix its labels carry: ``mla_`` (``MLA_DECODE_ATTN``), ``kda_``
+  (``KDA_DECODE_STEP``: ``g0_kda_sumsq_kda_qnorm`` ... ``g3_kda_u``,
+  ``g4_kda_w``, ``g5_kda_snew_kda_o``).
 
 Counters of one call, which a plan fixes (no trace needed):
 
@@ -13,7 +16,8 @@ Counters of one call, which a plan fixes (no trace needed):
 * ``CompiledProgram.input_passes``: ``{input name: passes}``, the times
   the call streams each input from HBM, the sum of ``n_phases`` over the
   groups that read it (MLA's latent cache ``ckv``: 1 in the online-softmax
-  plan, 2 in the split plan, one pass to score and one to weight).
+  plan, 2 in the split plan, one pass to score and one to weight; KDA's
+  state ``S``: 2, one pass to read it at the key and one to update it).
 * ``CompiledProgram.transposed_operands``: the values a Pallas kernel
   carries with their last two dims swapped, as XLA stores them
   (``predictor.carrier_swapped``): MLA's ``kr``, decode attention's

@@ -28,7 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.elementary import (Monoid, col, make_map, make_nested_map,
-                                   make_nested_map_reduce,
+                                   make_nested_map_reduce, make_tensor_map,
                                    make_tensor_map_reduce)
 
 # --- rmsnorm -----------------------------------------------------------------
@@ -129,6 +129,77 @@ mla_value = make_tensor_map_reduce(
                             precision="highest"),
     in_axes=[(0, 1), (1, 2)], reduce_axis=1, linear_args=(0, 1))
 
+# --- Kimi Delta Attention (KDA), one decode step ----------------------------
+#
+# Kimi Linear (arXiv:2510.26692): every (session, head) row b keeps a
+# (key, value) state S, gated per key channel and corrected by a delta
+# rule each token.  Axes: b (rows), i (key width), j (value width).  The
+# contractions over i are VPU broadcasts and sublane sums: per row they
+# are matrix-vector products, which an MXU pass at M = 1 would waste.
+
+#: Kimi-Linear-48B-A3B's published KDA head width (linear_attn_config)
+KDA_DIM = 128
+#: the eps of the L2 norm of q and k
+KDA_EPS = 1e-6
+
+
+def _kda_contract(x, S):
+    """sum_i x_bi S_bij over an (i, j) block of every row."""
+    return jnp.sum(col(x) * S, axis=-2)
+
+
+# ss_b = sum_i x_bi^2, the squared norm of a row of q or k
+kda_sumsq = make_nested_map_reduce(
+    "kda_sumsq", lambda x: jnp.sum(x * x, axis=-1), in_axes=[(0, 1)],
+    out_axis=0, flops_per_point=2)
+# the L2-normed key, and the query normed and scaled by KDA_DIM**-0.5
+kda_knorm = make_nested_map(
+    "kda_knorm", lambda x, ss: x * jax.lax.rsqrt(col(ss) + KDA_EPS),
+    in_axes=[(0, 1), (0,)], flops_per_point=2)
+kda_qnorm = make_nested_map(
+    "kda_qnorm",
+    lambda x, ss: x * (jax.lax.rsqrt(col(ss) + KDA_EPS) * KDA_DIM ** -0.5),
+    in_axes=[(0, 1), (0,)], flops_per_point=3)
+
+
+def _softplus(f):
+    """log(1 + exp(f)) as max(f, 0) + log1p(t), t = exp(-|f|), which
+    cannot overflow.  One Newton step on exp(y) = 1 + t refines the
+    log1p, which a Mosaic kernel on a v5e computes far less exactly
+    than exp."""
+    t = jnp.exp(-jnp.abs(f))
+    y = jnp.log1p(t)
+    return jnp.maximum(f, 0.0) + y + ((1.0 + t) * jnp.exp(-y) - 1.0)
+
+
+# the decay alpha_bi = exp(-exp(a_log_b) softplus(f_bi)), in (0, 1)
+kda_gate = make_nested_map(
+    "kda_gate",
+    lambda f, a_log: jnp.exp(-jnp.exp(col(a_log)) * _softplus(f)),
+    in_axes=[(0, 1), (0,)], flops_per_point=10, pad_safe=False)
+# the gated key kn_bi alpha_bi, which reads the state
+kda_ka = make_nested_map(
+    "kda_ka", lambda kn, alpha: kn * alpha, in_axes=[(0, 1), (0, 1)],
+    flops_per_point=1)
+# u_bj = sum_i ka_bi S_bij: the gated state read at the key
+kda_u = make_tensor_map_reduce(
+    "kda_u", _kda_contract, in_axes=[(0, 1), (0, 1, 2)], reduce_axis=1,
+    linear_args=(0, 1))
+# w_bj = sigmoid(b_b) (v_bj - u_bj): the write, at strength beta in (0, 1)
+kda_w = make_nested_map(
+    "kda_w", lambda b, v, u: jax.nn.sigmoid(col(b)) * (v - u),
+    in_axes=[(0,), (0, 1), (0, 1)], flops_per_point=2)
+# S'_bij = alpha_bi S_bij + kn_bi w_bj: the decayed state and the
+# rank-1 delta-rule update, the one rank-3 value a kernel writes
+kda_snew = make_tensor_map(
+    "kda_snew", lambda S, alpha, kn, w: col(alpha) * S + col(kn) * (
+        w[..., None, :]),
+    in_axes=[(0, 1, 2), (0, 1), (0, 1), (0, 2)], depth=3, flops_per_point=3)
+# o_bj = sum_i qn_bi S'_bij: the new state read out at the query
+kda_o = make_tensor_map_reduce(
+    "kda_o", _kda_contract, in_axes=[(0, 1), (0, 1, 2)], reduce_axis=1,
+    linear_args=(0, 1))
+
 # --- AdamW (precision-matched variants of repro.optim.fused) -----------------
 
 ema_pm = make_map(
@@ -144,5 +215,6 @@ from repro.optim.fused import adam_dir, apply_lr  # noqa: E402,F401
 ALL = {e.name: e for e in [
     rms_scale, exp_map, exp_sub, rsqrt_map, div_by, attn_score, attn_out,
     mla_score, mla_logits, mla_max, mla_exp_sub, mla_sum, mla_div, mla_value,
-    ema_pm, ema_sq_pm, adam_dir, apply_lr,
+    kda_sumsq, kda_knorm, kda_qnorm, kda_gate, kda_ka, kda_u, kda_w, kda_snew,
+    kda_o, ema_pm, ema_sq_pm, adam_dir, apply_lr,
 ]}

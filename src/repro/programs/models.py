@@ -1,6 +1,6 @@
 """LM decode-step workloads as registered programs (DESIGN.md §10).
 
-Four model-derived sequences, each validated *bitwise* against the
+Model-derived sequences, the first four validated *bitwise* against the
 repo's reference implementations (``repro.kernels.ref`` /
 ``repro.models.common``) when served through the fusion pipeline:
 
@@ -19,6 +19,11 @@ repo's reference implementations (``repro.kernels.ref`` /
   against one shared latent cache, softmax per head, the latent rows
   weighted (oracles: the float64 ``reference``, and
   ``mla_unabsorbed_reference``, the architecture's own equations).
+* ``KDA_DECODE_STEP`` — Kimi Linear's Kimi Delta Attention (KDA) at
+  decode, at its published head width 128: each row's gated delta-rule
+  state read, updated by a rank-1 term and written back, the one
+  program with a rank-3 output (oracles: the float64 ``reference``, and
+  ``kda_recurrent_reference``, the paper's recurrence over tokens).
 
 Size notes (pinned empirically, see DESIGN.md §10): matvec-bearing
 graphs (``LM_BLOCK``, ``LM_DECODE_ATTN``) are bitwise against the
@@ -297,3 +302,89 @@ def mla_unabsorbed_reference(q_nope, q_rope, ckv, kr, w_uk, w_uv):
         values = jnp.einsum("tc,hcd->htd", ckv, w_uv)
         return jnp.einsum("ht,htd->hd", p, values)
 
+
+# --- KDA_DECODE_STEP:  one decode step of Kimi Delta Attention ---------------
+
+def _kda_script(g, S, q, k, v, f, a_log, b):
+    qss = g.apply(mlib.kda_sumsq, q, name="qss")
+    kss = g.apply(mlib.kda_sumsq, k, name="kss")
+    qn = g.apply(mlib.kda_qnorm, q, qss, name="qn")
+    kn = g.apply(mlib.kda_knorm, k, kss, name="kn")
+    alpha = g.apply(mlib.kda_gate, f, a_log, name="alpha")
+    ka = g.apply(mlib.kda_ka, kn, alpha, name="ka")
+    u = g.apply(mlib.kda_u, ka, S, name="u")
+    w = g.apply(mlib.kda_w, b, v, u, name="w")
+    S_new = g.apply(mlib.kda_snew, S, alpha, kn, w, name="S_new")
+    o = g.apply(mlib.kda_o, qn, S_new, name="o")
+    return S_new, o
+
+
+def _kda_ref(S, q, k, v, f, a_log, b):
+    d = q.shape[-1]
+    qn = q / np.sqrt(np.sum(q * q, axis=-1, keepdims=True) + mlib.KDA_EPS)
+    qn = qn * d ** -0.5
+    kn = k / np.sqrt(np.sum(k * k, axis=-1, keepdims=True) + mlib.KDA_EPS)
+    alpha = np.exp(-np.exp(a_log)[:, None] * np.logaddexp(f, 0.0))
+    beta = 1.0 / (1.0 + np.exp(-b))
+    gated = alpha[:, :, None] * S
+    u = np.einsum("bi,bij->bj", kn, gated)
+    S_new = gated + kn[:, :, None] * (beta[:, None] * (v - u))[:, None, :]
+    return S_new, np.einsum("bi,bij->bj", qn, S_new)
+
+
+# One decode step of KDA for ``n`` rows, each one (session, head): heads
+# and sessions are independent, so both are flattened into the row axis.
+# Inputs: the state ``S`` (n, 128, 128), key by value; this token's q, k,
+# v and gate pre-activation f (n, 128), after the projections, the short
+# convolution and SiLU (f with ``dt_bias`` added); the head's decay
+# ``a_log`` and the token's write pre-activation ``b`` (n,).  Outputs:
+# the new state ``S_new`` and the read-out ``o`` (n, 128).
+_KDA_D = mlib.KDA_DIM
+_register(Program(
+    "KDA_DECODE_STEP", "M", _kda_script,
+    lambda n: {"S": (n, _KDA_D, _KDA_D), "q": (n, _KDA_D), "k": (n, _KDA_D),
+               "v": (n, _KDA_D), "f": (n, _KDA_D), "a_log": (n,), "b": (n,)},
+    _kda_ref,
+    lambda n: n * (7.0 * _KDA_D * _KDA_D + 23.0 * _KDA_D)))
+
+
+def kda_recurrent_reference(q, k, v, f, a_log, b, S0):
+    """KDA over T tokens as arXiv:2510.26692 writes it, float32
+    ``jax.numpy`` at full matmul precision, no compiler: per token,
+
+        S_t = (I - beta_t k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T
+        o_t = S_t^T q_t
+
+    with q and k L2-normed (eps 1e-6) and q scaled by ``head_dim**-0.5``,
+    ``alpha_t = exp(-exp(A_log) softplus(f_t))`` and ``beta_t =
+    sigmoid(b_t)``.
+
+    ``q``, ``k``, ``v``, ``f`` ``(T, n, d)``, ``b`` ``(T, n)``, ``a_log``
+    ``(n,)`` (a per-head parameter), ``S0`` ``(n, d, d)``; returns every
+    token's ``o`` ``(T, n, d)`` and the final state.  Departures from the
+    published layer (the cut ``KDA_DECODE_STEP`` makes too): q, k and v
+    arrive after the projections, the short convolution and SiLU; the
+    gate's low-rank projection and ``dt_bias`` are folded into ``f``;
+    the gated output RMSNorm and ``o_proj`` are left out."""
+    with jax.default_matmul_precision("highest"):
+        d = q.shape[-1]
+        eye = jnp.eye(d, dtype=jnp.float32)
+
+        def l2norm(x):
+            return x * jax.lax.rsqrt(
+                jnp.sum(x * x, axis=-1, keepdims=True) + mlib.KDA_EPS)
+
+        def step(S, tok):
+            q_t, k_t, v_t, f_t, b_t = tok
+            q_t, k_t = l2norm(q_t) * d ** -0.5, l2norm(k_t)
+            alpha = jnp.exp(-jnp.exp(a_log)[:, None] * jax.nn.softplus(f_t))
+            beta = jax.nn.sigmoid(b_t)[:, None, None]
+            erase = eye - beta * jnp.einsum("ni,nk->nik", k_t, k_t)
+            S = (jnp.einsum("nik,nkj->nij", erase, alpha[:, :, None] * S)
+                 + beta * jnp.einsum("ni,nj->nij", k_t, v_t))
+            return S, jnp.einsum("nij,ni->nj", S, q_t)
+
+        S, o = jax.lax.scan(step, jnp.asarray(S0, jnp.float32),
+                            tuple(jnp.asarray(x, jnp.float32)
+                                  for x in (q, k, v, f, b)))
+        return o, S

@@ -260,8 +260,9 @@ def test_required_cache_bytes_dominate_and_traffic_counts_two_passes():
     assert 2 * ckv.nbytes + kr.nbytes < split < 3 * required
 
 
-#: the parent's plans at the benchmark's sizes: per group its calls, grid
-#: order (positions into its sorted axis roots) and blocks
+#: the plans of the benchmarked programs (and ATAX) at the benchmark's
+#: sizes: per group its calls, grid order (positions into its sorted axis
+#: roots) and blocks
 PLANS = {
     ("GEMVER", 16384): [((0, 1), (1, 0), (16384, 128)),
                         ((2,), (0,), (16384,)),
@@ -269,6 +270,8 @@ PLANS = {
                         ((4,), (0,), (16384,))],
     ("AXPYDOT", 1 << 26): [((0, 1, 2), (0,), (1048576,))],
     ("ATAX", 16384): [((0, 1), (0, 1), (16384, 256))],
+    ("MLA_DECODE_ATTN", 131072): [((0, 1, 2, 3, 4, 5, 6, 7), (0, 1, 2, 3),
+                                   (16, 512, 64, 4096))],
 }
 
 

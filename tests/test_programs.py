@@ -11,7 +11,7 @@ from repro.programs import (ADAMW_HYPERS, BLAS, MODELS, REGISTRY,
 PAPER_SEQUENCES = ["AXPYDOT", "ATAX", "BiCGK", "SGEMV", "SGEMVT", "SSCAL",
                    "GEMVER", "GESUMMV", "MADD", "VADD", "WAXPBY"]
 MODEL_SEQUENCES = ["LM_RMSNORM", "LM_BLOCK", "LM_DECODE_ATTN", "FUSED_ADAMW",
-                   "MLA_DECODE_ATTN"]
+                   "MLA_DECODE_ATTN", "KDA_DECODE_STEP"]
 
 
 def test_groups_partition_the_registry():

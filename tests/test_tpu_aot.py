@@ -13,7 +13,10 @@ refuse, at the sizes the chip smoke run serves:
 * ATAX at 16384 — a 1 GiB matrix with a consumed vector reduction;
 * MLA_DECODE_ATTN at 131072 — depth-3 contractions over (heads, cache,
   latent) and a per-head (1, 16) vector carried in VMEM scratch, all in
-  one online-softmax kernel (also cut to 128 steps over the cache).
+  one online-softmax kernel (also cut to 128 steps over the cache);
+* KDA_DECODE_STEP at 4096 — a fully indexed (rows, key, value) state
+  read by broadcasts and sublane sums, and a rank-3 output written in
+  (rows, 128, 128) blocks.
 
 Each Pallas compile must contain a Mosaic kernel (``tpu_custom_call``).
 A narrow matrix reaches its kernel in XLA's own layout, as a bitcast
@@ -181,6 +184,19 @@ def test_online_softmax_kernel_compiles_for_v5e(one_chip, tpu_codegen):
         compiled.plan, groups=(dataclasses.replace(gp, blocks=blocks),))
     for p in (compiled, codegen.compile_plan(compiled.graph, plan)):
         assert "tpu_custom_call" in _compile(p, prog.shapes(n), one_chip)
+
+
+def test_kda_state_reaches_its_kernels_without_copy(one_chip, tpu_codegen):
+    """KDA_DECODE_STEP at the benchmark's 4096 rows: six Mosaic kernels,
+    the (4096, 128, 128) state S read by two of them and S_new written
+    by one, with no relayout copy or transpose of either."""
+    prog, n = REGISTRY["KDA_DECODE_STEP"], 4096
+    compiled = FusionCompiler(backend="pallas", cache=None).compile(
+        prog.script, prog.shapes(n))
+    hlo = _compile(compiled, prog.shapes(n), one_chip)
+    assert hlo.count("tpu_custom_call") >= compiled.n_groups == 6
+    assert _relayouts(hlo, 128, 128) == []
+    assert "f32[4096,128,128]{2,1,0}" in _kernel_operands(hlo)
 
 
 def test_jnp_block_compiles_for_v5e(one_chip, tpu_codegen):
