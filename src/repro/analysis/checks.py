@@ -38,7 +38,8 @@ from typing import Sequence
 import numpy as np
 
 from ..core.diagnostics import KNOWN_BACKENDS, Diagnostic, diag
-from ..core.fusion import analyse_group, consumed_reductions
+from ..core.fusion import (analyse_group, consumed_reductions,
+                           local_reductions)
 from ..core.graph import Graph
 from ..core.masking import MASK_INPUT
 from ..core.plan import (PLAN_VERSION, ExecutionPlan, PackedPlan,
@@ -358,8 +359,9 @@ def verify_plan(plan: ExecutionPlan, g: Graph, hw: HardwareModel = V5E,
     2), validates the grid order and block sizes against the bound
     fusion (RPL212/RPL213; blocks must also follow the predictor's
     per-operand tiling rule, ``block_granules``, on either backend),
-    enforces the pallas phase contract — every
-    consumed reduction accumulable under the plan's order (RPL214) —
+    enforces the pallas phase contract — every ``Fusion.whole_roots``
+    axis one whole block, and every consumed reduction that is not
+    block-local accumulable under the plan's order (RPL214) —
     and re-costs the implementation to check the VMEM footprint,
     including consumed-reduction scratch, against the budget (RPL215;
     configurable via ``vmem_budget`` or ``REPRO_VMEM_BUDGET``).
@@ -429,8 +431,21 @@ def verify_plan(plan: ExecutionPlan, g: Graph, hw: HardwareModel = V5E,
                         f"axis other than the streamed one",
                         "every other axis must be one whole block; pick "
                         "a block enumerate_impls emits"))
+                im = cost_impl(f, g, order, gp.blocks, hw)
+                split = [r for r in f.whole_roots if r not in im.whole_roots]
+                if split:
+                    out.append(diag(
+                        "RPL214", loc,
+                        f"blocks {gp.blocks} split axes {split}, which "
+                        f"must be one whole block: a member lacks them, "
+                        f"or a consumed reduction must finish inside a "
+                        f"grid step",
+                        "pick a block enumerate_impls emits, or split "
+                        "the group"))
+                local = local_reductions(f, g, im.whole_roots)
                 for c in consumed_reductions(f, g):
-                    if not accumulable(c.out, f, g, order):
+                    if c not in local and not accumulable(
+                            c.out, f, g, order, im.grid):
                         out.append(diag(
                             "RPL214", loc,
                             f"consumed reduction '{c.elem.name}' is not "
@@ -438,7 +453,6 @@ def verify_plan(plan: ExecutionPlan, g: Graph, hw: HardwareModel = V5E,
                             "its reduce axes must be the innermost "
                             "suffix; pick an order enumerate_impls "
                             "emits, or split the group"))
-                im = cost_impl(f, g, order, gp.blocks, hw)
                 if im.vmem_bytes > budget:
                     out.append(diag(
                         "RPL215", loc,

@@ -27,8 +27,9 @@ def make_synthetic_chain(n_calls: int, *, reduce_consume: bool = False,
       first (exercises the ``(1, 1)``-carrier BlockSpec path);
     * ``reduce_consume`` — the chain tail is sum-reduced and the
       finished scalar consumed by a later map (``xpay``), the fusion
-      rule-2 reduce→consume link the pallas backend phases through a
-      VMEM scratch accumulator;
+      rule-2 reduce→consume link the pallas backend consumes in the
+      grid step where one block holds the whole chain, else phases
+      through a VMEM scratch accumulator;
     * ``gemv`` — an ATAX-shaped depth-2 pair ``A^T (A v)`` hangs off
       the chain tail: the second matvec consumes the first's finished
       reduction (needs a fresh ``(n, n)`` input ``A``).

@@ -52,7 +52,7 @@ from . import tracing
 from .diagnostics import UnsupportedGroupError, VerificationError
 from .elementary import Monoid, col
 from .fusion import (ACC, DIV, MAX, Fusion, call_phases,
-                     consumed_reductions)
+                     consumed_reductions, local_reductions)
 from .graph import Graph, Var
 from .plan import ExecutionPlan, PackedPlan, build_plan
 from .predictor import (V5E, Carrier, HardwareModel, Impl, accumulable,
@@ -142,20 +142,29 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
                      name: str | None = None) -> Callable:
     """Build the single pallas_call for one fused group.
 
-    Groups whose reductions are only *produced* (never consumed inside)
-    compile to the single-sweep kernel.  Groups consuming a finished
-    reduction in-kernel (fusion rule 2, relaxed) get a leading *phase*
+    Groups whose reductions are only *produced* (never consumed inside),
+    or consumed block-local, compile to the single-sweep kernel.  A
+    consumed reduction is block-local when the impl's blocks hold each
+    of its reduce axes whole (``fusion.local_reductions``): its value is
+    finished inside every grid step and consumed in that step, with no
+    scratch, phase or ``pl.when``.  A member over fewer axes than the
+    group computes once per step on its natural block, every axis it
+    lacks one whole block, so an output of it, written through its own
+    index map, is never revisited.  Groups consuming any other finished
+    reduction in-kernel (fusion rule 2) get a leading *phase*
     grid axis: during phase p the consumed reductions assigned to phase
     p accumulate into VMEM scratch buffers; from phase p+1 on, their
     finished values are read back from scratch by the consuming calls.
     Map values are recomputed every phase (rematerialization), and
     every side effect — output write, scratch or output accumulation —
     is gated on its call's phase with ``pl.when``, so an unfinished
-    accumulator is never observable.  This requires every consumed
+    accumulator is never observable.  This requires every phased
     reduction to be ``accumulable`` under the impl's grid order (reduce
-    axes an innermost suffix); ``enumerate_impls`` emits only such
-    orders, and a hand-built plan violating it raises
-    ``NotImplementedError`` — the group-split contract (DESIGN.md §2).
+    axes an innermost suffix), and every axis in ``Fusion.whole_roots``
+    to be one whole block; ``enumerate_impls`` emits only such impls,
+    and a hand-built plan violating either raises
+    ``NotImplementedError`` (RPL214) — the group-split contract
+    (DESIGN.md §2).
 
     Every value crosses the kernel boundary — BlockSpec, partials
     array — in its ``predictor.operand_carrier`` form (rank >= 2,
@@ -186,15 +195,26 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
     blk = {r: b for r, b in zip(order, impl.blocks)}
     group_names = "+".join(c.elem.name for c in f.calls)
 
-    consumed = consumed_reductions(f, g)
+    whole = impl.whole_roots
+    split = [r for r in f.whole_roots if r not in whole]
+    if split:
+        raise UnsupportedGroupError.single(
+            "RPL214", f"plan.group[{group_names}]",
+            f"pallas backend cannot emit group [{group_names}] with grid "
+            f"{spatial_grid} over order {order}: axes {split} must be one "
+            f"whole block, as a member lacks them or a consumed "
+            f"reduction must finish inside a grid step")
+    local_idx = {c.idx for c in local_reductions(f, g, whole)}
+    consumed = tuple(c for c in consumed_reductions(f, g)
+                     if c.idx not in local_idx)   # the phased ones
     consumed_idx = {c.idx for c in consumed}
-    phase_of, n_phases = call_phases(f, g)
+    phase_of, n_phases = call_phases(f, g, whole)
     multi = n_phases > 1
     gofs = 1 if multi else 0                 # leading phase grid axis
     grid = ((n_phases,) + spatial_grid) if multi else spatial_grid
 
     for c in consumed:
-        if not accumulable(c.out, f, g, order):
+        if not accumulable(c.out, f, g, order, spatial_grid):
             raise UnsupportedGroupError.single(
                 "RPL214", f"plan.group[{group_names}]",
                 f"pallas backend cannot emit group [{group_names}]: "
@@ -226,7 +246,8 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
                 f"pallas backend cannot emit group [{group_names}]: call "
                 f"'{c.elem.name}' consumes the output of {bad}, which "
                 f"never becomes visible inside the kernel")
-        if online or not c.elem.is_reduction or c.idx in consumed_idx:
+        if (online or not c.elem.is_reduction
+                or c.idx in consumed_idx | local_idx):
             resolvable.add(c.out)
 
     def roots_of(v: Var) -> tuple[int, ...]:
@@ -301,7 +322,7 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
     for v in f.outputs:
         shape, block, _ = carrier(v)
         rr = reduce_roots_of(v, f, g)
-        if not rr or accumulable(v, f, g, order) or online:
+        if not rr or accumulable(v, f, g, order, spatial_grid) or online:
             out_specs.append(pl.BlockSpec(block, make_index_map(v)))
             out_shapes.append(jax.ShapeDtypeStruct(shape, v.dtype))
             out_mode.append(("acc", tuple(pos[r] for r in rr)) if rr
@@ -383,7 +404,8 @@ def _group_pallas_fn(g: Graph, impl: Impl, hw: HardwareModel = V5E,
                     sref[idx] = m.combine(sref[idx], cval)
 
                 env[c.out] = load(c.out, sref, idx, scratch=True)
-            elif not c.elem.is_reduction:
+            elif not c.elem.is_reduction or c.idx in local_idx:
+                # a block-local reduction is finished in this step
                 env[c.out] = val
             if c.out in out_index:
                 i = out_index[c.out]
@@ -545,6 +567,16 @@ class CompiledProgram:
                 if v.is_input:
                     passes[v.name] += im.n_phases
         return passes
+
+    @property
+    def local_reductions(self) -> tuple[str, ...]:
+        """The reductions consumed in the grid step that finishes them
+        (block-local, ``fusion.local_reductions``): their output names,
+        in plan order.  Each spares its group a phase, a pass over its
+        inputs, that ``input_passes`` would otherwise count."""
+        return tuple(c.out.name for im in self.group_impls
+                     for c in local_reductions(im.fusion, self.graph,
+                                               im.whole_roots))
 
     def __call__(self, **inputs):
         with tracing.span(tracing.DISPATCH):

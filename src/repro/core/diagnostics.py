@@ -67,8 +67,9 @@ CODES: dict[str, tuple[str, str]] = {
     "RPL211": ("error", "plan group is not a legal fusion of this graph"),
     "RPL212": ("error", "grid order invalid for the bound fusion"),
     "RPL213": ("error", "block size illegal for the bound fusion axis"),
-    "RPL214": ("error", "consumed reduction not accumulable under the "
-                        "plan's grid order (pallas phase contract), or an "
+    "RPL214": ("error", "phased consumed reduction not accumulable under "
+                        "the plan's grid order (pallas phase contract), an "
+                        "axis that must be one whole block split, or an "
                         "online-softmax group blocked off its streamed "
                         "axis"),
     "RPL215": ("error", "group VMEM footprint (blocks + consumed-reduction "

@@ -3,21 +3,36 @@
 A *fusion* is a subset of the call DAG that can be glued into one kernel.
 Legality rules, transposed from CUDA thread blocks to Pallas grids:
 
-1. **Same iteration space.**  All calls in a fusion must iterate over the
-   same unified axis set (paper: same thread-block-to-data mapping; also
-   subsumes "never fuse different nesting depths", §3.2.3).
-2. **Reduce consumption needs phases.**  The *finished* result of a
-   reduction is only available once its reduce axes complete, which on
-   CUDA meant a global barrier (= kernel boundary, §3.2.2).  On the
-   Pallas backend the barrier is a leading *phase* grid axis instead:
-   phase p accumulates the reduction into a VMEM scratch buffer, phase
-   p+1 reads the finished value back (DESIGN.md §2).  That requires a
-   grid order with every consumed reduction's reduce axes as an
-   innermost suffix, so a producer→consumer edge from a reduction is
-   legal iff the consumed reduce-axis sets form a chain under inclusion
-   (some order then serves them all).  Groups with no such order are
-   rejected here — the documented *group-split*: the partition search
-   simply covers those calls with smaller fusions.
+1. **One iteration space.**  A fusion iterates over the union of its
+   calls' unified axes (paper: same thread-block-to-data mapping; also
+   "never fuse different nesting depths", §3.2.3).  A call over a
+   subset of them is admitted only if every axis it lacks is one whole
+   block (``Fusion.whole_roots``): it then computes once per grid step
+   on its natural block, and an output of it is never revisited.
+2. **Reduce consumption: a block-local or a phase barrier.**  The
+   *finished* result of a reduction is only available once its reduce
+   axes complete, which on CUDA meant a global barrier (= kernel
+   boundary, §3.2.2).  On the Pallas backend that barrier becomes local
+   wherever it can (DESIGN.md §2):
+
+   * *block-local*: under an implementation whose blocks hold each of
+     the reduction's reduce axes whole (grid extent 1), the reduction
+     finishes inside every grid step and is consumed in the same step
+     (``local_reductions``);
+   * *phase*: otherwise a leading phase grid axis; phase p accumulates
+     the reduction into a VMEM scratch buffer, phase p+1 reads the
+     finished value back.  That requires a grid order with every
+     phased reduction's reduce axes as an innermost suffix, so the
+     consumed reduce-axis sets must form a chain under inclusion.
+
+   A fusion whose calls share one axis set and whose consumed
+   reduce-axis sets form a chain needs nothing more (either barrier
+   serves).  Any other fusion — a call over fewer axes, or a broken
+   chain — is legal only with every consumed reduction block-local, so
+   their reduce axes join ``whole_roots``; where no block that size
+   fits VMEM, ``enumerate_impls`` yields nothing and the partition
+   search covers those calls with smaller fusions (the documented
+   *group-split*).
 3. **Convexity.**  No path from a fusion member to another fusion member
    may leave the fusion (the outside node could not be scheduled).
 4. **Connectivity / usefulness.**  Members must be connected through
@@ -56,6 +71,10 @@ class Fusion:
     # call's role (``online_roles``); None and () for any other group
     stream_root: int | None = None
     roles: tuple[str, ...] = ()
+    # the axes every implementation must block whole (rules 1-2): each
+    # axis a member lacks, and, where the group needs them block-local,
+    # its consumed reductions' reduce axes (sorted; () for most groups)
+    whole_roots: tuple[int, ...] = ()
 
     @property
     def key(self) -> frozenset:
@@ -158,10 +177,11 @@ def online_roles(g: Graph, members: list[CallNode],
 
 
 def consumed_reductions(f: Fusion, g: Graph) -> tuple[CallNode, ...]:
-    """Reduction members whose output is consumed *inside* ``f`` — the
-    calls whose finished value a multi-phase pallas kernel must carry in
-    a VMEM scratch accumulator (rule 2, relaxed).  An online-softmax
-    group has none: its running max and sums are read unfinished."""
+    """Reduction members whose output is consumed *inside* ``f``: the
+    calls whose finished value the kernel must have before a consumer
+    reads it, block-local or through a phase (rule 2).  An
+    online-softmax group has none: its running max and sums are read
+    unfinished."""
     if f.stream_root is not None:
         return ()
     idxset = {c.idx for c in f.calls}
@@ -169,16 +189,40 @@ def consumed_reductions(f: Fusion, g: Graph) -> tuple[CallNode, ...]:
                  and any(cc.idx in idxset for cc in g.consumers(c.out)))
 
 
-def call_phases(f: Fusion, g: Graph) -> tuple[dict[int, int], int]:
-    """Phase assignment for a (possibly multi-phase) kernel body.
+def reduce_roots(c: CallNode, g: Graph) -> frozenset[int]:
+    """The axis roots call ``c`` reduces over: those it iterates and
+    its output lacks."""
+    return (frozenset(g.call_axis_roots(c))
+            - {g.axis_root(a) for a in c.out.axis_ids})
+
+
+def local_reductions(f: Fusion, g: Graph,
+                     whole: Iterable[int] | None = None
+                     ) -> tuple[CallNode, ...]:
+    """The consumed reductions that are *block-local* when the axis
+    roots ``whole`` are each one whole block (default: the fusion's own
+    ``whole_roots``): each of their reduce axes is whole, so the value
+    finishes inside every grid step and is consumed in that step."""
+    whole = set(f.whole_roots if whole is None else whole)
+    return tuple(c for c in consumed_reductions(f, g)
+                 if reduce_roots(c, g) <= whole)
+
+
+def call_phases(f: Fusion, g: Graph, whole: Iterable[int] | None = None
+                ) -> tuple[dict[int, int], int]:
+    """Phase assignment for a (possibly multi-phase) kernel body, where
+    the axis roots ``whole`` are each one whole block (default: the
+    fusion's ``whole_roots``, what every implementation holds).
 
     ``phase(c)`` is the max over c's in-fusion producers p of
-    ``phase(p) + 1`` if p is a consumed reduction (its finished value
-    only becomes visible one full grid sweep later) else ``phase(p)``;
-    calls fed only by external inputs are phase 0.  Returns
-    ``(call idx -> phase, n_phases)``; ``n_phases == 1`` means the
-    group needs no phase axis (the single-sweep kernel)."""
-    consumed = {c.idx for c in consumed_reductions(f, g)}
+    ``phase(p) + 1`` if p is a consumed reduction that is not
+    block-local (its finished value only becomes visible one full grid
+    sweep later) else ``phase(p)``; calls fed only by external inputs
+    are phase 0.  Returns ``(call idx -> phase, n_phases)``;
+    ``n_phases == 1`` means the group needs no phase axis (the
+    single-sweep kernel)."""
+    local = {c.idx for c in local_reductions(f, g, whole)}
+    phased = {c.idx for c in consumed_reductions(f, g)} - local
     producer = {c.out: c for c in f.calls}
     phase: dict[int, int] = {}
     for c in f.calls:
@@ -186,7 +230,7 @@ def call_phases(f: Fusion, g: Graph) -> tuple[dict[int, int], int]:
         for a in c.args:
             pc = producer.get(a)
             if pc is not None:
-                p = max(p, phase[pc.idx] + (1 if pc.idx in consumed else 0))
+                p = max(p, phase[pc.idx] + (1 if pc.idx in phased else 0))
         phase[c.idx] = p
     n_phases = 1 + (max(phase.values()) if phase else 0)
     return phase, n_phases
@@ -219,35 +263,31 @@ def analyse_group(g: Graph, members: Iterable[CallNode],
         for r, s in zip(g.call_axis_roots(c), c.axis_sizes):
             root_to_size[r] = s
 
-    if online is not None:
-        # an online-softmax group iterates over the union of its calls'
-        # axes, and its one consumed MAX needs no phase (rules 1-2)
-        ref_roots = tuple(sorted(root_to_size))
-    else:
-        # rule 1: identical unified axis sets
-        ref_roots = call_roots[0]
-        if any(roots != ref_roots for roots in call_roots):
-            return None
-
-        # rule 2 (relaxed): a reduction output consumed inside the fusion
-        # is legal iff every consumed reduce-axis set can sit as an
-        # innermost suffix of ONE grid order — i.e. the consumed sets
-        # form a chain under inclusion.  Codegen then emits a multi-phase
-        # kernel carrying the finished value in VMEM scratch; otherwise
-        # the group is rejected and the partition search falls back to
-        # smaller fusions (the documented group-split, DESIGN.md §2).
-        rootset = set(ref_roots)
-        consumed_sets: list[set[int]] = []
-        for c in members:
-            if not c.elem.is_reduction:
-                continue
-            if any(cc.idx in idxset for cc in g.consumers(c.out)):
-                out_roots = {g.axis_root(a) for a in c.out.axis_ids}
-                consumed_sets.append(rootset - out_roots)
-        consumed_sets.sort(key=len)
-        for small, big in zip(consumed_sets, consumed_sets[1:]):
-            if not small <= big:
-                return None
+    # rule 1: the union of the calls' axes; a call over fewer of them
+    # needs each axis it lacks whole, and an online-softmax group's one
+    # consumed MAX needs no phase
+    ref_roots = tuple(sorted(root_to_size))
+    whole: set[int] = set()
+    if online is None:
+        for roots in call_roots:
+            whole |= set(ref_roots) - set(roots)
+        # rule 2: a consumed reduction is block-local under blocks that
+        # hold its reduce axes whole, else it needs a phase, and one
+        # grid order must put every phased reduce-axis set as an
+        # innermost suffix — i.e. the sets form a chain under inclusion.
+        # A group that is uniform (no axis missing) and chained may use
+        # either; any other makes every consumed reduction block-local,
+        # and where no such block fits VMEM, enumerate_impls yields
+        # nothing and the search splits the group (DESIGN.md §2)
+        consumed_sets = sorted(
+            (reduce_roots(c, g) for c in members if c.elem.is_reduction
+             and any(cc.idx in idxset for cc in g.consumers(c.out))),
+            key=len)
+        chained = all(small <= big for small, big
+                      in zip(consumed_sets, consumed_sets[1:]))
+        if whole or not chained:
+            for rr in consumed_sets:
+                whole |= rr
 
     # rule 3: convexity
     if reach is None:
@@ -313,6 +353,7 @@ def analyse_group(g: Graph, members: Iterable[CallNode],
         outputs=tuple(outputs),
         stream_root=stream_root,
         roles=roles,
+        whole_roots=tuple(sorted(whole)),
     )
 
 

@@ -33,7 +33,8 @@ from typing import NamedTuple
 
 import numpy as np
 
-from .fusion import ACC, MAX, Fusion, call_phases, consumed_reductions
+from .fusion import (ACC, MAX, Fusion, call_phases, consumed_reductions,
+                     local_reductions, reduce_roots)
 from .graph import CallNode, Graph, Var
 
 #: a refit needs at least this many group records before the regression
@@ -243,6 +244,12 @@ class Impl:
         axis of a multi-phase group included."""
         return self.n_phases * math.prod(self.grid)
 
+    @property
+    def whole_roots(self) -> tuple[int, ...]:
+        """The axis roots this implementation blocks whole (grid extent
+        1), in grid order."""
+        return whole_roots(self.order, self.grid)
+
     def block_of(self, root: int) -> int:
         return self.blocks[self.order.index(root)]
 
@@ -252,6 +259,12 @@ class Impl:
                 f"traffic={self.traffic_bytes/1e6:.2f}MB "
                 f"flops={self.flops/1e6:.2f}MF vmem={self.vmem_bytes/1e3:.0f}KB "
                 f"t={self.t_pred*1e6:.2f}us")
+
+
+def whole_roots(order: tuple[int, ...], grid: tuple[int, ...]
+                ) -> tuple[int, ...]:
+    """The roots of ``order`` whose grid extent is 1: one whole block."""
+    return tuple(r for r, n in zip(order, grid) if n == 1)
 
 
 def _divisor_blocks(size: int, minimum: int, maximum: int | None = None) -> list[int]:
@@ -391,31 +404,38 @@ def var_streams(v: Var, g: Graph, order: tuple[int, ...], grid: tuple[int, ...])
     """How many times ``v`` is streamed from HBM for a given grid order.
 
     An input indexed by axis subset S is re-fetched whenever an axis
-    outside S, ordered *outer* than the innermost axis of S, advances
-    (Pallas refetches a block when its index map output changes).
+    outside S, ordered *outer* than the innermost axis of S that the
+    grid splits, advances (Pallas refetches a block when its index map
+    output changes; an axis of one whole block never changes it).
     """
     s_roots = {g.axis_root(a) for a in v.axis_ids}
-    if not s_roots:
+    split = [i for i, (r, n) in enumerate(zip(order, grid))
+             if r in s_roots and n > 1]
+    if not split:
         return 1
-    pos = {r: i for i, r in enumerate(order)}
-    inner_s = max(pos[r] for r in s_roots if r in pos) if any(r in pos for r in s_roots) else -1
     n = 1
-    for i, r in enumerate(order):
-        if r not in s_roots and i < inner_s:
+    for i, r in enumerate(order[:max(split)]):
+        if r not in s_roots:
             n *= grid[i]
     return n
 
 
 def reduce_roots_of(v: Var, f: Fusion, g: Graph) -> tuple[int, ...]:
-    """Fusion axes over which output ``v`` is reduced."""
-    s_roots = {g.axis_root(a) for a in v.axis_ids}
-    return tuple(r for r in f.axis_roots if r not in s_roots)
+    """Fusion axes over which output ``v`` is reduced, in the fusion's
+    order: those its producing call reduces over (an axis only other
+    calls of the fusion iterate is no reduce axis of ``v``)."""
+    rr = reduce_roots(v.producer, g)
+    return tuple(r for r in f.axis_roots if r in rr)
 
 
-def accumulable(v: Var, f: Fusion, g: Graph, order: tuple[int, ...]) -> bool:
-    """True iff v's reduce axes are the innermost suffix of the grid order
-    — the in-VMEM accumulation case; else partials + combine."""
-    rr = set(reduce_roots_of(v, f, g))
+def accumulable(v: Var, f: Fusion, g: Graph, order: tuple[int, ...],
+                grid: tuple[int, ...]) -> bool:
+    """True iff v's reduce axes that the ``grid`` splits are the
+    innermost suffix of the split axes in the grid order — the in-VMEM
+    accumulation case; else partials + combine.  An axis of one whole
+    block never moves a block, so its place in the order is no matter."""
+    order = tuple(r for r, n in zip(order, grid) if n > 1)
+    rr = set(reduce_roots_of(v, f, g)) & set(order)
     if not rr:
         return True
     k = len(rr)
@@ -435,13 +455,17 @@ def cost_impl(f: Fusion, g: Graph, order: tuple[int, ...],
     grid = tuple(-(-sizes[a] // b) for a, b in zip(order, blocks))
     blk = dict(zip(order, blocks))
 
-    # in-kernel reduce consumption forces a leading phase grid axis: the
+    # a consumed reduction whose reduce axes are each one whole block
+    # finishes inside every grid step and is consumed there (block-local,
+    # DESIGN.md §2); any other forces a leading phase grid axis: the
     # kernel re-streams every input and recomputes every map value once
-    # per phase (rematerialization — DESIGN.md §2), so inputs and flops
-    # are charged n_phases times; each consumed reduction additionally
-    # holds its FULL finished value in a VMEM scratch accumulator
-    consumed = consumed_reductions(f, g)
-    n_phases = call_phases(f, g)[1] if consumed else 1
+    # per phase (rematerialization), so inputs and flops are charged
+    # n_phases times, and the reduction holds its FULL finished value in
+    # a VMEM scratch accumulator
+    whole = whole_roots(order, grid)
+    local = local_reductions(f, g, whole)
+    phased = tuple(c for c in consumed_reductions(f, g) if c not in local)
+    n_phases = call_phases(f, g, whole)[1] if phased else 1
 
     # ---- traffic ----------------------------------------------------------
     traffic = 0.0
@@ -449,7 +473,8 @@ def cost_impl(f: Fusion, g: Graph, order: tuple[int, ...],
         traffic += v.nbytes * var_streams(v, g, order, grid) * n_phases
     for v in f.outputs:
         rr = reduce_roots_of(v, f, g)
-        if not rr or accumulable(v, f, g, order) or f.stream_root is not None:
+        if (not rr or accumulable(v, f, g, order, grid)
+                or f.stream_root is not None):
             traffic += v.nbytes
         else:
             nparts = math.prod(grid[order.index(r)] for r in rr)
@@ -482,9 +507,10 @@ def cost_impl(f: Fusion, g: Graph, order: tuple[int, ...],
     for v in f.internal_vars:
         # computed inside the kernel, so held in the value's own order
         vmem += padded_bytes(carrier(v).natural.block, v.dtype, hw)
-    for c in consumed + online_accumulators(f):
-        # full-size scratch accumulator carrying the finished reduction,
-        # or an online group's running max or sum
+    for c in phased + online_accumulators(f):
+        # full-size scratch accumulator carrying a phased reduction's
+        # finished value, or an online group's running max or sum (a
+        # block-local reduction is one block, counted as internal above)
         vmem += padded_bytes(carrier(c.out).natural.shape, c.out.dtype, hw)
 
     dt = fusion_dtype(f)
@@ -509,14 +535,17 @@ def enumerate_impls(f: Fusion, g: Graph, hw: HardwareModel = V5E,
     VMEM than another goes; a faster one that needs more VMEM stays.
     The survivors come fastest first.
 
-    Fusions that consume a reduction in-kernel (fusion rule 2, relaxed)
-    only admit grid orders under which every consumed reduction is
-    ``accumulable`` (reduce axes an innermost suffix) — the orders the
-    multi-phase pallas kernel can actually emit.  Rule 2's chain
-    condition guarantees at least one such order exists; if VMEM
-    pruning still empties the list, ``build_space`` drops the fusion
-    and the partition search covers its calls with smaller groups (the
-    group-split fallback, DESIGN.md §2).
+    Every axis in ``Fusion.whole_roots`` is one whole block (fusion
+    rules 1-2).  A consumed reduction whose reduce axes the blocks hold
+    whole is block-local and needs no particular order; every other
+    consumed reduction needs a phase, and only grid orders under which
+    it is ``accumulable`` (reduce axes an innermost suffix) are emitted
+    — the orders the multi-phase pallas kernel can actually emit.  Rule
+    2's chain condition guarantees at least one such order exists; if
+    VMEM pruning still empties the list (a whole axis too long for
+    VMEM, say), ``build_space`` drops the fusion and the partition
+    search covers its calls with smaller groups (the group-split
+    fallback, DESIGN.md §2).
     """
     roots, sizes = f.axis_roots, f.axis_sizes
     depth = len(roots)
@@ -537,15 +566,28 @@ def enumerate_impls(f: Fusion, g: Graph, hw: HardwareModel = V5E,
             cands.append(cost_impl(f, g, roots, (b,), hw))
     else:
         blocks_per_axis = [
+            [sizes[k]] if roots[k] in f.whole_roots else
             _divisor_blocks(sizes[k], gran[roots[k]], maximum=1 << 16)
             for k in range(depth)
         ]
+        seen = set()
         for order in itertools.permutations(range(depth)):
             o_roots = tuple(roots[i] for i in order)
-            if consumed and any(not accumulable(c.out, f, g, o_roots)
-                                for c in consumed):
-                continue  # the phase kernel cannot carry the value
             for bs in itertools.product(*(blocks_per_axis[i] for i in order)):
+                grid = tuple(-(-sizes[roots.index(r)] // b)
+                             for r, b in zip(o_roots, bs))
+                # where a whole axis sits in the order changes nothing:
+                # cost and kernel are those of the order without it
+                key = (tuple(r for r, n in zip(o_roots, grid) if n > 1),
+                       frozenset(zip(o_roots, bs)))
+                if key in seen:
+                    continue
+                seen.add(key)
+                local = local_reductions(f, g, whole_roots(o_roots, grid))
+                if any(c not in local
+                       and not accumulable(c.out, f, g, o_roots, grid)
+                       for c in consumed):
+                    continue  # the phase kernel cannot carry the value
                 cands.append(cost_impl(f, g, o_roots, bs, hw))
 
     cands = [c for c in cands if c.vmem_bytes <= hw.vmem_bytes]

@@ -14,8 +14,8 @@ function.
 
 ``--backend pallas`` serves the same program through the pallas backend
 instead — every fused group one ``pl.pallas_call``, including
-multi-phase kernels that consume finished reductions in-kernel
-(DESIGN.md §2).  The kernels compile with Mosaic, which needs a TPU;
+kernels that consume finished reductions in-kernel, in the grid step
+that finishes them or a phase later (DESIGN.md §2).  The kernels compile with Mosaic, which needs a TPU;
 ``--interpret`` runs them in the Pallas interpreter on any platform:
 
     PYTHONPATH=src python -m repro.launch.serve --blas GEMVER \

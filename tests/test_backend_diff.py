@@ -16,7 +16,8 @@ import pytest
 
 from repro.core import FusionCompiler, PlanCache, V5E, trace
 from repro.core import codegen
-from repro.core.fusion import call_phases, consumed_reductions
+from repro.core.fusion import (call_phases, consumed_reductions,
+                               local_reductions)
 from repro.core.plan import build_plan
 from repro.core.predictor import cost_impl
 from repro.core.scheduler import (Combination, build_space,
@@ -116,11 +117,14 @@ def test_consuming_fusion_exists_and_validates(name):
     for o_p, o_j in zip(pl_out, jnp_out):
         np.testing.assert_allclose(np.asarray(o_p), np.asarray(o_j),
                                    rtol=1e-4, atol=1e-3)
-    # and the consuming fusion is genuinely multi-phase
+    # and the consuming fusion carries the finished value with no kernel
+    # boundary: at N=32 its blocks hold every reduce axis whole, so the
+    # value is block-local, consumed in the step that finishes it (the
+    # phase barrier, where a reduce axis is split: ``_atax_phase_impl``)
     im = next(im for im in consuming[0].impls
               if consumed_reductions(im.fusion, g))
-    _, n_phases = call_phases(im.fusion, g)
-    assert n_phases >= 2
+    assert local_reductions(im.fusion, g, im.whole_roots)
+    assert im.n_phases == call_phases(im.fusion, g, im.whole_roots)[1] == 1
 
 
 def test_no_program_forced_to_singletons():
@@ -143,17 +147,21 @@ def test_no_program_forced_to_singletons():
 
 def test_attn_softmax_is_three_phases():
     """LM_DECODE_ATTN's softmax chain (scale, max-reduce, exp-sub,
-    sum-reduce, div) fuses into one kernel with two consumed
-    reductions — a 3-phase body.  (The space also holds the whole chain
-    as one online-softmax group, which has no phases.)"""
+    sum-reduce, div) fuses into one kernel over one axis set with two
+    consumed reductions — a 3-phase body wherever t is split into
+    blocks, and a single sweep where t is one whole block (both
+    reductions block-local).  (The space also holds the whole chain as
+    one online-softmax group, which has no phases.)"""
     _, g = _graph("LM_DECODE_ATTN")
     space = build_space(g)
-    widest = max((f for f in space.fusions if f.stream_root is None),
+    widest = max((f for f in space.fusions
+                  if f.stream_root is None and not f.whole_roots),
                  key=lambda f: len(f.calls))
     consumed = consumed_reductions(widest, g)
     assert len(consumed) >= 2
     _, n_phases = call_phases(widest, g)
     assert n_phases >= 3
+    assert call_phases(widest, g, widest.axis_roots)[1] == 1
 
 
 # ---------------------------------------------------------------------------
@@ -241,16 +249,26 @@ def test_compile_surfaces_group_names():
         codegen.compile_plan(g, plan, interpret=True, jit=False)
 
 
+def _atax_phase_impl(n=256, block=128):
+    """ATAX's consuming fusion with gemv's reduce axis j split into
+    blocks, innermost: the phase barrier, two phases."""
+    prog, g = _graph("ATAX", n=n)
+    f = next(f for f in build_space(g).fusions if len(f.calls) == 2)
+    t = f.calls[0].out                      # gemv out, keeps axis i
+    i_root = g.axis_root(t.axis_ids[0])
+    j_root = next(r for r in f.axis_roots if r != i_root)
+    im = cost_impl(f, g, (i_root, j_root), (block, block), V5E)
+    assert im.n_phases == 2 and not local_reductions(f, g, im.whole_roots)
+    return prog, g, im
+
+
 def test_measure_group_times_multiphase_pallas_kernel():
     """The autotune seam (DESIGN.md §8): ``measure_group`` with
     ``backend="pallas"`` times the SAME multi-phase consuming kernel
     ``_group_pallas_fn`` emits — no measurement-loop changes needed for
     the new group shapes."""
     from repro.core.autotune import measure_group
-    _, g = _graph("ATAX")
-    space = build_space(g)
-    f = next(f for f in space.fusions if len(f.calls) == 2)
-    im = space.impls_by_fusion[f.key][0]
+    _, g, im = _atax_phase_impl()
     assert consumed_reductions(im.fusion, g)
     t = measure_group(g, im, backend="pallas", interpret=True,
                       reps=2, warmup=1, inner=2)

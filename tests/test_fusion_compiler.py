@@ -46,26 +46,45 @@ class TestLegality:
         assert any(len(f.calls) == 2 for f in fusions)
 
     def test_reduce_consumer_needs_same_axes(self):
-        """Consuming a finished reduction in-kernel is now legal (rule 2
-        relaxed, multi-phase codegen) — but only when the consumer
-        iterates the same unified axis set (rule 1 still applies)."""
+        """Consuming a finished reduction in-kernel is legal through a
+        phase only when the consumer iterates the same unified axis set;
+        a consumer over fewer axes needs the reduction block-local."""
         g = _graph("AXPYDOT")
         # calls: axmy(0), ew_mul(1), sum_reduce(2); nothing consumes the
-        # reduce inside this graph, so the 3-fusion is legal
-        assert analyse_group(g, g.calls) is not None
-        # in SGEMVT, xpay consumes gemtv's finished reduction — but
-        # gemtv iterates {i, j} while xpay iterates {j} only, so rule 1
-        # (same iteration space) rejects the pair regardless of phases:
+        # reduce inside this graph, so the 3-fusion is legal as it was
+        f = analyse_group(g, g.calls)
+        assert f is not None and f.whole_roots == ()
+        # in SGEMVT, xpay consumes gemtv's finished reduction — gemtv
+        # iterates {i, j}, xpay {j} only: no phase can carry it, so the
+        # pair is legal only with i, the axis xpay lacks and gemtv
+        # reduces over, one whole block (the block-local barrier)
         g2 = _graph("SGEMVT")
-        gemtv_call = g2.calls[0]
-        xpay_call = g2.calls[1]
-        assert analyse_group(g2, [gemtv_call, xpay_call]) is None
+        gemtv_call, xpay_call = g2.calls[0], g2.calls[1]
+        f2 = analyse_group(g2, [gemtv_call, xpay_call])
+        i = g2.axis_root(gemtv_call.args[0].axis_ids[0])
+        assert f2 is not None and f2.whole_roots == (i,)
+        from repro.core.fusion import call_phases, local_reductions
+        assert local_reductions(f2, g2) == (gemtv_call,)
+        assert call_phases(f2, g2)[1] == 1
+        impls = enumerate_impls(f2, g2, V5E)
+        assert impls and all(im.block_of(i) == 256 and im.n_phases == 1
+                             for im in impls)
 
     def test_depth_mixing_rejected(self):
-        """Nested (depth-2) never fuses with unnested (depth-1) §3.2.3."""
+        """Nested (depth-2) fuses with unnested (depth-1) §3.2.3 only
+        where the unnested call's missing axis is one whole block: where
+        no such block fits VMEM, the space has no such group."""
         g = _graph("SGEMV")
         gemv_call, axpby_call = g.calls[0], g.calls[1]
-        assert analyse_group(g, [gemv_call, axpby_call]) is None
+        f = analyse_group(g, [gemv_call, axpby_call])
+        j = g.axis_root(gemv_call.args[0].axis_ids[1])
+        assert f is not None and f.whole_roots == (j,)
+        assert all(im.block_of(j) == 256
+                   for im in enumerate_impls(f, g, V5E))
+        # at n = 2**21 eight whole rows of A take 64 MiB a buffer
+        big = _graph("SGEMV", n=1 << 21)
+        assert not enumerate_impls(analyse_group(big, big.calls), big, V5E)
+        assert all(len(f.calls) == 1 for f in build_space(big).fusions)
 
     def test_convexity(self):
         """p→x→c with x outside the group is rejected (§4.2)."""
@@ -239,6 +258,9 @@ class TestTrafficModel:
         # r is indexed by i only: the mirror image
         assert var_streams(r, g, (i, j), grid) == 1
         assert var_streams(r, g, (j, i), grid) == grid[0]
+        # an axis of one whole block never moves a block: p over a
+        # whole j is fetched once whatever the order
+        assert var_streams(p, g, (i, j), (4, 1)) == 1
 
     def test_accumulable(self):
         g, f, i, j = self._bicgk_fusion()
@@ -246,10 +268,15 @@ class TestTrafficModel:
         assert set(reduce_roots_of(q, f, g)) == {j}
         assert set(reduce_roots_of(s, f, g)) == {i}
         # an output accumulates iff its reduce axes are innermost
-        assert accumulable(q, f, g, (i, j))
-        assert not accumulable(q, f, g, (j, i))
-        assert accumulable(s, f, g, (j, i))
-        assert not accumulable(s, f, g, (i, j))
+        grid = (4, 4)
+        assert accumulable(q, f, g, (i, j), grid)
+        assert not accumulable(q, f, g, (j, i), grid)
+        assert accumulable(s, f, g, (j, i), grid)
+        assert not accumulable(s, f, g, (i, j), grid)
+        # ... among the axes the grid splits: a whole j leaves q's block
+        # visited in one run of steps, so both outputs accumulate
+        assert accumulable(q, f, g, (j, i), (1, 4))
+        assert accumulable(s, f, g, (j, i), (1, 4))
 
     def test_partials_traffic_formula(self):
         """cost_impl charges an accumulable output one write and a

@@ -43,11 +43,12 @@ def _check_axpydot(cp, hw):
 def _check_gemver(cp, hw):
     labels = cp.group_labels
     assert [lb.split("_", 1)[1] for lb in labels] == [
-        "rank2_update_gemtv", "xpay", "gemv", "scal"]
+        "rank2_update_gemtv_xpay_gemv", "scal"]
     g0 = cp.group_impls[0]
-    assert (g0.order, g0.blocks) == ((1, 0), (16384, 128))
+    assert (g0.order, g0.blocks) == ((0, 1), (16384, 128))
     steps = dict(zip(labels, (im.grid_steps for im in cp.group_impls)))
-    assert steps["g1_xpay"] == 1 and steps["g3_scal"] == 1
+    assert steps["g0_rank2_update_gemtv_xpay_gemv"] == 128
+    assert steps["g1_scal"] == 1
 
 
 @pytest.mark.parametrize("name,n,check", [

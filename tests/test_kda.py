@@ -131,10 +131,11 @@ def test_a_step_without_the_delta_term_or_the_gate_fails(monkeypatch, name,
 
 def test_multi_step_grids_on_the_interpreter():
     """At n = 256 the plan re-blocked to the fewest rows each group's
-    operands allow (8 where every operand has a row of 128, else 128):
-    every group runs several grid steps over the rows, the rank-3
-    ``S_new`` written block by block, and still meets the float64
-    reference."""
+    operands allow (128 where a vector over the rows is an operand, as
+    in both groups of this plan, else 8): every group runs several grid
+    steps over the rows, the one group that reads the state finishing
+    ``u`` inside each step and writing the rank-3 ``S_new`` block by
+    block, and still meets the float64 reference."""
     n = 256
     g = trace(PROG.script, PROG.shapes(n))
     S = next(v for v in g.inputs if v.name == "S")
@@ -147,10 +148,12 @@ def test_multi_step_grids_on_the_interpreter():
         impls.append(cost_impl(im.fusion, g, im.order, blocks, V5E))
     assert all(im.grid_steps >= 2 for im in impls)
     state = [im for im in impls if S in im.fusion.external_inputs]
-    assert len(state) == 2 and all(im.grid_steps == 32 for im in state)
+    assert len(state) == 1 and state[0].grid_steps == 2
+    assert state[0].n_phases == 1
     cp = codegen.compile_combination(
         g, Combination(tuple(impls), sum(i.t_pred for i in impls)),
         backend="pallas", interpret=True)
+    assert "u" in cp.local_reductions
     assert max(_errs(cp, n=n)) < TOL
 
 
@@ -203,34 +206,60 @@ def _plan_4096():
                                                   PROG.shapes(4096))
 
 
+def _split_plan_4096():
+    """The plan without the block-local barrier: the best combination
+    of the groups that need no axis whole (every call over one axis
+    set), which the compiler chose before it could consume a reduction
+    in the step that finishes it."""
+    g = trace(PROG.script, PROG.shapes(4096))
+    space = build_space(g)
+    space.fusions = [f for f in space.fusions if not f.whole_roots]
+    return g, best_combination(space).impls
+
+
+def _required(g):
+    return (sum(v.nbytes for v in g.inputs)
+            + sum(v.nbytes for v in g.outputs))
+
+
 def test_the_state_is_read_twice_and_written_once():
-    """Fusion rule 1 keeps the write ``w`` over (rows, value width) out
-    of the (rows, key, value) groups, so the state read ``u`` and the
-    update ``S_new`` are two kernels and this plan streams ``S`` twice;
-    it writes ``S_new`` once, to a buffer of its own."""
-    cp = _plan_4096()
-    assert cp.input_passes["S"] == 2
-    assert cp.transposed_operands == ()
-    groups = [[c.elem.name for c in im.fusion.calls]
-              for im in cp.group_impls]
+    """Without the block-local barrier, fusion rule 1 keeps the write
+    ``w`` over (rows, value width) out of the (rows, key, value) groups,
+    so the state read ``u`` and the update ``S_new`` are two kernels
+    that each stream ``S``.  With it, ``u`` finishes inside each grid
+    step (its key axis is one whole block), so one kernel reads ``S``
+    once, consumes ``u`` in the same step and writes ``S_new`` once, to
+    a buffer of its own."""
+    g, split = _split_plan_4096()
+    S = next(v for v in g.inputs if v.name == "S")
+    assert sum(S in im.fusion.external_inputs for im in split) == 2
+    groups = [[c.elem.name for c in im.fusion.calls] for im in split]
     assert ["kda_u"] in groups and ["kda_snew", "kda_o"] in groups
-    (snew,) = [im for im in cp.group_impls
-               if im.fusion.calls[0].elem.name == "kda_snew"]
-    assert [v.name for v in snew.fusion.outputs] == ["S_new", "o"]
+    cp = _plan_4096()
+    assert cp.input_passes["S"] == 1
+    assert cp.transposed_operands == ()
+    assert {"qss", "kss", "u"} <= set(cp.local_reductions)
+    (one,) = [im for im in cp.group_impls
+              if any(v.name == "S" for v in im.fusion.external_inputs)]
+    assert [v.name for v in one.fusion.outputs] == ["S_new", "o"]
+    assert one.n_phases == 1 and cp.n_groups <= 2
 
 
 def test_planned_traffic_counts_two_passes_over_the_state():
-    """The state's read and write are over 97 % of the required bytes;
-    the plan moves one more pass over it and the small vectors between
-    its groups (``compiler.traffic_ratio`` about 1.54)."""
-    cp = _plan_4096()
-    g = cp.graph
+    """The state's read and write are over 97 % of the required bytes.
+    The split plan moves one more pass over it and the small vectors
+    between its groups (``compiler.traffic_ratio`` about 1.54); the
+    block-local plan moves the required bytes and the normed query
+    between its two groups, under 1.02 times them."""
+    g, split = _split_plan_4096()
     S = next(v for v in g.inputs if v.name == "S")
-    required = (sum(v.nbytes for v in g.inputs)
-                + sum(v.nbytes for v in g.outputs))
+    required = _required(g)
     assert 2 * S.nbytes > 0.97 * required
-    traffic = sum(im.traffic_bytes for im in cp.group_impls)
+    traffic = sum(im.traffic_bytes for im in split)
     assert required + S.nbytes < traffic < 1.6 * required
+    cp = _plan_4096()
+    traffic = sum(im.traffic_bytes for im in cp.group_impls)
+    assert required <= traffic < 1.02 * required
 
 
 def test_lint_cli_verifies_kda_at_the_benchmark_size(capsys):

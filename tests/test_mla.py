@@ -137,10 +137,12 @@ def _roots(g):
 
 
 def _split_space(g):
-    """The optimization space without its online-softmax groups: the
+    """The optimization space without its online-softmax groups, nor
+    the groups that need an axis whole (``Fusion.whole_roots``): the
     plans the compiler chose before it could stream the cache once."""
     space = build_space(g)
-    space.fusions = [f for f in space.fusions if f.stream_root is None]
+    space.fusions = [f for f in space.fusions
+                     if f.stream_root is None and not f.whole_roots]
     return space
 
 
@@ -262,14 +264,17 @@ def test_required_cache_bytes_dominate_and_traffic_counts_two_passes():
 
 #: the plans of the benchmarked programs (and ATAX) at the benchmark's
 #: sizes: per group its calls, grid order (positions into its sorted axis
-#: roots) and blocks
+#: roots) and blocks.  GEMVER's first group, ATAX's one and KDA's second
+#: hold a reduce axis whole, so the reduction they consume is block-local
+#: (one pass over A, B's one write, one pass over S)
 PLANS = {
-    ("GEMVER", 16384): [((0, 1), (1, 0), (16384, 128)),
-                        ((2,), (0,), (16384,)),
-                        ((3,), (0, 1), (16384, 256)),
+    ("GEMVER", 16384): [((0, 1, 2, 3), (0, 1), (16384, 128)),
                         ((4,), (0,), (16384,))],
     ("AXPYDOT", 1 << 26): [((0, 1, 2), (0,), (1048576,))],
-    ("ATAX", 16384): [((0, 1), (0, 1), (16384, 256))],
+    ("ATAX", 16384): [((0, 1), (0, 1), (256, 16384))],
+    ("KDA_DECODE_STEP", 4096): [((0, 2), (0, 1), (1024, 128)),
+                                ((1, 3, 4, 5, 6, 7, 8, 9), (0, 1, 2),
+                                 (128, 128, 128))],
     ("MLA_DECODE_ATTN", 131072): [((0, 1, 2, 3, 4, 5, 6, 7), (0, 1, 2, 3),
                                    (16, 512, 64, 4096))],
 }

@@ -16,7 +16,10 @@ refuse, at the sizes the chip smoke run serves:
   one online-softmax kernel (also cut to 128 steps over the cache);
 * KDA_DECODE_STEP at 4096 — a fully indexed (rows, key, value) state
   read by broadcasts and sublane sums, and a rank-3 output written in
-  (rows, 128, 128) blocks.
+  (rows, 128, 128) blocks, in one sweep whose state read finishes in
+  each grid step (the block-local barrier);
+* GEMVER at 16384 — the block-local barrier over (16384, 128) column
+  blocks: A read once and B written once by one kernel.
 
 Each Pallas compile must contain a Mosaic kernel (``tpu_custom_call``).
 A narrow matrix reaches its kernel in XLA's own layout, as a bitcast
@@ -131,15 +134,16 @@ def test_narrow_operands_reach_kernel_without_copy(name, n, width,
 
 
 @pytest.mark.parametrize("name,n,copies", [
-    ("GEMVER", 8192, {"copy": 2, "copy-start": 1}),
+    ("GEMVER", 8192, {"copy": 2}),
     ("AXPYDOT", 1 << 24, {"copy": 1}),
     ("ATAX", 16384, {}),
 ])
 def test_wide_programs_keep_their_copies(name, n, copies, one_chip,
                                          tpu_codegen):
     """No operand of the BLAS programs is narrow, so their compiled
-    programs keep the copies they had (two scalars and a move into
-    VMEM on GEMVER, a scalar on AXPYDOT) and no transpose."""
+    programs copy no matrix and transpose nothing: two scalars on
+    GEMVER (whose x no longer moves between kernels), a scalar on
+    AXPYDOT."""
     prog = REGISTRY[name]
     compiled = FusionCompiler(backend="pallas", cache=None).compile(
         prog.script, prog.shapes(n))
@@ -187,16 +191,33 @@ def test_online_softmax_kernel_compiles_for_v5e(one_chip, tpu_codegen):
 
 
 def test_kda_state_reaches_its_kernels_without_copy(one_chip, tpu_codegen):
-    """KDA_DECODE_STEP at the benchmark's 4096 rows: six Mosaic kernels,
-    the (4096, 128, 128) state S read by two of them and S_new written
-    by one, with no relayout copy or transpose of either."""
+    """KDA_DECODE_STEP at the benchmark's 4096 rows: two Mosaic kernels,
+    the (4096, 128, 128) state S read once by one of them, which writes
+    S_new, with no relayout copy or transpose of either."""
     prog, n = REGISTRY["KDA_DECODE_STEP"], 4096
     compiled = FusionCompiler(backend="pallas", cache=None).compile(
         prog.script, prog.shapes(n))
+    assert compiled.input_passes["S"] == 1
     hlo = _compile(compiled, prog.shapes(n), one_chip)
-    assert hlo.count("tpu_custom_call") >= compiled.n_groups == 6
+    assert hlo.count("tpu_custom_call") >= compiled.n_groups == 2
     assert _relayouts(hlo, 128, 128) == []
     assert "f32[4096,128,128]{2,1,0}" in _kernel_operands(hlo)
+
+
+def test_one_pass_gemver_compiles_without_relayout(one_chip, tpu_codegen):
+    """GEMVER at the benchmark's 16384: the block-local group (rank-2
+    update, both matrix-vector products and xpay over (16384, 128)
+    column blocks) and scal compile as two Mosaic kernels, A reaching
+    the first as it is stored and B leaving it so, with no copy or
+    transpose of either."""
+    prog, n = REGISTRY["GEMVER"], 16384
+    compiled = FusionCompiler(backend="pallas", cache=None).compile(
+        prog.script, prog.shapes(n))
+    assert compiled.local_reductions == ("t",)
+    hlo = _compile(compiled, prog.shapes(n), one_chip)
+    assert hlo.count("tpu_custom_call") >= compiled.n_groups == 2
+    assert _relayouts(hlo, n, n) == []
+    assert f"f32[{n},{n}]{{1,0}}" in _kernel_operands(hlo)
 
 
 def test_jnp_block_compiles_for_v5e(one_chip, tpu_codegen):

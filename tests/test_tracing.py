@@ -16,7 +16,7 @@ from repro.programs import REGISTRY
 N = 256
 #: the labels of each program's plan at ``N``, in topological order
 LABELS = {
-    "GEMVER": ["g0_rank2_update_gemtv", "g1_xpay", "g2_gemv", "g3_scal"],
+    "GEMVER": ["g0_rank2_update_gemtv_xpay_gemv_scal"],
     "AXPYDOT": ["g0_axmy_ew_mul_sum_reduce"],
 }
 BACKENDS = [("jnp", False), ("pallas", True)]
