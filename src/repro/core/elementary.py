@@ -23,11 +23,13 @@ nested map (mapped map)     (i, j)     (i, j)      —
 mapped reduce               (i, j)     (i,)/(j,)   (j,)/(i,)
 ==========================  =========  ==========  ============
 
-Past the paper, a depth-3 map-reduce (``make_tensor_map_reduce``) maps
-over two axes and reduces over the third, its operands indexed by
-subsets of the three: a contraction such as every head of a decode step
-against one shared cache, ``s[h, t] = sum_c q[h, c] k[t, c]``, whose
-cache operand ``k`` is invariant over the head axis.
+Past the paper, a depth-3 or deeper map-reduce
+(``make_tensor_map_reduce``) maps over every axis but one and reduces
+over that one, its operands indexed by subsets of the axes: a
+contraction such as every head of a decode step against one shared
+cache, ``s[h, t] = sum_c q[h, c] k[t, c]``, whose cache operand ``k`` is
+invariant over the head axis, or at depth 4 a batch of experts, each
+projecting its own tokens, ``g[e, c, i] = sum_h x[e, c, h] w[e, i, h]``.
 
 The per-element first-order function ``fn`` is written *block-
 polymorphically*: it receives jnp arrays whose shapes are either the full
@@ -146,13 +148,15 @@ class Elementary:
 
     def __post_init__(self):
         depth = len(self.formal_axes)
-        # the paper stops at depth 2.  Depth 3 is carried through every
-        # layer downstream: trace axes, fusion legality (a group's calls
-        # share one axis set, so a depth-3 call shares a group with a
-        # depth-2 one only in an online softmax), impl enumeration over
-        # all grid orders, and codegen's index maps; MLA_DECODE_ATTN's
-        # contractions exercise it.  Nothing deeper is exercised by any
-        # program or test.
+        # the paper stops at depth 2.  Depths 3 and 4 are carried through
+        # every layer downstream: trace axes, fusion legality (a group's
+        # calls share one axis set, so a deeper call shares a group with
+        # a shallower one only in an online softmax or where the axes it
+        # lacks are whole blocks), impl enumeration over all grid orders,
+        # and codegen's index maps.  MLA_DECODE_ATTN's and
+        # KDA_DECODE_STEP's contractions exercise depth 3, MOE_EXPERTS's
+        # expert projections depth 4 (five axis roots in one group).
+        # Nothing deeper is exercised by any program or test.
         assert depth >= 1, "elementary needs at least one iteration axis"
         for spec in self.in_specs:
             assert all(0 <= a < depth for a in spec.axes)
@@ -278,22 +282,28 @@ def make_nested_map_reduce(name: str, fn: Callable,
 
 def make_tensor_map_reduce(name: str, fn: Callable,
                            in_axes: Sequence[Sequence[int]],
-                           reduce_axis: int, **props) -> Elementary:
-    """Depth-3 map over two axes of a sum over the third,
-    ``reduce_axis``.  ``in_axes`` indexes each operand by a subset of the
-    axes, as in ``make_nested_map``; the output keeps the other two axes
-    in order.  E.g. with axes ``(h, t, c)``:
+                           reduce_axis: int, *, depth: int = 3,
+                           **props) -> Elementary:
+    """Depth-``depth`` map over every axis but ``reduce_axis`` of a sum
+    over that one.  ``in_axes`` indexes each operand by a subset of the
+    axes, as in ``make_nested_map``; the output keeps the other axes in
+    order.  E.g. with axes ``(h, t, c)``:
 
     scores (reduce_axis=2, ``in_axes=[(0, 2), (1, 2)]``): s_ht = sum_c q_hc k_tc
     values (reduce_axis=1, ``in_axes=[(0, 1), (1, 2)]``): o_hc = sum_t w_ht v_tc
+
+    and at depth 4, with axes ``(e, c, i, h)``:
+
+    expert projection (reduce_axis=3, ``in_axes=[(0, 1, 3), (0, 2, 3)]``):
+    g_eci = sum_h x_ech w_eih
 
     ``fn`` computes the partial sum over the blocks it is given, as in
     ``make_nested_map_reduce``; a multiply and an add per point."""
     return Elementary(
         name=name, kind=Kind.NESTED_MAP_REDUCE,
-        formal_axes=("a0", "a1", "a2"),
+        formal_axes=tuple(f"a{k}" for k in range(depth)),
         in_specs=tuple(ArgSpec(tuple(a)) for a in in_axes),
-        out_axes=tuple(a for a in range(3) if a != reduce_axis),
+        out_axes=tuple(a for a in range(depth) if a != reduce_axis),
         fn=fn, flops_per_point=2.0, **props)
 
 

@@ -2,7 +2,8 @@
 
 These extend ``blas.elementary_lib`` with the non-multilinear pieces a
 decoder step needs: the rmsnorm scale map, softmax stages, attention
-contractions, latent attention's (MLA) per-head stages and the
+contractions, latent attention's (MLA) per-head stages, KDA's state
+step, the routed experts' projections and gated activation, and the
 precision-matched AdamW moment updates.
 
 Bitwise discipline (DESIGN.md §10): every ``fn`` body is written so the
@@ -200,6 +201,40 @@ kda_o = make_tensor_map_reduce(
     "kda_o", _kda_contract, in_axes=[(0, 1), (0, 1, 2)], reduce_axis=1,
     linear_args=(0, 1))
 
+# --- routed experts (MoE), the experts held on one chip ---------------------
+#
+# DeepSeek-V2 (arXiv:2405.04434, section 2.2): each expert is
+# down(silu(gate(x)) * up(x)), its weights in the nn.Linear layout
+# (out, in).  Axes: e (experts held), c (the tokens dispatched to each),
+# i (expert width), h (hidden), d (hidden, as the down projection's
+# output).  At M = tokens per expert the contractions are batched MXU
+# matmuls, one batch entry per expert.
+
+#: DeepSeek-V2-Lite's published widths (hidden_size, moe_intermediate_size)
+MOE_HIDDEN, MOE_WIDTH = 2048, 1408
+
+
+def _expert_contract(x, w):
+    """sum_k x_eck w_ejk for every expert e of the block: each expert's
+    tokens against its own (out, in) weight."""
+    return jnp.einsum("...eck,...ejk->...ecj", x, w, precision="highest")
+
+
+# g_eci = sum_h x_ech w_eih over (e, c, i, h): the gate and the up
+# projection, which share their input x
+moe_proj = make_tensor_map_reduce(
+    "moe_proj", _expert_contract, in_axes=[(0, 1, 3), (0, 2, 3)],
+    reduce_axis=3, depth=4, linear_args=(0, 1))
+# a_eci = r_ec silu(g_eci) u_eci: the gated activation, each token's row
+# scaled by its combine weight r (routed_scaling_factor 1)
+moe_act = make_tensor_map(
+    "moe_act", lambda r, g, u: col(r) * (g * jax.nn.sigmoid(g)) * u,
+    in_axes=[(0, 1), (0, 1, 2), (0, 1, 2)], depth=3, flops_per_point=7)
+# y_ecd = sum_i a_eci w_edi over (e, c, d, i): the down projection
+moe_down = make_tensor_map_reduce(
+    "moe_down", _expert_contract, in_axes=[(0, 1, 3), (0, 2, 3)],
+    reduce_axis=3, depth=4, linear_args=(0, 1))
+
 # --- AdamW (precision-matched variants of repro.optim.fused) -----------------
 
 ema_pm = make_map(
@@ -216,5 +251,6 @@ ALL = {e.name: e for e in [
     rms_scale, exp_map, exp_sub, rsqrt_map, div_by, attn_score, attn_out,
     mla_score, mla_logits, mla_max, mla_exp_sub, mla_sum, mla_div, mla_value,
     kda_sumsq, kda_knorm, kda_qnorm, kda_gate, kda_ka, kda_u, kda_w, kda_snew,
-    kda_o, ema_pm, ema_sq_pm, adam_dir, apply_lr,
+    kda_o, moe_proj, moe_act, moe_down, ema_pm, ema_sq_pm, adam_dir,
+    apply_lr,
 ]}

@@ -24,6 +24,12 @@ repo's reference implementations (``repro.kernels.ref`` /
   state read, updated by a rank-1 term and written back, the one
   program with a rank-3 output (oracles: the float64 ``reference``, and
   ``kda_recurrent_reference``, the paper's recurrence over tokens).
+* ``MOE_EXPERTS`` — DeepSeek-V2-Lite's routed experts at decode: the 8
+  of 64 experts one chip holds under expert parallelism, each
+  ``down(silu(gate(x)) * up(x))`` on the tokens dispatched to it, at
+  the published widths (oracles: the float64 ``reference``, and
+  ``moe_layer_reference``, the whole MoE layer with its router and
+  shared experts, through ``moe_dispatch`` and ``moe_combine``).
 
 Size notes (pinned empirically, see DESIGN.md §10): matvec-bearing
 graphs (``LM_BLOCK``, ``LM_DECODE_ATTN``) are bitwise against the
@@ -388,3 +394,151 @@ def kda_recurrent_reference(q, k, v, f, a_log, b, S0):
                             tuple(jnp.asarray(x, jnp.float32)
                                   for x in (q, k, v, f, b)))
         return o, S
+
+
+# --- MOE_EXPERTS:  the routed experts one chip holds, at decode --------------
+
+def _moe_script(g, x, r, w_gate, w_up, w_down):
+    gg = g.apply(mlib.moe_proj, x, w_gate, name="gg")
+    uu = g.apply(mlib.moe_proj, x, w_up, name="uu")
+    a = g.apply(mlib.moe_act, r, gg, uu, name="a")
+    y = g.apply(mlib.moe_down, a, w_down, name="y")
+    return (y,)
+
+
+def _moe_ref(x, r, w_gate, w_up, w_down):
+    gg = np.matmul(x, np.swapaxes(w_gate, 1, 2))
+    uu = np.matmul(x, np.swapaxes(w_up, 1, 2))
+    a = r[:, :, None] * gg / (1.0 + np.exp(-gg)) * uu
+    return (np.matmul(a, np.swapaxes(w_down, 1, 2)),)
+
+
+def _moe_inputs(experts, hidden, width):
+    """Tokens N(0, 1), combine weights U(0, 1) as router probabilities
+    are, and weights N(0, 1 / fan_in) as a trained layer's are scaled,
+    so that every activation is of order 1."""
+    def make(n, seed=0, dtype=np.float32):
+        rng = np.random.default_rng(seed)
+
+        def normal(shape, fan_in):
+            return (rng.standard_normal(shape) / np.sqrt(fan_in)).astype(
+                dtype)
+        return {"x": normal((experts, n, hidden), 1),
+                "r": rng.uniform(size=(experts, n)).astype(dtype),
+                "w_gate": normal((experts, width, hidden), hidden),
+                "w_up": normal((experts, width, hidden), hidden),
+                "w_down": normal((experts, hidden, width), width)}
+    return make
+
+
+def moe_program(experts: int = 8, hidden: int = mlib.MOE_HIDDEN,
+                width: int = mlib.MOE_WIDTH,
+                name: str = "MOE_EXPERTS") -> Program:
+    """The routed experts one chip holds under expert parallelism, on
+    the tokens dispatched to them: ``n`` tokens per held expert.
+
+    Inputs: the dispatch buffer ``x`` ``(experts, n, hidden)`` and the
+    combine weights ``r`` ``(experts, n)`` (``moe_dispatch``), and each
+    expert's ``w_gate``, ``w_up`` ``(experts, width, hidden)`` and
+    ``w_down`` ``(experts, hidden, width)``, in the ``nn.Linear``
+    layout.  Output: ``y[e, c] = r[e, c] w_down[e] (silu(w_gate[e]
+    x[e, c]) * w_up[e] x[e, c])`` ``(experts, n, hidden)``, which
+    ``moe_combine`` adds back to the tokens.  The defaults are one chip's
+    share of DeepSeek-V2-Lite's 64 experts over 8 chips at the published
+    widths; smaller widths are for tests."""
+    return Program(
+        name, "M", _moe_script,
+        lambda n: {"x": (experts, n, hidden), "r": (experts, n),
+                   "w_gate": (experts, width, hidden),
+                   "w_up": (experts, width, hidden),
+                   "w_down": (experts, hidden, width)},
+        _moe_ref,
+        lambda n: experts * n * width * (6.0 * hidden + 7.0),
+        inputs=_moe_inputs(experts, hidden, width))
+
+
+_register(moe_program())
+
+#: DeepSeek-V2-Lite's router: 64 routed experts, 6 a token
+MOE_EXPERTS_ALL, MOE_TOP_K = 64, 6
+
+
+def _route(x_tokens, router_w):
+    """The softmax over every routed expert and its greedy top 6:
+    ``(weights, experts)``, each ``(tokens, 6)``, the weights not
+    renormalised (``norm_topk_prob`` false, ``routed_scaling_factor``
+    1)."""
+    probs = jax.nn.softmax(x_tokens @ router_w.T, axis=-1)
+    return jax.lax.top_k(probs, MOE_TOP_K)
+
+
+def moe_dispatch(x_tokens, router_w, held):
+    """Route ``x_tokens`` ``(T, hidden)`` over every expert of
+    ``router_w`` ``(experts, hidden)`` and group the tokens routed to
+    the ``held`` experts into ``MOE_EXPERTS``'s dispatch buffer, as an
+    expert-parallel dispatch delivers it, with room for every token in
+    each expert (a token picks an expert once).
+
+    Returns ``(x, r, slots)``: ``x`` ``(len(held), T, hidden)``, expert
+    ``held[k]``'s tokens in token order in row block ``k``; ``r``
+    ``(len(held), T)``, each row's router weight; ``slots`` ``(len(held),
+    T)``, each row's token index.  Rows beyond an expert's count are zero
+    and point at token ``T``, which ``moe_combine`` drops."""
+    with jax.default_matmul_precision("highest"):
+        x_tokens = jnp.asarray(x_tokens, jnp.float32)
+        n_tok = x_tokens.shape[0]
+        weights, experts = _route(x_tokens, jnp.asarray(router_w,
+                                                        jnp.float32))
+        held = jnp.asarray(held)
+        hit = experts[:, :, None] == held[None, None, :]   # (T, 6, held)
+        w_tok = jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), axis=1)
+        routed = jnp.any(hit, axis=1)                        # (T, held)
+        row = jnp.where(routed, jnp.cumsum(routed, axis=0) - 1, n_tok)
+        cols = jnp.arange(held.shape[0])
+        slots = jnp.full((held.shape[0], n_tok), n_tok).at[
+            cols[None, :], row].set(jnp.arange(n_tok)[:, None], mode="drop")
+        x_pad = jnp.concatenate(
+            [x_tokens, jnp.zeros((1, x_tokens.shape[1]), jnp.float32)])
+        w_pad = jnp.concatenate([w_tok, jnp.zeros((1, held.shape[0]))])
+        return x_pad[slots], w_pad[slots, cols[:, None]], slots
+
+
+def moe_combine(y, slots, n_tokens: int):
+    """Add each row of ``y`` ``(experts, capacity, hidden)`` back to its
+    token ``slots[e, c]``: ``(n_tokens, hidden)``, rows pointing at
+    ``n_tokens`` dropped."""
+    out = jnp.zeros((n_tokens + 1, y.shape[-1]), jnp.float32)
+    return out.at[slots].add(jnp.asarray(y, jnp.float32))[:n_tokens]
+
+
+def _swiglu(x, w_gate, w_up, w_down):
+    """One expert's MLP on rows ``x``: down(silu(gate(x)) * up(x))."""
+    return (jax.nn.silu(x @ w_gate.T) * (x @ w_up.T)) @ w_down.T
+
+
+def moe_layer_reference(x, router_w, experts, shared):
+    """DeepSeek-V2's MoE layer (arXiv:2405.04434 section 2.2) on tokens
+    ``x`` ``(T, hidden)``, float32 ``jax.numpy`` at full matmul
+    precision, no compiler: the softmax router over every expert of
+    ``router_w`` ``(experts, hidden)``, its greedy top 6 weights not
+    renormalised and scaled by 1, each routed expert's
+    ``down(silu(gate(x)) * up(x))`` weighted, plus the shared experts.
+
+    ``experts`` is ``(w_gate, w_up, w_down)`` of every routed expert,
+    ``(experts, width, hidden)`` twice and ``(experts, hidden, width)``;
+    ``shared`` the same of the shared experts taken as one MLP (2 x 1408
+    = 2816 wide for DeepSeek-V2-Lite), ``(width, hidden)`` twice and
+    ``(hidden, width)``.  Every expert is computed on every token and
+    weighted by its router weight, zero where it is not among the token's
+    top 6.  Departures from the published layer: the residual add and the
+    norms around the layer stay outside, as the auxiliary losses do."""
+    with jax.default_matmul_precision("highest"):
+        x = jnp.asarray(x, jnp.float32)
+        weights, picked = _route(x, jnp.asarray(router_w, jnp.float32))
+        w_gate, w_up, w_down = experts
+        out = _swiglu(x, *shared)
+        for e in range(w_gate.shape[0]):
+            w_e = jnp.sum(jnp.where(picked == e, weights, 0.0), axis=1)
+            out = out + w_e[:, None] * _swiglu(x, w_gate[e], w_up[e],
+                                               w_down[e])
+        return out

@@ -1,12 +1,12 @@
 """Backend-differential harness (DESIGN.md §2/§10).
 
 The enforcement teeth behind "the pallas backend emits every fusion the
-scheduler can legally form": every REGISTRY program (11 BLAS + 4 LM
-decode-step workloads), every scheduler-enumerated combination at a
-small size budget, compiled under ``backend="pallas"`` (interpret mode)
-and compared against the ``jnp`` backend within the §10 tolerance
-envelope — bitwise for map/reduce-only graphs, allclose for
-matvec-bearing ones.  Includes the acceptance pins for multi-phase
+scheduler can legally form": every REGISTRY program (11 BLAS + 7 model
+workloads, the routed experts at reduced widths), every
+scheduler-enumerated combination at a small size budget, compiled under
+``backend="pallas"`` (interpret mode) and compared against the ``jnp``
+backend within the §10 tolerance envelope — bitwise for map/reduce-only
+graphs, allclose for matvec-bearing ones.  Includes the acceptance pins for multi-phase
 in-kernel reduce consumption (ATAX's second matvec, rmsnorm's
 rsqrt-of-sum, softmax's exp-sub-of-max) and the clear-error contract
 for group shapes the backend cannot emit.
@@ -23,6 +23,7 @@ from repro.core.predictor import cost_impl
 from repro.core.scheduler import (Combination, build_space,
                                   enumerate_combinations)
 from repro.programs import REGISTRY, make_inputs
+from repro.programs.models import moe_program
 from repro.serving import ServingEngine
 
 #: small size budget: every axis one grid cell at depth 1, a handful of
@@ -35,9 +36,13 @@ COMBO_LIMIT = 16
 #: finished reduction in-kernel (the multi-phase pallas path)
 CONSUMING = ("ATAX", "LM_RMSNORM", "LM_BLOCK", "LM_DECODE_ATTN")
 
+#: reduced-width instances of programs whose published widths hold
+#: weights of 92 MB each whatever ``n``, and add no coverage here
+REDUCED = {"MOE_EXPERTS": moe_program(8, 128, 256, name="MOE_EXPERTS")}
+
 
 def _graph(name, n=N):
-    prog = REGISTRY[name]
+    prog = REDUCED.get(name, REGISTRY[name])
     return prog, trace(prog.script, prog.shapes(n))
 
 

@@ -19,7 +19,10 @@ refuse, at the sizes the chip smoke run serves:
   (rows, 128, 128) blocks, in one sweep whose state read finishes in
   each grid step (the block-local barrier);
 * GEMVER at 16384 — the block-local barrier over (16384, 128) column
-  blocks: A read once and B written once by one kernel.
+  blocks: A read once and B written once by one kernel;
+* MOE_EXPERTS at 64 tokens per expert — depth-4 batched contractions,
+  8 experts' (1408, 2048) weights against their own tokens, gate and up
+  finished in the step that reads them.
 
 Each Pallas compile must contain a Mosaic kernel (``tpu_custom_call``).
 A narrow matrix reaches its kernel in XLA's own layout, as a bitcast
@@ -218,6 +221,25 @@ def test_one_pass_gemver_compiles_without_relayout(one_chip, tpu_codegen):
     assert hlo.count("tpu_custom_call") >= compiled.n_groups == 2
     assert _relayouts(hlo, n, n) == []
     assert f"f32[{n},{n}]{{1,0}}" in _kernel_operands(hlo)
+
+
+def test_routed_experts_compile_without_weight_relayout(one_chip,
+                                                        tpu_codegen):
+    """MOE_EXPERTS at the benchmark's 64 tokens per expert and the
+    published widths: two Mosaic kernels (gate, up and the activation;
+    the down projection), every weight read once and reaching its kernel
+    as it is stored, with no copy or transpose of w_gate, w_up or
+    w_down."""
+    prog, n = REGISTRY["MOE_EXPERTS"], 64
+    compiled = FusionCompiler(backend="pallas", cache=None).compile(
+        prog.script, prog.shapes(n))
+    assert set(compiled.input_passes.values()) == {1}
+    hlo = _compile(compiled, prog.shapes(n), one_chip)
+    assert hlo.count("tpu_custom_call") >= compiled.n_groups == 2
+    assert _copies(hlo) == []
+    operands = _kernel_operands(hlo)
+    assert operands.count("f32[8,1408,2048]{2,1,0}") == 2
+    assert "f32[8,2048,1408]{2,1,0}" in operands
 
 
 def test_jnp_block_compiles_for_v5e(one_chip, tpu_codegen):
